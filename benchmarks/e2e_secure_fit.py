@@ -27,9 +27,9 @@ steady-state pipelines, not XLA compilation or data staging.
 Interpret-mode caveat: on this CPU container the Pallas protocol kernels
 run through the interpreter and the fused-IRLS kernel runs as its XLA
 functional simulation (same numerics contract — f32 Gram accumulation,
-payload-dtype gradient/deviance; see ``kernels/fused_irls.py``).  On TPU
-(``interpret=False``) the blocked kernels compile natively and the f32
-path is simply the hardware dtype.  Machine-readable rows land in
+payload-dtype gradient/deviance; see ``kernels/fused_irls.py``).  On a
+TPU the blocked kernels compile (``kernels/backend.py`` decides), and
+these CPU timings say nothing about them.  Machine-readable rows land in
 BENCH_e2e_secure_fit.json (``--quick`` is the bench_smoke gate size).
 """
 from __future__ import annotations

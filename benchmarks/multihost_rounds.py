@@ -232,7 +232,6 @@ def run_child_2d(pods: int, params: int, repeats: int):
     from jax.sharding import PartitionSpec as P
 
     from repro.core.secure_agg import SecureAggregator, secure_psum
-    from repro.distributed.compat import shard_map
     from repro.distributed.multihost import pod_mesh, pod_share_mesh, \
         secure_psum_2d
     from repro.distributed.sharding import POD_AXIS
@@ -243,7 +242,7 @@ def run_child_2d(pods: int, params: int, repeats: int):
                                           jnp.float32)}
     key = jax.random.PRNGKey(7)
     mesh2 = pod_share_mesh(pods, t)
-    fn2 = jax.jit(shard_map(
+    fn2 = jax.jit(jax.shard_map(
         lambda: secure_psum_2d(tree, key, aggregator=agg),
         mesh=mesh2, in_specs=(), out_specs=P(), check_vma=False,
     ))
@@ -258,7 +257,7 @@ def run_child_2d(pods: int, params: int, repeats: int):
     # oracle: the 1D wire on a pods-sized mesh reveals the same field
     # encoding, so the decoded floats must agree BITWISE
     mesh1 = pod_mesh(pods)
-    out1 = jax.jit(shard_map(
+    out1 = jax.jit(jax.shard_map(
         lambda: secure_psum(tree, POD_AXIS, key, aggregator=agg),
         mesh=mesh1, in_specs=(), out_specs=P(), check_vma=False,
     ))()

@@ -25,6 +25,10 @@ def main() -> None:
     args = ap.parse_args()
     os.makedirs(args.out, exist_ok=True)
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from . import (accuracy, convergence, runtime, scalability, roofline,
                    secure_overhead)
 

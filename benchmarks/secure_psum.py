@@ -96,7 +96,6 @@ def run(params: int, devices: int, repeats: int):
     from jax.sharding import PartitionSpec as P
 
     from repro.core.secure_agg import SecureAggregator, secure_psum
-    from repro.distributed.compat import shard_map
     from repro.distributed.sharding import POD_AXIS
 
     agg_pal = SecureAggregator(backend="pallas")
@@ -108,8 +107,8 @@ def run(params: int, devices: int, repeats: int):
     psum_key = jax.random.PRNGKey(7)
 
     def spmd(fn):
-        return jax.jit(shard_map(fn, mesh=mesh, in_specs=(), out_specs=P(),
-                                 check_vma=False))
+        return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=(),
+                                     out_specs=P(), check_vma=False))
 
     def per_leaf_frozen():
         """Pre-PR secure_psum, frozen: full uint64 tree, all-w reveal."""

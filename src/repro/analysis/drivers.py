@@ -26,7 +26,7 @@ Fused specs trace twice — ``protect="both"`` (everything shared) and
 
 Every spec's graph routes through the ONE
 :class:`repro.core.collective.SecureCollective` chain, so the named
-boundary pjits the taint rules key on (``_protect_flat`` /
+boundary jits the taint rules key on (``_protect_flat`` /
 ``_reveal_flat`` / ``_distributed_reveal`` / ``declassify_sum``) are the
 same four call sites the runtime ledger hooks and the byte telemetry
 account — certifying a driver here certifies the only chain it can use.
@@ -100,7 +100,7 @@ def _fused_spec(name: str, protect: str, include_count: bool):
         def fn(beta, key, X, X32, y, counts):
             return _fused_secure_iteration(
                 beta, key, X, X32, y, counts, 1.0, agg, protect, 0.0,
-                True, points=None, include_count=include_count,
+                points=None, include_count=include_count,
                 summaries_backend="pallas",
             )
 
@@ -118,7 +118,7 @@ def _fused_spec(name: str, protect: str, include_count: bool):
         beta = jnp.zeros((packed.dim,), jnp.float64)
         out = _fused_secure_iteration(
             beta, jax.random.PRNGKey(0), packed.X, packed.X32, packed.y,
-            packed.counts, 1.0, agg, protect, 0.0, True, points=None,
+            packed.counts, 1.0, agg, protect, 0.0, points=None,
             include_count=include_count, summaries_backend="pallas",
         )
         jax.block_until_ready(out)
@@ -142,7 +142,7 @@ def _scan_spec(name: str, protect: str, include_count: bool):
                 beta, obj_prev, conv, iters, key, rbase,
                 X, X32, y, counts, 1.0,
                 agg=agg, protect=protect, l1=0.0, tol=1e-10,
-                interpret=True, points=None,
+                points=None,
                 include_count=include_count,
                 summaries_backend="pallas", num_rounds=3,
                 num_parts=packed.num_institutions, max_rounds=3,
@@ -168,7 +168,7 @@ def _scan_spec(name: str, protect: str, include_count: bool):
             jnp.zeros((), jnp.int32),
             packed.X, packed.X32, packed.y, packed.counts, 1.0,
             agg=agg, protect=protect, l1=0.0, tol=1e-10,
-            interpret=True, points=None, include_count=include_count,
+            points=None, include_count=include_count,
             summaries_backend="pallas", num_rounds=3,
             num_parts=packed.num_institutions, max_rounds=3,
         )
@@ -204,7 +204,7 @@ def _selection_spec(name: str, protect: str):
                 betas, obj_prev, conv, iters, vdev, vcorr, vcnt, key,
                 rbase, X, X32, y, counts, fold_ids, fold_of, lams,
                 agg=agg, protect=protect, l1=0.0, tol=1e-10,
-                interpret=True, points=None,
+                points=None,
                 summaries_backend="pallas", num_rounds=2,
                 num_parts=packed.num_institutions, max_rounds=2,
             )
@@ -255,7 +255,7 @@ def _selection_spec(name: str, protect: str):
             packed.X, packed.X32, packed.y, packed.counts,
             fold_ids, fold_of, lams,
             agg=agg, protect=protect, l1=0.0, tol=1e-10,
-            interpret=True, points=None,
+            points=None,
             summaries_backend="pallas", num_rounds=2,
             num_parts=packed.num_institutions, max_rounds=2,
         )
@@ -278,13 +278,12 @@ def _psum_spec(name: str, reveal: str, out: str, num_pods: int = 4):
         from jax.sharding import AbstractMesh, PartitionSpec as P
 
         from ..core.collective import secure_psum
-        from ..distributed.compat import shard_map
         from ..distributed.sharding import POD_AXIS
 
         agg = _aggregator()
         key = jax.random.PRNGKey(0)
-        mesh = AbstractMesh(((POD_AXIS, num_pods),))
-        fn = shard_map(
+        mesh = AbstractMesh((num_pods,), (POD_AXIS,))
+        fn = jax.shard_map(
             lambda tree: secure_psum(
                 tree, POD_AXIS, key, aggregator=agg, reveal=reveal,
                 out=out,
@@ -300,14 +299,13 @@ def _psum_spec(name: str, reveal: str, out: str, num_pods: int = 4):
         from jax.sharding import PartitionSpec as P
 
         from ..core.collective import secure_psum
-        from ..distributed.compat import shard_map
         from ..distributed.multihost import pod_mesh
         from ..distributed.sharding import POD_AXIS
 
         agg = _aggregator()
         key = jax.random.PRNGKey(0)
         mesh = pod_mesh(num_pods)
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda tree: secure_psum(
                 tree, POD_AXIS, key, aggregator=agg, reveal=reveal,
                 out=out,
@@ -325,7 +323,6 @@ def _psum_2d_spec(name: str, num_pods: int = 3):
     def build():
         from jax.sharding import AbstractMesh, PartitionSpec as P
 
-        from ..distributed.compat import shard_map
         from ..distributed.multihost import secure_psum_2d
         from ..distributed.sharding import POD_AXIS, SHARE_AXIS
 
@@ -333,9 +330,9 @@ def _psum_2d_spec(name: str, num_pods: int = 3):
         key = jax.random.PRNGKey(0)
         # one share column per reveal point: share axis == threshold
         mesh = AbstractMesh(
-            ((POD_AXIS, num_pods), (SHARE_AXIS, agg.scheme.threshold))
+            (num_pods, agg.scheme.threshold), (POD_AXIS, SHARE_AXIS)
         )
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda tree: secure_psum_2d(tree, key, aggregator=agg),
             mesh=mesh, in_specs=(P(),), out_specs=P(), check_vma=False,
         )
@@ -347,13 +344,12 @@ def _psum_2d_spec(name: str, num_pods: int = 3):
     def runner():
         from jax.sharding import PartitionSpec as P
 
-        from ..distributed.compat import shard_map
         from ..distributed.multihost import pod_share_mesh, secure_psum_2d
 
         agg = _aggregator()
         key = jax.random.PRNGKey(0)
         mesh = pod_share_mesh(num_pods, agg.scheme.threshold)
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda tree: secure_psum_2d(tree, key, aggregator=agg),
             mesh=mesh, in_specs=(P(),), out_specs=P(), check_vma=False,
         ))

@@ -13,7 +13,7 @@ unless every fixture produces an error finding.
 * ``reveal_institution_slice``— protects correctly, then reveals ONE
   institution's share slice instead of the Algorithm-2 aggregate: the
   reconstruction is a per-institution summary.  The finding names the
-  offending ``pjit(_reveal_flat)`` equation path.
+  offending ``jit(_reveal_flat)`` equation path.
 * ``callback_leak``           — ships a per-institution deviance into a
   ``jax.debug.callback`` (a print/telemetry hook): host code outside
   the protocol would observe institution-local data.
@@ -40,7 +40,7 @@ def _skip_protect_build():
     def fn(beta, X, X32, y, counts):
         sm = batched_local_summaries(
             beta, PackedPartitions(X, X32, y, counts),
-            backend="pallas", interpret=True,
+            backend="pallas",
         )
         # LEAK: plain unannotated sums — no protect, no declassify_sum
         H = jnp.sum(sm.hessian, axis=0)
@@ -68,7 +68,7 @@ def _reveal_slice_build():
     def fn(beta, key, X, X32, y, counts):
         sm = batched_local_summaries(
             beta, PackedPartitions(X, X32, y, counts),
-            backend="pallas", interpret=True,
+            backend="pallas",
         )
         tree = {"gradient": sm.gradient, "deviance": sm.deviance}
         prot = agg.protect_batched(key, tree)
@@ -97,12 +97,12 @@ def _callback_leak_build():
     def fn(beta, key, X, X32, y, counts):
         sm = batched_local_summaries(
             beta, PackedPartitions(X, X32, y, counts),
-            backend="pallas", interpret=True,
+            backend="pallas",
         )
         # LEAK: per-institution deviances shipped to a host logging hook
         jax.debug.callback(lambda d: None, sm.deviance)
         return _fused_secure_iteration(
-            beta, key, X, X32, y, counts, 1.0, agg, "both", 0.0, True,
+            beta, key, X, X32, y, counts, 1.0, agg, "both", 0.0,
             summaries_backend="pallas",
         )
 

@@ -29,7 +29,7 @@ class Finding:
     """One fact a pass established about one program point.
 
     ``where`` is the jaxpr equation path (e.g.
-    ``eqn[3]:pjit(_reveal_flat)`` nested as ``.../eqn[0]:scan/...``) or
+    ``eqn[3]:jit(_reveal_flat)`` nested as ``.../eqn[0]:scan/...``) or
     a ``file:line`` location for source-level lints.
     """
 

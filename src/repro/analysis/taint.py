@@ -16,7 +16,7 @@ with a four-level taint lattice, join = max:
 
 Transitions the verifier recognizes (everything else joins its inputs):
 
-* ``pjit(_protect_flat)`` — the fused fixed-point-encode + Horner share
+* ``jit(_protect_flat)`` — the fused fixed-point-encode + Horner share
   kernel: outputs are PROTECTED whatever came in (SECRET -> PROTECTED).
 * ``reduce_sum`` over the institution axis of a batched share buffer
   (axis ndim-3 of a >=5D PROTECTED operand — the (w, R, [C,] S, rows,
@@ -26,15 +26,15 @@ Transitions the verifier recognizes (everything else joins its inputs):
   taint, so slicing tricks cannot launder a single contribution.
 * ``psum`` / ``reduce_scatter`` over a mesh axis of size >= 2 on a
   PROTECTED operand: the SPMD form of Algorithm 2 -> PROTECTED_AGG.
-* ``pjit(_reveal_flat)`` — the fused Lagrange+CRT reconstruction: the
+* ``jit(_reveal_flat)`` — the fused Lagrange+CRT reconstruction: the
   ONLY declassification of share material.  Requires (a) input taint
   exactly PROTECTED_AGG (SECRET means protect was skipped; PROTECTED
   means a per-institution buffer is being revealed) and (b) a
   threshold-satisfying share axis (leading dim >= t).  Outputs PUBLIC.
-* ``pjit(_distributed_reveal)`` — the 2D-mesh collective reveal: same
+* ``jit(_distributed_reveal)`` — the 2D-mesh collective reveal: same
   contract, with the share *mesh axis* (its size must be >= t) standing
   in for the stacked share dim.
-* ``pjit(declassify_sum)`` — the sanctioned *plaintext* aggregation
+* ``jit(declassify_sum)`` — the sanctioned *plaintext* aggregation
   annotation (``core.secure_agg.declassify_sum``) used by the
   ``protect != "both"`` modes the paper allows: requires an actually
   aggregating reduction (>= 2 addends); SECRET -> PUBLIC with the site
@@ -43,7 +43,7 @@ Transitions the verifier recognizes (everything else joins its inputs):
 Violations: SECRET/PROTECTED reaching a host callback
 (``debug_callback`` / ``io_callback`` / ``pure_callback``) or any
 jaxpr output (outputs feed RoundReport telemetry and downstream hosts).
-Sub-jaxprs of pjit/scan/cond/while/shard_map are walked recursively
+Sub-jaxprs of jit/scan/cond/while/shard_map are walked recursively
 (scan and while to a carry fixpoint); shard_map pushes its mesh's axis
 sizes so collective rules know whether an axis actually aggregates.
 """
@@ -52,7 +52,7 @@ from __future__ import annotations
 import dataclasses
 import math
 
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 from .report import AnalysisReport, Finding
 
@@ -130,7 +130,7 @@ def _prod(shape):
     return math.prod(shape) if shape else 1
 
 
-# -- declassifier / transition rules for named pjit calls -----------------
+# -- declassifier / transition rules for named jit calls -----------------
 
 
 def _rule_protect_flat(eqn, ins, ctx, where):
@@ -273,9 +273,8 @@ def _eval_jaxpr(jaxpr, in_taints, ctx, path):
         where = f"{path}/eqn[{i}]:{_eqn_label(eqn)}"
         ins = [_read(env, a) for a in eqn.invars]
         outs = _eval_eqn(eqn, ins, ctx, where)
-        for v, t in zip(eqn.outvars, outs):
-            if not isinstance(v, jax_core.DropVar):
-                env[v] = t
+        # dropped outputs are never read, so binding them is harmless
+        env.update(zip(eqn.outvars, outs))
     return [_read(env, a) for a in jaxpr.outvars]
 
 
@@ -318,7 +317,7 @@ def _eval_eqn(eqn, ins, ctx, where):
             )
         return [PUBLIC] * len(eqn.outvars)
 
-    if prim == "pjit":
+    if prim == "jit":
         name = params.get("name", "")
         rule = _PJIT_RULES.get(name)
         if rule is not None:

@@ -77,7 +77,7 @@ class PackedPartitions:
 
 @functools.partial(jax.jit, static_argnames=("n_max", "dtype"))
 def _stack_pad(xs, ys, n_max: int, dtype):
-    """One fused graph for pad + stack + the f32 MXU-operand cast."""
+    """One graph for pad + stack of the payload and the labels."""
     Xs = jnp.stack([
         jnp.pad(jnp.asarray(X, dtype), ((0, n_max - X.shape[0]), (0, 0)))
         for X in xs
@@ -86,8 +86,14 @@ def _stack_pad(xs, ys, n_max: int, dtype):
         jnp.pad(jnp.asarray(y, jnp.float64), (0, n_max - y.shape[0]))
         for y in ys
     ])
-    X32 = Xs if Xs.dtype == jnp.float32 else Xs.astype(jnp.float32)
-    return Xs, X32, ys_
+    return Xs, ys_
+
+
+# The f32 MXU-operand cast is a program of its own: emitted in the same
+# graph as the float64 pad + stack, XLA:CPU (jax 0.9) fused the two
+# outputs into one loop that read the wrong rows and left padding
+# uninitialized for some ragged shapes (e.g. 6/52/53 rows of width 4).
+_to_f32 = jax.jit(lambda X: X.astype(jnp.float32))
 
 
 # LRU pack cache for pack_partitions.  jax arrays are immutable, so the
@@ -155,9 +161,9 @@ def pack_partitions(
     the cached pack (a small LRU, so alternating studies or churned
     cohorts each keep their pack resident).  The padded copies (plus the
     f32 MXU operand) replace S live partition references, traded for a
-    loop-free iteration.  Pad/stack/cast run as one jitted graph (a few
-    hundred MB of pure memory movement at benchmark scale; doing it
-    eagerly per part costs 2-3x that).  ``dtype`` is the X payload:
+    loop-free iteration.  Pad/stack run as one jitted graph and the f32
+    cast as a second (a few hundred MB of pure memory movement at
+    benchmark scale; doing it eagerly per part costs 2-3x that).  ``dtype`` is the X payload:
     float64 keeps the exact oracle payload (plus a separate f32 MXU
     operand); float32 stores one f32 buffer total — the TPU layout.
     """
@@ -181,10 +187,11 @@ def pack_partitions(
     counts = np.asarray([Xj.shape[0] for Xj in (p[0] for p in parts)],
                         np.int32)
     n_max = int(counts.max())
-    Xs, X32, ys = _stack_pad(
+    Xs, ys = _stack_pad(
         [p[0] for p in parts], [p[1] for p in parts], n_max,
         jnp.dtype(dtype).name,
     )
+    X32 = Xs if Xs.dtype == jnp.float32 else _to_f32(Xs)
     packed = PackedPartitions(Xs, X32, ys, jnp.asarray(counts))
     if cacheable:
         # evict-on-collect: if ANY part buffer dies, the ids in `key` may
@@ -332,7 +339,6 @@ def batched_cv_summaries(
     fold_ids: jnp.ndarray,
     fold_of: jnp.ndarray,
     backend: str = "pallas",
-    interpret: bool = True,
     block_n: int = 512,
 ) -> CVSummaries:
     """All (config, institution) train summaries + held-out metrics in one
@@ -346,8 +352,7 @@ def batched_cv_summaries(
 
     * "reference" — f64 end to end (per-round-parity rung),
     * "pallas"    — the kernel layout: f32 Gram, f64 g/dev
-      (``interpret=True`` runs the XLA simulation, exactly like the
-      non-CV path),
+      (the CPU runs the XLA simulation, exactly like the non-CV path),
     * "mixed"     — f64 g/dev + chunked split-accumulation f32 Gram.
 
     The Gram on every rung runs as a ``lax.map`` over the config axis so
@@ -362,7 +367,7 @@ def batched_cv_summaries(
 
         H, g, dev_tr, dev_va, acc_va, n_va = ops.fused_irls_cv(
             betas, packed.X, packed.y, fold_ids, fold_of,
-            counts=packed.counts, block_n=block_n, interpret=interpret,
+            counts=packed.counts, block_n=block_n,
             mxu_operand=packed.X32,
         )
         # train + held-out rows partition the valid rows exactly (also
@@ -419,7 +424,6 @@ def batched_local_summaries(
     beta: jnp.ndarray,
     packed: PackedPartitions,
     backend: str = "pallas",
-    interpret: bool = True,
     block_n: int = 512,
 ) -> LocalSummaries:
     """All S institutions' summaries in one launch.
@@ -440,12 +444,12 @@ def batched_local_summaries(
     if backend == "pallas":
         from ..kernels import ops
 
-        # interpret=True routes to the kernel's XLA simulation inside
-        # ops.fused_irls (block_n then has no effect); interpret=False
-        # compiles the blocked TPU kernel with VMEM-sized N tiles.
+        # the CPU runs the kernel's XLA simulation inside ops.fused_irls
+        # (block_n then has no effect); a TPU runs the compiled blocked
+        # kernel with VMEM-sized N tiles.
         H, g, dev = ops.fused_irls(
             beta, packed.X, packed.y, packed.counts,
-            block_n=block_n, interpret=interpret, mxu_operand=packed.X32,
+            block_n=block_n, mxu_operand=packed.X32,
         )
         # protocol dtype: the fixed-point encode needs f64 past 2**24
         H = H.astype(jnp.float64)

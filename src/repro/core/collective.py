@@ -73,7 +73,6 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
-from ..distributed.compat import axis_size as _compat_axis_size
 from ..distributed.sharding import POD_AXIS, SHARE_AXIS
 from ..obs import ledger as _ledger
 from ..obs.trace import traced as _traced
@@ -113,9 +112,10 @@ OUT_MODES = ("tree", "tile")
 def check_aggregation_headroom(num_addends: int, field: FieldSpec) -> None:
     """Guard the exact-uint64 share sum: ``S * max(p_r) < 2**64``.
 
-    Every aggregation path (streaming fold, batched reduction, in-SPMD
-    psum) accumulates reduced share elements (< p_r) in uint64 and applies
-    ONE trailing mod, which is exact iff the unreduced sum cannot wrap.
+    Every aggregation path (streaming fold, batched reduction, and the
+    recombined limbs of the in-SPMD sum, :func:`_exact_psum`) accumulates
+    reduced share elements (< p_r) in uint64 and applies ONE trailing
+    mod, which is exact iff the unreduced sum cannot wrap.
     This is the single shared bound — ~2**33 institutions for the 31-bit
     moduli — enforced here so no path carries its own (historically
     inconsistent) claim.
@@ -130,7 +130,7 @@ def check_aggregation_headroom(num_addends: int, field: FieldSpec) -> None:
 
 # ------------------------------------------------------------------------
 # The four named declassification boundaries.  Each is a triple: an impl
-# with a forced __name__/__qualname__ (the pjit equation name the static
+# with a forced __name__/__qualname__ (the jit equation name the static
 # taint verifier's rules match on), a jitted form, and a host wrapper
 # that records to the runtime privacy ledger before dispatching.  These
 # are the ONLY direct call sites of the boundary wrappers in the tree
@@ -142,7 +142,7 @@ def _declassify_sum_impl(x, axis: int = 0):
     return jnp.sum(x, axis=axis)
 
 
-# the pjit equation must be NAMED declassify_sum — that exact name is the
+# the jit equation must be NAMED declassify_sum — that exact name is the
 # key the static taint verifier's declassification rules match on
 _declassify_sum_impl.__name__ = "declassify_sum"
 _declassify_sum_impl.__qualname__ = "declassify_sum"
@@ -256,11 +256,11 @@ def _protect_flat_impl(key, buf, scheme: ShamirScheme, frac_bits: int,
     ).astype(jnp.uint32)  # (R, t-1, rows, 128)
     return ops.shamir_protect_flat(
         buf, coeffs, scheme.num_shares, field.moduli, frac_bits,
-        interpret=scheme.interpret, points=points,
+        points=points,
     )  # (len(points) or w, R, rows, 128) uint32
 
 
-# keep the pjit names the taint verifier's declassification rules key on
+# keep the jit names the taint verifier's declassification rules key on
 _protect_flat_impl.__name__ = "_protect_flat"
 _protect_flat_impl.__qualname__ = "_protect_flat"
 _protect_flat_jit = functools.partial(
@@ -287,7 +287,6 @@ def _reveal_flat_impl(buf, scheme: ShamirScheme, frac_bits: int,
 
     return ops.shamir_reveal_flat(
         buf, points, scheme.field.moduli, frac_bits,
-        interpret=scheme.interpret,
     )  # (rows, 128) float64
 
 
@@ -317,13 +316,13 @@ def _distributed_reveal_impl(agg_slice, scheme, codec, points, share_axis,
 
     ``agg_slice`` is this center's aggregated share slice (R, rows, 128)
     uint32.  Each center multiplies by its own public weight
-    ``L_j(0) mod p_r`` (field mul, uint64), then ONE psum over the share
-    axis + trailing mod yields the aggregate residues — exact because
-    the k partial products are each < p_r < 2**31 and k << 2**33
-    (the shared aggregation-headroom bound).  CRT decode is local.
+    ``L_j(0) mod p_r`` (field mul, uint64), then ONE exact sum over the
+    share axis (:func:`_exact_psum`) + trailing mod yields the aggregate
+    residues — the k partial products are each < p_r < 2**31.  CRT
+    decode is local.
 
     Jitted under its own name on purpose: the static privacy-flow gate
-    (:mod:`repro.analysis`) recognizes the ``_distributed_reveal`` pjit
+    (:mod:`repro.analysis`) recognizes the ``_distributed_reveal`` jit
     as the 2D mesh's ONE sanctioned declassification and checks its
     operand is the pod-aggregated share slice revealed over a
     threshold-satisfying share axis.
@@ -337,12 +336,12 @@ def _distributed_reveal_impl(agg_slice, scheme, codec, points, share_axis,
     w = jnp.take(lam, j, axis=1)  # (R,) this center's weight
     partial = (agg_slice.astype(jnp.uint64) * w[:, None, None]) \
         % field._bcast(agg_slice, 0)
-    summed = jax.lax.psum(partial, share_axis) % field._bcast(partial, 0)
+    summed = _exact_psum(partial, share_axis) % field._bcast(partial, 0)
     signed = crt_combine_signed(summed, field)
     return (signed.astype(jnp.float64) / codec.scale).astype(dtype)
 
 
-# the pjit equation must keep the exact name the static gate's
+# the jit equation must keep the exact name the static gate's
 # declassification rules match on
 _distributed_reveal_impl.__name__ = "_distributed_reveal"
 _distributed_reveal_impl.__qualname__ = "_distributed_reveal"
@@ -367,27 +366,55 @@ def _distributed_reveal(agg_slice, scheme, codec, points, share_axis,
                                    share_axis, dtype)
 
 
+# limb width of the exact cross-device sum: D addends of a 16-bit limb
+# stay below 2**32 for D < 2**16 devices
+_LIMB_BITS = 16
+
+
+def _exact_psum(x, axis_name: str, scatter_axis: int | None = None):
+    """Exact uint64 sum over a mesh axis of field elements (< 2**31).
+
+    XLA:TPU has no 64-bit collectives, and a uint32 sum of a few elements
+    near 2**31 wraps.  Each element therefore travels as two 16-bit limbs
+    in uint32 — the same 8 bytes per element a uint64 wire moves — whose
+    sums stay exact for fewer than 2**16 devices, and the limbs recombine
+    locally in uint64.  ``scatter_axis`` reduce-scatters that axis of
+    ``x`` (tiled) instead of all-reducing.
+    """
+    num_devices = jax.lax.axis_size(axis_name)
+    if num_devices >= 1 << _LIMB_BITS:
+        raise ValueError(
+            f"cannot sum over {num_devices} devices exactly: the 16-bit "
+            "limb sums would overflow uint32"
+        )
+    x = x.astype(jnp.uint32)
+    mask = jnp.uint32((1 << _LIMB_BITS) - 1)
+    limbs = jnp.stack([x & mask, x >> jnp.uint32(_LIMB_BITS)])
+    if scatter_axis is None:
+        summed = jax.lax.psum(limbs, axis_name)
+    else:
+        summed = jax.lax.psum_scatter(
+            limbs, axis_name, scatter_dimension=scatter_axis + 1, tiled=True,
+        )
+    return summed[0].astype(jnp.uint64) \
+        + (summed[1].astype(jnp.uint64) << jnp.uint64(_LIMB_BITS))
+
+
 def _field_allreduce(shares, axis_name: str, field: FieldSpec,
                      residue_axis: int = 1, scatter_axis: int | None = None):
     """Exact share-wise field sum over a mesh axis (Algorithm 2 on the wire).
 
-    The accumulation widens to uint64 so XLA's collective (which has no
-    per-hop modular reduction) stays exact — the shared
-    ``check_aggregation_headroom`` bound ``S * max(p_r) < 2**64`` — and a
-    single trailing mod returns the reduced wire dtype.  A deployment
-    fabric doing per-hop modular adds would move the reduced uint32
-    elements instead; the payload accounting counts those (see
-    ``benchmarks/secure_psum.py``).
+    XLA's collective has no per-hop modular reduction, so the sum is
+    taken exactly (:func:`_exact_psum`) and a single trailing mod returns
+    the reduced wire dtype.  A deployment fabric doing per-hop modular
+    adds would move the reduced uint32 elements instead; the payload
+    accounting counts those (see ``benchmarks/secure_psum.py``).
 
     ``scatter_axis=None`` all-reduces (every device gets the full summed
     buffer); an integer reduce-scatters that axis so each device keeps
     only its 1/D tile of the distributed residues.
     """
-    summed = jax.lax.psum(shares.astype(jnp.uint64), axis_name) \
-        if scatter_axis is None else jax.lax.psum_scatter(
-            shares.astype(jnp.uint64), axis_name,
-            scatter_dimension=scatter_axis, tiled=True,
-        )
+    summed = _exact_psum(shares, axis_name, scatter_axis)
     return (summed % field._bcast(summed, residue_axis)).astype(shares.dtype)
 
 
@@ -847,7 +874,7 @@ class SecureCollective:
                 "everywhere"
             )
         pts = self._validated_points(points)
-        num_devices = _compat_axis_size(axis_name)
+        num_devices = jax.lax.axis_size(axis_name)
         check_aggregation_headroom(num_devices, self.scheme.field)
         if self.overflow_check:
             # every device's contribution is bounded by capacity / D so the
@@ -921,13 +948,13 @@ class SecureCollective:
             raise ValueError("secure_psum_2d needs the flat-buffer wire "
                              "(pallas backend)")
         pts = self._validated_points(points)
-        k = _compat_axis_size(share_axis)
+        k = jax.lax.axis_size(share_axis)
         if k != len(pts):
             raise ValueError(
                 f"share axis has {k} devices but the reveal subset is "
                 f"{len(pts)} points — one center per revealed slice"
             )
-        num_pods = _compat_axis_size(pod_axis)
+        num_pods = jax.lax.axis_size(pod_axis)
         check_aggregation_headroom(num_pods, self.scheme.field)
         key = self.round_key(key, jax.lax.axis_index(pod_axis))
         buf, layout = pack_pytree(tree)

@@ -90,12 +90,12 @@ def stack_studies(studies) -> PackedPartitions:
 
 
 @functools.partial(
-    jax.jit, static_argnames=("agg", "protect", "l1", "interpret", "points",
+    jax.jit, static_argnames=("agg", "protect", "l1", "points",
                               "include_count", "summaries_backend")
 )
 def fused_multistudy_iteration(betas, key, X, X32, y, counts, lams,
                                agg: SecureCollective, protect: str,
-                               l1: float, interpret: bool,
+                               l1: float,
                                points: tuple[int, ...] | None = None,
                                include_count: bool = False,
                                summaries_backend: str = "pallas"):
@@ -122,7 +122,7 @@ def fused_multistudy_iteration(betas, key, X, X32, y, counts, lams,
     sms = [
         batched_local_summaries(
             betas[m], PackedPartitions(X[m], X32[m], y[m], counts[m]),
-            backend=summaries_backend, interpret=interpret,
+            backend=summaries_backend,
         )
         for m in range(num_studies)
     ]
@@ -159,8 +159,7 @@ def run_multistudy_rounds(studies, lams, num_rounds: int,
                           aggregator: SecureCollective | None = None,
                           protect: str = "both", l1: float = 0.0,
                           key: jax.Array | None = None,
-                          summaries_backend: str = "pallas",
-                          interpret: bool = True):
+                          summaries_backend: str = "pallas"):
     """Advance M studies ``num_rounds`` rounds, one collective round each.
 
     Host-loop convenience over :func:`fused_multistudy_iteration` (the
@@ -182,7 +181,7 @@ def run_multistudy_rounds(studies, lams, num_rounds: int,
     for r in range(num_rounds):
         betas, objs, _, _ = fused_multistudy_iteration(
             betas, agg.round_key(key, r), packed.X, packed.X32, packed.y,
-            packed.counts, lams, agg, protect, l1, interpret,
+            packed.counts, lams, agg, protect, l1,
             summaries_backend=summaries_backend,
         )
         trace.append(objs)

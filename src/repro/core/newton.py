@@ -174,16 +174,17 @@ def newton_step(
 
     This is the "securely derive beta_new" step (Algorithm 1, line 15)
     which operates on *revealed global aggregates* plus public
-    lambda/beta.  The regularized Hessian is SPD, but at protocol-scale d
-    the dense solve is sub-millisecond either way and the plain solve
-    lowers to one LAPACK call — the Cholesky/cho_solve pair costs several
-    custom-call round trips per iteration for no measurable accuracy or
-    speed gain at d <= 512.
+    lambda/beta.  The regularized Hessian X^T W X + lam I is SPD, so the
+    solve is a Cholesky factorization: one code path on every platform,
+    and the one XLA:TPU implements in float64 (its LU decomposition is
+    float32-only).
     """
     d = beta.shape[0]
     A = hessian + lam * jnp.eye(d, dtype=hessian.dtype)
     rhs = gradient - lam * beta
-    return beta + jnp.linalg.solve(A, rhs)
+    return beta + jax.scipy.linalg.cho_solve(
+        jax.scipy.linalg.cho_factor(A), rhs
+    )
 
 
 def _soft_threshold(x, t):
@@ -216,8 +217,9 @@ def prox_newton_step(
         return newton_step(beta, hessian, gradient, lam)
     d = beta.shape[0]
     A = hessian + lam * jnp.eye(d, dtype=hessian.dtype)
-    # Lipschitz constant of the quadratic part
-    L = jnp.linalg.norm(A, 2) + 1e-12
+    # Lipschitz constant of the quadratic part: A's largest eigenvalue
+    # (A is symmetric, so this is its spectral norm)
+    L = jnp.linalg.eigvalsh(A)[-1] + 1e-12
     # gradient of the smooth part at b: A (b - beta) - g + lam*beta
     #   (expand: H(b-beta) + lam*b - g ... careful) — derive:
     #   m_smooth(b) = -g^T(b-beta) + .5 (b-beta)^T H (b-beta) + lam/2 b^T b
@@ -298,12 +300,11 @@ def _iteration_bytes(d: int, num_parts: int, protect: str,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("agg", "protect", "l1", "interpret", "points",
+    jax.jit, static_argnames=("agg", "protect", "l1", "points",
                               "include_count", "summaries_backend")
 )
 def _fused_secure_iteration(beta, key, X, X32, y, counts, lam,
                             agg: SecureCollective, protect: str, l1: float,
-                            interpret: bool,
                             points: tuple[int, ...] | None = None,
                             include_count: bool = False,
                             summaries_backend: str = "pallas"):
@@ -332,7 +333,7 @@ def _fused_secure_iteration(beta, key, X, X32, y, counts, lam,
     """
     packed = PackedPartitions(X, X32, y, counts)
     sm = batched_local_summaries(
-        beta, packed, backend=summaries_backend, interpret=interpret
+        beta, packed, backend=summaries_backend
     )
     hessian, gradient, dev = sm.hessian, sm.gradient, sm.deviance
     revealed = {}
@@ -702,8 +703,7 @@ class SecureFitDriver:
         self.key, sub = jax.random.split(self.key)
         beta_new, obj, grad_norm, step_norm = _fused_secure_iteration(
             self.beta, sub, packed.X, packed.X32, packed.y, packed.counts,
-            self.lam, self.agg, self.protect, self.l1,
-            self.agg.scheme.interpret, points=pts,
+            self.lam, self.agg, self.protect, self.l1, points=pts,
             summaries_backend=self.summaries_backend,
         )
         # host-sync: the one readback per fused iteration — objective plus
@@ -769,8 +769,7 @@ class SecureFitDriver:
             jnp.asarray(self._round_base, jnp.int32),
             packed.X, packed.X32, packed.y, packed.counts, self.lam,
             agg=self.agg, protect=self.protect, l1=self.l1,
-            tol=float(self.tol), interpret=self.agg.scheme.interpret,
-            points=pts, include_count=False,
+            tol=float(self.tol), points=pts, include_count=False,
             summaries_backend=self.summaries_backend,
             num_rounds=num_rounds, num_parts=len(parts),
             max_rounds=num_rounds,

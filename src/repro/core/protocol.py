@@ -486,7 +486,7 @@ class StudyCoordinator:
         beta_new, obj, grad_norm, step_norm = _fused_secure_iteration(
             self.beta, sub, packed.X, packed.X32, packed.y, packed.counts,
             self.lam, self.agg, self.protect, 0.0,
-            self.agg.scheme.interpret, points=points, include_count=True,
+            points=points, include_count=True,
             summaries_backend=self.summaries_backend,
         )
         # host-sync: the round's one readback (secure_fit's twin) —
@@ -548,8 +548,7 @@ class StudyCoordinator:
             jnp.asarray(self._round_base, jnp.int32),
             packed.X, packed.X32, packed.y, packed.counts, self.lam,
             agg=self.agg, protect=self.protect, l1=0.0,
-            tol=float(self.tol), interpret=self.agg.scheme.interpret,
-            points=points, include_count=True,
+            tol=float(self.tol), points=points, include_count=True,
             summaries_backend=self.summaries_backend,
             num_rounds=num_rounds, num_parts=len(cohort),
             max_rounds=num_rounds,

@@ -61,14 +61,14 @@ def scan_rounds(round_fn, skip_fn, settled_fn, carry0, num_rounds: int):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("agg", "protect", "l1", "tol", "interpret", "points",
+    static_argnames=("agg", "protect", "l1", "tol", "points",
                      "include_count", "summaries_backend", "num_rounds",
                      "num_parts", "max_rounds"),
 )
 def fit_scan_block(beta, obj_prev, converged, iters, key, round_base,
                    X, X32, y, counts, lam,
                    agg: SecureCollective, protect: str, l1: float,
-                   tol: float, interpret: bool,
+                   tol: float,
                    points: tuple[int, ...] | None,
                    include_count: bool, summaries_backend: str,
                    num_rounds: int, num_parts: int, max_rounds: int):
@@ -114,7 +114,7 @@ def fit_scan_block(beta, obj_prev, converged, iters, key, round_base,
         beta, obj_prev, converged, iters, slot = carry
         kr = agg.round_key(key, slot)
         sm = batched_local_summaries(
-            beta, packed, backend=summaries_backend, interpret=interpret,
+            beta, packed, backend=summaries_backend,
         )
         tree = _protected_tree(protect, sm.hessian, sm.gradient,
                                sm.deviance)

@@ -18,7 +18,8 @@ Backends
 plain jnp — the correctness oracle, one dispatch per field op.
 ``backend="pallas"`` routes the same Horner/Lagrange loops through the
 TPU kernels (``kernels/shamir_poly.py`` / ``kernels/shamir_reconstruct.py``,
-16-bit-limb ``mulmod31``, interpret mode on CPU).  Given identical
+16-bit-limb ``mulmod31``; compiled on a TPU, interpreted on the CPU —
+``kernels.backend`` decides).  Given identical
 coefficients the two backends produce **bit-identical** shares and
 reconstructions — both compute exact field elements; only the word-size
 decomposition differs (``share_with_coeffs`` exposes the deterministic
@@ -69,7 +70,6 @@ class ShamirScheme:
     num_shares: int = 3  # w: total Computation Centers
     field: FieldSpec = FIELD_WIDE
     backend: str = "reference"  # "reference" (jnp oracle) | "pallas"
-    interpret: bool = True  # pallas interpret mode (CPU container default)
 
     def __post_init__(self):
         if not (1 <= self.threshold <= self.num_shares):
@@ -135,7 +135,7 @@ class ShamirScheme:
             out = ops.shamir_shares(
                 secret[r].reshape(-1).astype(jnp.uint32),
                 coeffs[r].reshape(t - 1, -1).astype(jnp.uint32),
-                w, p, interpret=self.interpret,
+                w, p,
             )  # (w, n) uint32
             per_residue.append(
                 out.astype(jnp.uint64).reshape((w,) + shape)
@@ -189,7 +189,7 @@ class ShamirScheme:
         for r, p in enumerate(field.moduli):
             rec = ops.shamir_reconstruct(
                 shares[:, r].reshape(k, -1).astype(jnp.uint32),
-                tuple(points), p, interpret=self.interpret,
+                tuple(points), p,
             )  # (n,) uint32
             per_residue.append(rec.astype(jnp.uint64).reshape(shape))
         return jnp.stack(per_residue, axis=0)  # (R, ...)

@@ -16,6 +16,7 @@ paper's.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 
 import jax
 import jax.numpy as jnp
@@ -60,7 +61,9 @@ def _logistic_table(key, n, d, num_inst, rho=0.3, dtype=jnp.float64):
 
 def load_study(name: str, seed: int = 0, scale: float = 1.0) -> Study:
     """``scale`` shrinks row counts for CI-speed runs (1.0 = paper size)."""
-    key = jax.random.PRNGKey(hash(name) % (2**31) + seed)
+    # a stable digest of the name: hash() is salted per process, so it
+    # would draw different rows in every run
+    key = jax.random.PRNGKey(zlib.crc32(name.encode()) % (2**31) + seed)
     def rows(n):
         return max(64, int(n * scale))
 

@@ -45,8 +45,7 @@ import jax
 import jax.numpy as jnp
 
 from ..obs import trace as _trace
-from .compat import axis_size, make_mesh, shard_map
-from .sharding import POD_AXIS, SHARE_AXIS
+from .sharding import POD_AXIS, SHARE_AXIS, make_mesh
 
 __all__ = [
     "SHARE_AXIS",
@@ -167,7 +166,7 @@ def scan_secure_rounds(tree, key, num_rounds: int, aggregator=None,
         raise ValueError(f"reveal must be one of {REVEAL_MODES}")
     pts = agg._validated_points(None)
     scheme, field = agg.scheme, agg.scheme.field
-    num_devices = axis_size(axis_name)
+    num_devices = jax.lax.axis_size(axis_name)
     check_aggregation_headroom(num_devices, field)
     key = agg.round_key(key, jax.lax.axis_index(axis_name))
 
@@ -185,7 +184,7 @@ def scan_secure_rounds(tree, key, num_rounds: int, aggregator=None,
         buf, coeffs, slot = carry
         shares = ops.shamir_protect_flat(
             buf, coeffs, scheme.num_shares, field.moduli,
-            agg.codec.frac_bits, interpret=scheme.interpret, points=pts,
+            agg.codec.frac_bits, points=pts,
         )
         if reveal == "replicated":
             summed = agg.allreduce(shares, axis_name)
@@ -224,7 +223,7 @@ def run_scanned_rounds(num_pods: int, tree, key, num_rounds: int,
     from jax.sharding import PartitionSpec as P
 
     mesh = pod_mesh(num_pods)
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         lambda: scan_secure_rounds(
             tree, key, num_rounds, aggregator=aggregator, reveal=reveal,
             dtype=dtype,

@@ -21,10 +21,10 @@ import re
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
-__all__ = ["MeshRules", "POD_AXIS", "SHARE_AXIS", "param_pspec",
-           "param_shardings"]
+__all__ = ["MeshRules", "POD_AXIS", "SHARE_AXIS", "make_mesh",
+           "param_pspec", "param_shardings"]
 
 # The institution axis: one paper party per pod.  secure_psum's share
 # reductions (and the sharded reveal's reduce-scatter) run over this axis.
@@ -36,6 +36,15 @@ POD_AXIS = "pod"
 # a psum of Lagrange-weighted slices over this axis.  Orthogonal to
 # POD_AXIS.
 SHARE_AXIS = "share"
+
+
+def make_mesh(axis_shapes, axis_names, devices=None) -> Mesh:
+    """``jax.make_mesh`` with Auto axes, so the partitioner places the
+    collectives (jax's own default is Explicit axes)."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(AxisType.Auto,) * len(axis_names), devices=devices,
+    )
 
 
 @dataclasses.dataclass(frozen=True)

@@ -136,10 +136,15 @@ def mesh_env(
     The subprocess-launch twin of :func:`apply_xla_flags`: never touches
     this process's environment (so the parent's already-initialized jax
     is irrelevant), which is how the CPU-mesh CI jobs and the multihost
-    benchmark give each worker its own forced device count.
+    benchmark give each worker its own forced device count.  A forced
+    host device count is a CPU mesh, so the child is also held to the
+    CPU backend: an accelerator belongs to one process, and a parent
+    that touched JAX already holds it.
     """
     env = dict(os.environ if base is None else base)
     env["XLA_FLAGS"] = _build(
         host_device_count, latency_hiding, extra, env.get("XLA_FLAGS", "")
     )
+    if host_device_count is not None:
+        env["JAX_PLATFORMS"] = "cpu"
     return env

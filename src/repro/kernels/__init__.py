@@ -1,8 +1,10 @@
 """Pallas TPU kernels for the paper's compute hot spots.
 
 Each kernel: <name>.py (pl.pallas_call + BlockSpec), a jit'd wrapper in
-ops.py, and a pure-jnp oracle in ref.py.  Validated in interpret mode on CPU
-(tests/test_kernels.py); written against TPU VMEM/MXU semantics.
+ops.py, and a pure-jnp oracle in ref.py.  ``backend.py`` compiles them on a
+TPU and interprets them on the CPU.  Validated through the interpreter
+(tests/test_kernels.py) and compiled for a described v5e
+(tests/test_tpu_compile.py).
 
 The secure-aggregation pipeline is fully kernelized: ``shamir_poly``
 (share generation + fused fixed-point encode) and ``shamir_reconstruct``

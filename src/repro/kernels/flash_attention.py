@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .backend import resolve_interpret
+
 __all__ = ["flash_attention_pallas"]
 
 NEG_INF = -1e30
@@ -100,11 +102,12 @@ def flash_attention_pallas(
     seq_len: int,     # true (unpadded) length, for masking
     block_q: int = 512,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Causal flash attention -> (o, m, l).  Caller pads S to a block
-    multiple and D to 128 (ops.py).  interpret=True executes on CPU; the
-    (m, l) softmax statistics feed the backward kernels."""
+    multiple and D to 128 (ops.py).  ``interpret=None`` follows the
+    backend (``kernels.backend``); the (m, l) softmax statistics feed
+    the backward kernels."""
     BH, S, D = q.shape
     assert S % block_q == 0 and S % block_k == 0, "caller pads S"
     nq, nk = S // block_q, S // block_k
@@ -138,5 +141,5 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, 1), jnp.float32),   # l (running denom)
             pltpu.VMEM((block_q, D), jnp.float32),   # acc
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

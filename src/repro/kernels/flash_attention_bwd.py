@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .backend import resolve_interpret
+
 __all__ = ["flash_dq_pallas", "flash_dkdv_pallas"]
 
 NEG_INF = -1e30
@@ -133,7 +135,7 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, linv_ref, delta_ref,
 @functools.partial(jax.jit, static_argnames=(
     "group", "seq_len", "block_q", "block_k", "interpret"))
 def flash_dq_pallas(q, k, v, do, m, linv, delta, group, seq_len,
-                    block_q=512, block_k=512, interpret=True):
+                    block_q=512, block_k=512, interpret=None):
     """dq: q/do (B*H, S, D); k/v (B*KVH, S, D); m/linv/delta (B*H, S)."""
     BH, S, D = q.shape
     nq, nk = S // block_q, S // block_k
@@ -157,14 +159,14 @@ def flash_dq_pallas(q, k, v, do, m, linv, delta, group, seq_len,
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v, do, m, linv, delta)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "group", "seq_len", "block_q", "block_k", "interpret"))
 def flash_dkdv_pallas(q, k, v, do, m, linv, delta, group, seq_len,
-                      block_q=512, block_k=512, interpret=True):
+                      block_q=512, block_k=512, interpret=None):
     """dk, dv: shapes as in flash_dq_pallas; returns (B*KVH, S, D) pair."""
     BH, S, D = q.shape
     BKV = k.shape[0]
@@ -194,6 +196,6 @@ def flash_dkdv_pallas(q, k, v, do, m, linv, delta, group, seq_len,
                    jax.ShapeDtypeStruct((BKV, S, D), v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v, do, m, linv, delta)
     return dk, dv

@@ -26,7 +26,15 @@ main payload in float64 — on TPU both refs alias one f32 array).  The
 gradient/deviance accumulate in the payload dtype.  H only preconditions
 the Newton step — the fixed point solves g(beta) = lam beta — so f32 H
 changes the trajectory, not the answer; g/dev precision is what bounds the
-final beta and the deviance-based convergence test.
+final beta and the deviance-based convergence test.  Mosaic has no 64-bit
+types, so a compiled launch sees f32 operands only: with a float64 payload
+``ops.fused_irls`` takes the Gram from the kernel and the float64 g/dev
+from XLA ops beside it.
+
+TPU layout: labels (and fold ids) stream as lane-dense (1, block_n) rows,
+per-institution counts (and held-out folds) ride in SMEM as scalar
+prefetch, and the small outputs are (1, d) / (1, 128) rows — every block
+obeys the (8, 128) tiling rule.
 """
 from __future__ import annotations
 
@@ -35,15 +43,47 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .backend import ZERO, resolve_interpret
 
 __all__ = ["fused_irls_pallas", "fused_irls_sim", "fused_irls_cv_pallas",
            "fused_irls_cv_sim", "gram_hessian_pallas"]
 
 DEFAULT_BLOCK_N = 512
 
+# f32 matmuls at full f32 precision on the MXU (the TPU default would
+# round operands to bfloat16); a no-op for the interpreter's f64 payload.
+_HIGHEST = jax.lax.Precision.HIGHEST
 
-def _irls_kernel(beta_ref, x_ref, xm_ref, y_ref, cnt_ref,
+
+def _row_dot(a, b):
+    """(1, n) @ (n, m) -> (1, m) in a's dtype."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (0,)), ((), ())), precision=_HIGHEST,
+        preferred_element_type=a.dtype,
+    )
+
+
+def _linear_row(beta, x):
+    """z = x beta as a lane-dense (1, block_n) row: beta (1, d) . x^T."""
+    return jax.lax.dot_general(
+        beta, x, (((1,), (1,)), ((), ())), precision=_HIGHEST,
+        preferred_element_type=x.dtype,
+    )
+
+
+def _weighted_gram(xm, w):
+    """X^T diag(w) X from the f32 MXU operand; ``w`` a (1, block_n) row."""
+    return jax.lax.dot_general(
+        xm.T * w.astype(jnp.float32), xm, (((1,), (0,)), ((), ())),
+        precision=_HIGHEST, preferred_element_type=jnp.float32,
+    )
+
+
+def _irls_kernel(cnt_ref, beta_ref, x_ref, xm_ref, y_ref,
                  h_ref, g_ref, dev_ref, *, block_n):
+    s = pl.program_id(0)
     i = pl.program_id(1)
 
     @pl.when(i == 0)
@@ -53,28 +93,19 @@ def _irls_kernel(beta_ref, x_ref, xm_ref, y_ref, cnt_ref,
         dev_ref[...] = jnp.zeros_like(dev_ref)
 
     x = x_ref[0]  # (block_n, d) payload dtype
-    y = y_ref[0]  # (block_n,)
-    beta = beta_ref[...].astype(x.dtype)  # (d,)
+    y = y_ref[0]  # (1, block_n) lane-dense labels
+    beta = beta_ref[...].astype(x.dtype)  # (1, d)
     # ragged mask: absolute row id vs this institution's true row count
-    row = i * block_n + jax.lax.broadcasted_iota(
-        jnp.int32, (block_n, 1), 0
-    )[:, 0]
-    valid = (row < cnt_ref[0]).astype(x.dtype)  # (block_n,)
+    row = i * block_n + jax.lax.broadcasted_iota(jnp.int32, (1, block_n), 1)
+    valid = (row < cnt_ref[s]).astype(x.dtype)  # (1, block_n)
 
-    z = x @ beta  # (block_n,)
+    z = _linear_row(beta, x)  # (1, block_n)
     p = jax.nn.sigmoid(z)
     w = (p * (1.0 - p)) * valid  # IRLS weights: VMEM-resident only
-    resid = (y - p) * valid
-    g_ref[0] += x.T @ resid
+    g_ref[0] += _row_dot((y - p) * valid, x)
     softplus = jnp.logaddexp(jnp.zeros_like(z), z)
     dev_ref[0] += -2.0 * jnp.sum((y * z - softplus) * valid)
-    # MXU Gram update in f32; weights fold into the left operand
-    xm = xm_ref[0]  # (block_n, d) float32
-    h_ref[0] += jax.lax.dot_general(
-        xm * w.astype(jnp.float32)[:, None], xm,
-        (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    h_ref[0] += _weighted_gram(xm_ref[0], w)  # MXU Gram update in f32
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -85,40 +116,77 @@ def fused_irls_pallas(
     y: jnp.ndarray,  # (S, N_max) payload dtype
     counts: jnp.ndarray,  # (S,) int32 true row counts (<= N_max)
     block_n: int = DEFAULT_BLOCK_N,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """All-institution summaries in one launch.
 
     Returns (H (S, d, d) f32, g (S, d), dev (S,)); g/dev in X.dtype.
     N_max % block_n == 0 and d % 128 == 0 (ops.py pads); rows >= counts[s]
-    are masked out, so tail padding may hold anything.
+    are masked out, so tail padding may hold anything.  Compiled, a
+    multi-block ``block_n`` must be a multiple of 128: labels stream as
+    lane-dense (1, block_n) rows, and the row counts ride in SMEM as
+    scalar prefetch.
     """
     s_dim, n, d = X.shape
     assert n % block_n == 0, "caller pads N_max"
-    grid = (s_dim, n // block_n)
     kernel = functools.partial(_irls_kernel, block_n=block_n)
-    return pl.pallas_call(
+    H, g, dev = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((d,), lambda s, i: (0,)),
-            pl.BlockSpec((1, block_n, d), lambda s, i: (s, i, 0)),
-            pl.BlockSpec((1, block_n, d), lambda s, i: (s, i, 0)),
-            pl.BlockSpec((1, block_n), lambda s, i: (s, i)),
-            pl.BlockSpec((1,), lambda s, i: (s,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, d, d), lambda s, i: (s, 0, 0)),
-            pl.BlockSpec((1, d), lambda s, i: (s, 0)),
-            pl.BlockSpec((1,), lambda s, i: (s,)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(s_dim, n // block_n),
+            in_specs=[
+                pl.BlockSpec((1, d), lambda s, i, c: (ZERO, ZERO)),
+                pl.BlockSpec((1, block_n, d), lambda s, i, c: (s, i, ZERO)),
+                pl.BlockSpec((1, block_n, d), lambda s, i, c: (s, i, ZERO)),
+                pl.BlockSpec((1, 1, block_n), lambda s, i, c: (s, ZERO, i)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, d, d), lambda s, i, c: (s, ZERO, ZERO)),
+                pl.BlockSpec((1, 1, d), lambda s, i, c: (s, ZERO, ZERO)),
+                pl.BlockSpec((1, 1, 128), lambda s, i, c: (s, ZERO, ZERO)),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((s_dim, d, d), jnp.float32),
-            jax.ShapeDtypeStruct((s_dim, d), X.dtype),
-            jax.ShapeDtypeStruct((s_dim,), X.dtype),
+            jax.ShapeDtypeStruct((s_dim, 1, d), X.dtype),
+            jax.ShapeDtypeStruct((s_dim, 1, 128), X.dtype),
         ],
-        interpret=interpret,
-    )(beta, X, Xm, y, counts)
+        interpret=resolve_interpret(interpret),
+    )(counts.astype(jnp.int32), beta.reshape(1, d), X, Xm,
+      y.reshape(s_dim, 1, n))
+    return H, g[:, 0], dev[:, 0, 0]
+
+
+def _sim_terms(beta, X, y, counts):
+    """The simulation's f64 z/g/dev terms and the f32 IRLS weights."""
+    n = X.shape[1]
+    mask = (
+        jnp.arange(n, dtype=jnp.int32)[None, :] < counts[:, None]
+    ).astype(jnp.float64)
+    if X.dtype == jnp.float32:
+        z = jax.lax.dot_general(
+            X, beta.astype(jnp.float32), (((2,), (0,)), ((), ())),
+            preferred_element_type=jnp.float64,
+        )
+    else:
+        z = jnp.einsum("snd,d->sn", X, beta.astype(X.dtype))
+    p = jax.nn.sigmoid(z)
+    w32 = ((p * (1.0 - p)) * mask).astype(jnp.float32)
+    resid = (y - p) * mask
+    if X.dtype == jnp.float32:
+        r32 = resid.astype(jnp.float32)
+        g = jnp.stack([
+            jax.lax.dot_general(
+                r32[j], X[j], (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float64,
+            )
+            for j in range(X.shape[0])
+        ])
+    else:
+        g = jnp.einsum("snd,sn->sd", X, resid)
+    dev = -2.0 * jnp.sum((y * z - jnp.logaddexp(0.0, z)) * mask, axis=1)
+    return w32, g, dev
 
 
 @jax.jit
@@ -127,14 +195,14 @@ def fused_irls_sim(beta, X, Xm, y, counts):
     contract (f32 Gram accumulation from the MXU operand, g/dev in the
     payload dtype, row masks), evaluated as plain XLA ops.
 
-    This is what ``interpret=True`` callers run at production sizes: the
-    Pallas interpreter emulates every grid program with whole-operand
+    This is what the CPU runs at production sizes: the Pallas
+    interpreter emulates every grid program with whole-operand
     copies, which at (S, 2e5, d) costs ~6x the arithmetic itself on CPU.
     The blocked kernel remains the compiled TPU path; tests pin the two
     against each other (they differ only in f32 summation order).
 
-    One deliberate upgrade over the TPU kernel: with a float32 payload
-    the kernel accumulates g/dev in f32 (the hardware dtype); the sim
+    One deliberate upgrade over the kernel: with a float32 payload the
+    kernel accumulates g/dev in f32 (the hardware dtype); the sim
     always accumulates them in f64 (free on CPU via
     ``preferred_element_type``), which keeps the secure protocol's
     fixed-point codec the dominant error term.  The kernel == sim
@@ -151,39 +219,14 @@ def fused_irls_sim(beta, X, Xm, y, counts):
     into the Gram contraction instead of materializing a scaled copy of
     Xm.
     """
-    s_dim, n = X.shape[0], X.shape[1]
-    mask = (
-        jnp.arange(n, dtype=jnp.int32)[None, :] < counts[:, None]
-    ).astype(jnp.float64)
-    if X.dtype == jnp.float32:
-        z = jax.lax.dot_general(
-            X, beta.astype(jnp.float32), (((2,), (0,)), ((), ())),
-            preferred_element_type=jnp.float64,
-        )
-    else:
-        z = jnp.einsum("snd,d->sn", X, beta.astype(X.dtype))
-    p = jax.nn.sigmoid(z)
-    w32 = ((p * (1.0 - p)) * mask).astype(jnp.float32)
+    w32, g, dev = _sim_terms(beta, X, y, counts)
     H = jnp.stack([
         jnp.einsum(
             "n,ni,nj->ij", w32[j], Xm[j], Xm[j],
             preferred_element_type=jnp.float32,
         )
-        for j in range(s_dim)
+        for j in range(X.shape[0])
     ])
-    resid = (y - p) * mask
-    if X.dtype == jnp.float32:
-        r32 = resid.astype(jnp.float32)
-        g = jnp.stack([
-            jax.lax.dot_general(
-                r32[j], X[j], (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float64,
-            )
-            for j in range(s_dim)
-        ])
-    else:
-        g = jnp.einsum("snd,sn->sd", X, resid)
-    dev = -2.0 * jnp.sum((y * z - jnp.logaddexp(0.0, z)) * mask, axis=1)
     return H, g, dev
 
 
@@ -200,9 +243,11 @@ def fused_irls_sim(beta, X, Xm, y, counts):
 # fold ids are never negative, so the val mask is empty and the train mask
 # reduces to the plain row mask).
 
-def _irls_cv_kernel(beta_ref, x_ref, xm_ref, y_ref, cnt_ref, fid_ref,
-                    fold_ref, h_ref, g_ref, dtr_ref, dva_ref, acc_ref,
+def _irls_cv_kernel(cnt_ref, fold_ref, beta_ref, x_ref, xm_ref, y_ref,
+                    fid_ref, h_ref, g_ref, dtr_ref, dva_ref, acc_ref,
                     nva_ref, *, block_n):
+    c = pl.program_id(0)
+    s = pl.program_id(1)
     i = pl.program_id(2)
 
     @pl.when(i == 0)
@@ -215,32 +260,25 @@ def _irls_cv_kernel(beta_ref, x_ref, xm_ref, y_ref, cnt_ref, fid_ref,
         nva_ref[...] = jnp.zeros_like(nva_ref)
 
     x = x_ref[0]  # (block_n, d) payload dtype
-    y = y_ref[0]  # (block_n,)
-    beta = beta_ref[0].astype(x.dtype)  # (d,) — this config's iterate
-    row = i * block_n + jax.lax.broadcasted_iota(
-        jnp.int32, (block_n, 1), 0
-    )[:, 0]
-    valid = row < cnt_ref[0]  # ragged row mask
-    hold = jnp.logical_and(valid, fid_ref[0] == fold_ref[0])
+    y = y_ref[0]  # (1, block_n) lane-dense labels
+    beta = beta_ref[0].astype(x.dtype)  # (1, d) — this config's iterate
+    row = i * block_n + jax.lax.broadcasted_iota(jnp.int32, (1, block_n), 1)
+    valid = row < cnt_ref[s]  # ragged row mask
+    hold = jnp.logical_and(valid, fid_ref[0] == fold_ref[c])
     tmask = jnp.logical_and(valid, jnp.logical_not(hold)).astype(x.dtype)
     vmask = hold.astype(x.dtype)
 
-    z = x @ beta
+    z = _linear_row(beta, x)  # (1, block_n)
     p = jax.nn.sigmoid(z)
     w = (p * (1.0 - p)) * tmask  # train-fold IRLS weights, VMEM-only
-    g_ref[0, 0] += x.T @ ((y - p) * tmask)
+    g_ref[0, 0] += _row_dot((y - p) * tmask, x)
     ll = y * z - jnp.logaddexp(jnp.zeros_like(z), z)
     dtr_ref[0, 0] += -2.0 * jnp.sum(ll * tmask)
     dva_ref[0, 0] += -2.0 * jnp.sum(ll * vmask)
     correct = (z > 0.0) == (y > 0.5)
     acc_ref[0, 0] += jnp.sum(jnp.where(correct, vmask, 0.0))
     nva_ref[0, 0] += jnp.sum(vmask)
-    xm = xm_ref[0]  # (block_n, d) float32 MXU operand
-    h_ref[0, 0] += jax.lax.dot_general(
-        xm * w.astype(jnp.float32)[:, None], xm,
-        (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    h_ref[0, 0] += _weighted_gram(xm_ref[0], w)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -253,65 +291,62 @@ def fused_irls_cv_pallas(
     fold_ids: jnp.ndarray,  # (S, N_max) int32 per-row fold assignment
     fold_of: jnp.ndarray,  # (C,) int32 held-out fold per config (-1: none)
     block_n: int = DEFAULT_BLOCK_N,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Every (config, institution) train summary + held-out metric in one
     launch: H (C, S, d, d) f32, g (C, S, d), dev_train (C, S),
     dev_val (C, S), correct_val (C, S), count_val (C, S); g and the
     scalar reductions in X.dtype.  Grid (C, S, N/block_n): X streams
     through VMEM once per config with the fold mask applied in-register.
+    Blocking and layout follow ``fused_irls_pallas``; ``counts`` and
+    ``fold_of`` are the scalar prefetch.
     """
     c_dim = betas.shape[0]
     s_dim, n, d = X.shape
     assert n % block_n == 0, "caller pads N_max"
-    grid = (c_dim, s_dim, n // block_n)
     kernel = functools.partial(_irls_cv_kernel, block_n=block_n)
-    scalar = lambda: jax.ShapeDtypeStruct((c_dim, s_dim), X.dtype)
-    return pl.pallas_call(
+    scalar_spec = pl.BlockSpec((1, 1, 1, 128),
+                               lambda c, s, i, *_: (c, s, ZERO, ZERO))
+    scalar = jax.ShapeDtypeStruct((c_dim, s_dim, 1, 128), X.dtype)
+    row_spec = pl.BlockSpec((1, 1, block_n),
+                            lambda c, s, i, *_: (s, ZERO, i))
+    tile_spec = pl.BlockSpec((1, block_n, d),
+                             lambda c, s, i, *_: (s, i, ZERO))
+    H, g, *stats = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, d), lambda c, s, i: (c, 0)),
-            pl.BlockSpec((1, block_n, d), lambda c, s, i: (s, i, 0)),
-            pl.BlockSpec((1, block_n, d), lambda c, s, i: (s, i, 0)),
-            pl.BlockSpec((1, block_n), lambda c, s, i: (s, i)),
-            pl.BlockSpec((1,), lambda c, s, i: (s,)),
-            pl.BlockSpec((1, block_n), lambda c, s, i: (s, i)),
-            pl.BlockSpec((1,), lambda c, s, i: (c,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, d, d), lambda c, s, i: (c, s, 0, 0)),
-            pl.BlockSpec((1, 1, d), lambda c, s, i: (c, s, 0)),
-            pl.BlockSpec((1, 1), lambda c, s, i: (c, s)),
-            pl.BlockSpec((1, 1), lambda c, s, i: (c, s)),
-            pl.BlockSpec((1, 1), lambda c, s, i: (c, s)),
-            pl.BlockSpec((1, 1), lambda c, s, i: (c, s)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(c_dim, s_dim, n // block_n),
+            in_specs=[
+                pl.BlockSpec((1, 1, d), lambda c, s, i, *_: (c, ZERO, ZERO)),
+                tile_spec,
+                tile_spec,
+                row_spec,
+                row_spec,
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, d, d),
+                             lambda c, s, i, *_: (c, s, ZERO, ZERO)),
+                pl.BlockSpec((1, 1, 1, d),
+                             lambda c, s, i, *_: (c, s, ZERO, ZERO)),
+                scalar_spec, scalar_spec, scalar_spec, scalar_spec,
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((c_dim, s_dim, d, d), jnp.float32),
-            jax.ShapeDtypeStruct((c_dim, s_dim, d), X.dtype),
-            scalar(), scalar(), scalar(), scalar(),
+            jax.ShapeDtypeStruct((c_dim, s_dim, 1, d), X.dtype),
+            scalar, scalar, scalar, scalar,
         ],
-        interpret=interpret,
-    )(betas, X, Xm, y, counts, fold_ids, fold_of)
+        interpret=resolve_interpret(interpret),
+    )(counts.astype(jnp.int32), fold_of.astype(jnp.int32),
+      betas.reshape(c_dim, 1, d), X, Xm, y.reshape(s_dim, 1, n),
+      fold_ids.astype(jnp.int32).reshape(s_dim, 1, n))
+    return (H, g[:, :, 0], *(v[:, :, 0, 0] for v in stats))
 
 
-@jax.jit
-def fused_irls_cv_sim(betas, X, Xm, y, counts, fold_ids, fold_of):
-    """Functional simulation of ``fused_irls_cv_pallas`` as plain XLA ops
-    — the CPU/interpret execution shape at production N, mirroring
-    ``fused_irls_sim``'s contracts: f32 Gram accumulation from the MXU
-    operand, f64 gradient/deviance accumulation regardless of payload
-    dtype, fold∘row masks identical to the kernel.
-
-    Contraction styles follow the same CPU-emitter measurements as the
-    non-CV sim: the O(C S N d) z/g reductions run as clean 2D gemms
-    (z batched over configs, g unrolled per institution), while the
-    O(C S N d^2) Gram — the flop wall — runs as a ``lax.map`` over the
-    config axis of per-institution 2D contractions, so the traced graph
-    stays small at any path length while each contraction hits the fast
-    gemm emitter.
-    """
+def _cv_sim_terms(betas, X, y, counts, fold_ids, fold_of):
+    """The CV simulation's f64 terms: train weights (f32), g, train/val
+    deviance, held-out correct and count."""
     s_dim, n = X.shape[0], X.shape[1]
     row_ok = jnp.arange(n, dtype=jnp.int32)[None, :] < counts[:, None]
     on_fold = fold_ids[None] == fold_of[:, None, None]  # (C, S, N)
@@ -340,6 +375,29 @@ def fused_irls_cv_sim(betas, X, Xm, y, counts, fold_ids, fold_of):
         for s in range(s_dim)
     ], axis=1)  # (C, S, d)
     w32 = ((p * (1.0 - p)) * tmask).astype(jnp.float32)
+    return w32, g, dev_tr, dev_va, acc_va, n_va
+
+
+@jax.jit
+def fused_irls_cv_sim(betas, X, Xm, y, counts, fold_ids, fold_of):
+    """Functional simulation of ``fused_irls_cv_pallas`` as plain XLA ops
+    — the CPU/interpret execution shape at production N, mirroring
+    ``fused_irls_sim``'s contracts: f32 Gram accumulation from the MXU
+    operand, f64 gradient/deviance accumulation regardless of payload
+    dtype, fold∘row masks identical to the kernel.
+
+    Contraction styles follow the same CPU-emitter measurements as the
+    non-CV sim: the O(C S N d) z/g reductions run as clean 2D gemms
+    (z batched over configs, g unrolled per institution), while the
+    O(C S N d^2) Gram — the flop wall — runs as a ``lax.map`` over the
+    config axis of per-institution 2D contractions, so the traced graph
+    stays small at any path length while each contraction hits the fast
+    gemm emitter.
+    """
+    s_dim = X.shape[0]
+    w32, g, dev_tr, dev_va, acc_va, n_va = _cv_sim_terms(
+        betas, X, y, counts, fold_ids, fold_of
+    )
 
     def gram_one_config(w_c):  # (S, N) f32 -> (S, d, d) f32
         return jnp.stack([
@@ -363,35 +421,30 @@ def _gram_kernel(x_ref, w_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...].astype(jnp.float32)  # (block_n, d)
-    xw = x * w_ref[...].astype(jnp.float32)[:, None]
-    o_ref[...] += jax.lax.dot_general(
-        xw, x, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    o_ref[...] += _weighted_gram(x_ref[...].astype(jnp.float32), w_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def gram_hessian_pallas(
     X: jnp.ndarray, w: jnp.ndarray, block_n: int = DEFAULT_BLOCK_N,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """X^T diag(w) X for caller-supplied weights (X: (N, d), N % block_n
     == 0, d % 128 == 0 — ops.py pads).  The secure-fit hot path derives w
     from beta inside ``fused_irls_pallas`` instead; this variant stays for
     workloads that reweight rows externally (e.g. offset/exposure models).
+    The weights stream as a lane-dense (1, N) row.
     """
     n, d = X.shape
     assert n % block_n == 0, "caller pads N"
-    grid = (n // block_n,)
     return pl.pallas_call(
         _gram_kernel,
-        grid=grid,
+        grid=(n // block_n,),
         in_specs=[
-            pl.BlockSpec((block_n, d), lambda i: (i, 0)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
+            pl.BlockSpec((block_n, d), lambda i: (i, ZERO)),
+            pl.BlockSpec((1, block_n), lambda i: (ZERO, i)),
         ],
-        out_specs=pl.BlockSpec((d, d), lambda i: (0, 0)),
+        out_specs=pl.BlockSpec((d, d), lambda i: (ZERO, ZERO)),
         out_shape=jax.ShapeDtypeStruct((d, d), jnp.float32),
-        interpret=interpret,
-    )(X, w)
+        interpret=resolve_interpret(interpret),
+    )(X, w.reshape(1, n))

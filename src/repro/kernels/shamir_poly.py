@@ -16,22 +16,29 @@ the b1 < 2**15 case, keeping every intermediate in range.  This replaces the
 big-int field arithmetic a CPU implementation would use — same field, same
 security, MXU/VPU-native word sizes.
 
-Grid: secrets reshaped to (rows, 128) lanes by ops.py; one program per
-(block_rows, 128) tile computes all w shares for its tile (w is small and
-static).  Working set: (t-1 + 1 + w) * block_rows * 128 uint32 words.
+Grid: secrets reshaped to (R, rows, 128) lanes by ops.py; one program per
+(block_rows, 128) tile computes every residue's shares for its tile (w is
+small and static).  Working set: R * (t-1 + 1 + P) * block_rows * 128
+uint32 words.  The float -> residue lift of the fused protect path runs in
+XLA ahead of the kernel (``encode_residues``): it needs 64-bit integers,
+which the kernel never sees.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from .backend import ZERO, resolve_interpret
+
 __all__ = [
     "shamir_poly_pallas",
     "shamir_encode_share_pallas",
+    "encode_residues",
     "mulmod31",
     "addmod",
 ]
@@ -85,79 +92,15 @@ def mulmod31(a: jnp.ndarray, b: jnp.ndarray, p: int) -> jnp.ndarray:
     return addmod(addmod(t00, mid, p), hi, p)
 
 
-def _kernel(secret_ref, coeffs_ref, out_ref, *, num_shares, p):
-    t_minus_1 = coeffs_ref.shape[0]
-    secret = secret_ref[...]
-    for j in range(1, num_shares + 1):
-        x = np.uint32(j)
-        acc = jnp.zeros_like(secret)
-        for k in range(t_minus_1 - 1, -1, -1):
-            acc = addmod(mulmod31(acc, x, p), coeffs_ref[k], p)
-        out_ref[j - 1, ...] = addmod(mulmod31(acc, x, p), secret, p)
+def _share_kernel(secret_ref, coeffs_ref, out_ref, *, points, moduli):
+    """Horner evaluation of every residue's polynomial at every point.
 
-
-@functools.partial(
-    jax.jit, static_argnames=("num_shares", "modulus", "block_rows", "interpret")
-)
-def shamir_poly_pallas(
-    secret: jnp.ndarray,  # (rows, 128) uint32, reduced mod modulus
-    coeffs: jnp.ndarray,  # (t-1, rows, 128) uint32, reduced
-    num_shares: int,
-    modulus: int,
-    block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
-) -> jnp.ndarray:
-    """Returns (num_shares, rows, 128) uint32 shares."""
-    rows, lanes = secret.shape
-    assert lanes == 128 and rows % block_rows == 0, "ops.py reshapes/pads"
-    t_minus_1 = coeffs.shape[0]
-    grid = (rows // block_rows,)
-    kernel = functools.partial(_kernel, num_shares=num_shares, p=modulus)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, 128), lambda i: (i, 0)),
-            pl.BlockSpec((t_minus_1, block_rows, 128), lambda i: (0, i, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (num_shares, block_rows, 128), lambda i: (0, i, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct(
-            (num_shares, rows, 128), jnp.uint32
-        ),
-        interpret=interpret,
-    )(secret, coeffs)
-
-
-def _float_mod(s_abs, neg, p: int):
-    """|s| (float, integer-valued, < 2**62) mod p, sign-corrected, as uint32.
-
-    Exploits that a rounded float has at most mantissa-many significant
-    bits: splitting at 2**31 via divide/floor/multiply-subtract is EXACT
-    (both halves inherit <= mantissa bits), giving hi < 2**30 and
-    lo < 2**31 that fit uint32, then 2**31 === c (mod p) folds the split.
+    secret (R, br, 128), coeffs (R, t-1, br, 128) -> out (R, P, br, 128),
+    all uint32 reduced field elements: no 64-bit type enters the kernel.
     """
-    c = 2**31 - p
-    hi_f = jnp.floor(s_abs * (2.0**-31))
-    lo_f = s_abs - hi_f * (2.0**31)
-    hi = hi_f.astype(jnp.uint32)
-    lo = lo_f.astype(jnp.uint32)
-    m = addmod(_fold(lo, p, c), mulmod31(hi, np.uint32(c), p), p)
-    pp = np.uint32(p)
-    return jnp.where(neg & (m > 0), pp - m, m)
-
-
-def _encode_share_kernel(
-    x_ref, coeffs_ref, out_ref, *, points, moduli, scale, max_signed
-):
     t_minus_1 = coeffs_ref.shape[1]
-    x = x_ref[...]
-    s = jnp.clip(jnp.round(x * scale), -float(max_signed), float(max_signed))
-    neg = s < 0
-    s_abs = jnp.abs(s)
     for r, p in enumerate(moduli):
-        secret = _float_mod(s_abs, neg, p)
+        secret = secret_ref[r]
         for out_idx, j in enumerate(points):
             xj = np.uint32(j)
             acc = jnp.zeros_like(secret)
@@ -166,6 +109,72 @@ def _encode_share_kernel(
             out_ref[r, out_idx, ...] = addmod(
                 mulmod31(acc, xj, p), secret, p
             )
+
+
+def _share_residues(secret, coeffs, points, moduli, block_rows, interpret):
+    """(R, rows, 128) residues + (R, t-1, rows, 128) coefficients ->
+    (R, len(points), rows, 128) uint32 shares in one launch."""
+    num_residues, rows, lanes = secret.shape
+    assert lanes == 128 and rows % block_rows == 0, "ops.py reshapes/pads"
+    assert len(moduli) == num_residues == coeffs.shape[0]
+    t_minus_1 = coeffs.shape[1]
+    kernel = functools.partial(_share_kernel, points=points, moduli=moduli)
+    return pl.pallas_call(
+        kernel,
+        grid=(rows // block_rows,),
+        in_specs=[
+            pl.BlockSpec((num_residues, block_rows, 128),
+                         lambda i: (ZERO, i, ZERO)),
+            pl.BlockSpec((num_residues, t_minus_1, block_rows, 128),
+                         lambda i: (ZERO, ZERO, i, ZERO)),
+        ],
+        out_specs=pl.BlockSpec(
+            (num_residues, len(points), block_rows, 128),
+            lambda i: (ZERO, ZERO, i, ZERO),
+        ),
+        out_shape=jax.ShapeDtypeStruct(
+            (num_residues, len(points), rows, 128), jnp.uint32
+        ),
+        interpret=resolve_interpret(interpret),
+    )(secret, coeffs)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("num_shares", "modulus", "block_rows", "interpret"),
+)
+def shamir_poly_pallas(
+    secret: jnp.ndarray,  # (rows, 128) uint32, reduced mod modulus
+    coeffs: jnp.ndarray,  # (t-1, rows, 128) uint32, reduced
+    num_shares: int,
+    modulus: int,
+    block_rows: int = DEFAULT_BLOCK_ROWS,
+    interpret: bool | None = None,
+) -> jnp.ndarray:
+    """Returns (num_shares, rows, 128) uint32 shares (one residue)."""
+    out = _share_residues(
+        secret[None], coeffs[None], tuple(range(1, num_shares + 1)),
+        (modulus,), block_rows, interpret,
+    )
+    return out[0]
+
+
+def encode_residues(x: jnp.ndarray, moduli: tuple[int, ...],
+                    frac_bits: int) -> jnp.ndarray:
+    """Fixed-point encode of a float payload to (R, ...) uint32 residues.
+
+    The same map as ``FixedPointCodec.encode`` (round(x * 2**frac_bits)
+    in float64, clipped to +-max_signed, lifted mod each p_r), so the
+    fused path stays bit-identical to the reference codec.  It runs as
+    XLA ops ahead of the share kernel: the exact lift needs 64-bit
+    arithmetic, and Mosaic has no 64-bit types.
+    """
+    max_signed = (math.prod(moduli) - 1) // 2
+    lim = float(max_signed)
+    s = jnp.clip(
+        jnp.round(x.astype(jnp.float64) * float(1 << frac_bits)), -lim, lim
+    ).astype(jnp.int64)
+    return jnp.stack([(s % np.int64(p)).astype(jnp.uint32) for p in moduli])
 
 
 @functools.partial(
@@ -182,12 +191,11 @@ def shamir_encode_share_pallas(
     moduli: tuple[int, ...],
     frac_bits: int,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
     points: tuple[int, ...] | None = None,
 ) -> jnp.ndarray:
-    """Fused fixed-point encode + Horner share evaluation, all residues in
-    one launch.  Returns (R, len(points), rows, 128) uint32 — the uint64
-    encoded tensor of the two-stage path never materializes.
+    """Fixed-point encode + Horner share evaluation of every residue.
+    Returns (R, len(points), rows, 128) uint32.
 
     ``points`` (default 1..num_shares) are the public evaluation points to
     emit, statically unrolled like the full-fan-out loop — the sharded
@@ -196,46 +204,16 @@ def shamir_encode_share_pallas(
     Slice j of the output is the share at ``points[j]`` on every path.
 
     Equivalent to ``FixedPointCodec.encode`` followed by the share kernel:
-    s = round(x * 2**frac_bits) clipped to +-max_signed, lifted to residues
-    via the exact float split in ``_float_mod`` (float64 payloads are exact
-    to the codec's full 61-bit range; float32 payloads to 2**24 * scale —
-    on-TPU deployments feed f32 and rely on the same contract).
+    ``encode_residues`` lifts the payload in XLA (float64 exact to the
+    codec's full 61-bit range), and one kernel launch evaluates all
+    residues' polynomials on uint32 words.
     """
     rows, lanes = x.shape
     assert lanes == 128 and rows % block_rows == 0, "ops.py reshapes/pads"
     if points is None:
         points = tuple(range(1, num_shares + 1))
     assert all(1 <= p <= num_shares for p in points)
-    num_residues, t_minus_1 = coeffs.shape[0], coeffs.shape[1]
-    assert len(moduli) == num_residues
-    max_signed = 1
-    for p in moduli:
-        max_signed *= p
-    max_signed = (max_signed - 1) // 2
-    grid = (rows // block_rows,)
-    kernel = functools.partial(
-        _encode_share_kernel,
-        points=points,
-        moduli=moduli,
-        scale=float(1 << frac_bits),
-        max_signed=max_signed,
+    return _share_residues(
+        encode_residues(x, moduli, frac_bits), coeffs, tuple(points),
+        tuple(moduli), block_rows, interpret,
     )
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, 128), lambda i: (i, 0)),
-            pl.BlockSpec(
-                (num_residues, t_minus_1, block_rows, 128),
-                lambda i: (0, 0, i, 0),
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (num_residues, len(points), block_rows, 128),
-            lambda i: (0, 0, i, 0),
-        ),
-        out_shape=jax.ShapeDtypeStruct(
-            (num_residues, len(points), rows, 128), jnp.uint32
-        ),
-        interpret=interpret,
-    )(x, coeffs)

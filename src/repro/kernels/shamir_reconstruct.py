@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from .backend import ZERO, resolve_interpret
 from .shamir_poly import addmod, mulmod31
 
 __all__ = ["shamir_reconstruct_pallas", "lagrange_weights_host"]
@@ -103,7 +104,7 @@ def shamir_reconstruct_pallas(
     moduli: tuple[int, ...],
     garner: bool = False,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Returns (R, rows, 128) uint32: reconstructed residues, or with
     ``garner=True`` (R == 2 only) the pair (r1, garner digit k)."""
@@ -122,14 +123,15 @@ def shamir_reconstruct_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec(
-                (num_residues, k, block_rows, 128), lambda i: (0, 0, i, 0)
+                (num_residues, k, block_rows, 128),
+                lambda i: (ZERO, ZERO, i, ZERO),
             ),
         ],
         out_specs=pl.BlockSpec(
-            (num_residues, block_rows, 128), lambda i: (0, i, 0)
+            (num_residues, block_rows, 128), lambda i: (ZERO, i, ZERO)
         ),
         out_shape=jax.ShapeDtypeStruct(
             (num_residues, rows, 128), jnp.uint32
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(shares)

@@ -17,7 +17,9 @@ a documented no-op — nothing about the interpreted kernels changes.
 
 The model intentionally over-counts slightly (inputs + outputs resident
 simultaneously, no double-buffering discount), so a passing knob has
-real headroom.
+real headroom.  It is a pre-flight estimate, not the authority: the TPU
+compiler is, and ``tests/test_tpu_compile.py`` compiles every main-path
+kernel at its default blocking for a described v5e.
 """
 from __future__ import annotations
 
@@ -71,7 +73,8 @@ def vmem_bytes(knobs: KernelKnobs, *, d: int = 128, num_configs: int = 1,
       accumulators (float32).
     * ``fused_irls_cv``: the same tile shared across ``num_configs``
       betas/accumulators, plus the fold-id row.
-    * ``shamir_protect_flat``: (block_rows, 128) float64 payload +
+    * ``shamir_protect_flat``: (R, block_rows, 128) uint32 residues
+      (the float payload is encoded in XLA, outside the kernel) +
       (R, t-1, block_rows, 128) uint32 coefficients +
       (R, P, block_rows, 128) uint32 share output.
     * ``shamir_reveal_flat``: (P, R, block_rows, 128) uint32 shares +
@@ -85,7 +88,7 @@ def vmem_bytes(knobs: KernelKnobs, *, d: int = 128, num_configs: int = 1,
         return tile + num_configs * per_cfg
     if k == "shamir_protect_flat":
         br = knobs.block_rows
-        payload = br * LANES * 8
+        payload = num_residues * br * LANES * 4
         coeffs = num_residues * (threshold - 1) * br * LANES * 4
         out = num_residues * num_points * br * LANES * 4
         return payload + coeffs + out

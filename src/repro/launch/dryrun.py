@@ -64,6 +64,10 @@ ARGS = parse_args()
 # shape set a module-level default and then silently overwrote it after
 # parse_args, trusting nothing had initialized jax in between).
 apply_xla_flags(host_device_count=ARGS.host_devices)
+# The placeholder devices are CPU devices, here and in the --all
+# children that inherit this environment: none of them may claim an
+# accelerator.
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax  # noqa: E402  (after XLA_FLAGS)
 import jax.numpy as jnp  # noqa: E402
@@ -83,7 +87,7 @@ LM_ARCHS = tuple(a for a in ARCH_IDS if a != "logreg_paper")
 
 
 def make_mesh():
-    from repro.distributed.compat import make_mesh as _make_mesh
+    from repro.distributed.sharding import make_mesh as _make_mesh
 
     if ARGS.mesh_shape:
         dims = tuple(int(x) for x in ARGS.mesh_shape.split(","))

@@ -6,7 +6,7 @@ only dryrun.py forces 512 placeholder devices via XLA_FLAGS).
 """
 from __future__ import annotations
 
-from ..distributed.compat import make_mesh
+from ..distributed.sharding import make_mesh
 
 __all__ = ["make_production_mesh", "make_local_mesh"]
 

@@ -437,7 +437,10 @@ def run_lm(args) -> dict:
 
 
 def main(argv=None):
+    from .compile_cache import enable_compile_cache
+
     args = parse_args(argv)
+    enable_compile_cache()
     if args.arch == "logreg_paper":
         out = run_logreg(args)
     else:
@@ -446,6 +449,7 @@ def main(argv=None):
         os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
         with open(args.out, "w") as f:
             json.dump(out, f, indent=2)
+    return out
 
 
 if __name__ == "__main__":

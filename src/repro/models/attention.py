@@ -18,7 +18,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..distributed.compat import shard_map
 
 __all__ = ["attend", "decode_attend", "swa_attend_cp"]
 
@@ -277,7 +276,7 @@ def swa_attend_cp(q, k, v, *, window: int, rules, q_block: int = 1024,
         out = fn(qr, k_span, v_span, q_pos, kv_pos, window, scale)
         return out.reshape(q_l.shape[0], S_local, H, -1).astype(q_l.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )(q, k, v)

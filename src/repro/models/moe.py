@@ -26,7 +26,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from ..distributed.compat import shard_map
 
 __all__ = ["moe_ffn", "router_aux_loss"]
 
@@ -187,7 +186,7 @@ def moe_ffn(x, params, cfg, rules):
         args += [params["shared_w1"], params["shared_w3"],
                  params["shared_w2"]]
     out_specs = (P(dp, None, None), P(), P())
-    fn = shard_map(
+    fn = jax.shard_map(
         inner, mesh=mesh, in_specs=tuple(in_specs), out_specs=out_specs,
         check_vma=False,
     )

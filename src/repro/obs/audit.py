@@ -1,13 +1,13 @@
 """Runtime privacy audit: reconcile the ledger against the static gate.
 
 The static gate (:mod:`repro.analysis`) certifies, per driver spec, a
-closed jaxpr in which every declassification is a named pjit boundary
+closed jaxpr in which every declassification is a named jit boundary
 (``_reveal_flat`` / ``_distributed_reveal`` / ``declassify_sum``).  The
 runtime ledger (:mod:`repro.obs.ledger`) counts every Python-level
 invocation of those boundaries.  This module closes the loop:
 
 1. **Expected census** — walk the spec's certified jaxpr (recursing
-   through scan/cond/pjit/shard_map bodies) and count every boundary
+   through scan/cond/jit/shard_map bodies) and count every boundary
    equation, keyed ``(site, operand shape)``.  One equation == one
    wrapper invocation during the trace, because the hooks live in the
    host wrappers outside the jitted bodies.
@@ -48,8 +48,8 @@ SITES = ledger.DECLASS_SITES + ("_protect_flat",)
 
 
 def _sub_jaxprs(eqn):
-    """Every jaxpr nested in an equation's params (scan/cond/pjit/...)."""
-    from jax.core import ClosedJaxpr, Jaxpr
+    """Every jaxpr nested in an equation's params (scan/cond/jit/...)."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     for v in eqn.params.values():
         if isinstance(v, ClosedJaxpr):
@@ -87,7 +87,7 @@ def graph_census(closed) -> dict:
 
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pjit" \
+            if eqn.primitive.name == "jit" \
                     and eqn.params.get("name") in SITES:
                 counts[(eqn.params["name"], _operand_shape(eqn))] += 1
                 continue  # the boundary body holds no further boundaries

@@ -51,7 +51,7 @@ __all__ = [
     "Capture",
 ]
 
-# the sanctioned declassification boundaries, by pjit name — mirrors
+# the sanctioned declassification boundaries, by jit name — mirrors
 # analysis.taint._PJIT_RULES minus the protect direction
 DECLASS_SITES = ("_reveal_flat", "_distributed_reveal", "declassify_sum")
 

@@ -84,14 +84,14 @@ def _batched_update(betas, H, g, lams, l1: float):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("agg", "protect", "l1", "tol", "interpret", "points",
+    static_argnames=("agg", "protect", "l1", "tol", "points",
                      "summaries_backend", "num_rounds", "num_parts",
                      "max_rounds"),
 )
 def _cv_sweep_block(betas, obj_prev, converged, iters, vdev, vcorr, vcnt,
                     key, round_base, X, X32, y, counts, fold_ids, fold_of,
                     lams, agg: SecureCollective, protect: str, l1: float,
-                    tol: float, interpret: bool,
+                    tol: float,
                     points: tuple[int, ...] | None,
                     summaries_backend: str, num_rounds: int,
                     num_parts: int, max_rounds: int):
@@ -115,7 +115,7 @@ def _cv_sweep_block(betas, obj_prev, converged, iters, vdev, vcorr, vcnt,
         kr = agg.round_key(key, slot)
         sm = batched_cv_summaries(
             betas, packed, fold_ids, fold_of,
-            backend=summaries_backend, interpret=interpret,
+            backend=summaries_backend,
         )
         tree = {}
         if protect in ("gradient", "both"):
@@ -374,8 +374,8 @@ class PathDriver:
                 *carry[:7], self.key, carry[7], packed.X, packed.X32,
                 packed.y, packed.counts, fold_ids, fold_of_j, lams_j,
                 agg=self.agg, protect=s.protect, l1=float(s.l1),
-                tol=float(s.tol), interpret=self.agg.scheme.interpret,
-                points=pts, summaries_backend=s.summaries_backend,
+                tol=float(s.tol), points=pts,
+                summaries_backend=s.summaries_backend,
                 num_rounds=s.rounds_per_sync,
                 num_parts=packed.num_institutions,
                 max_rounds=s.max_rounds,

@@ -236,11 +236,9 @@ def test_headroom_lint_fails_past_uint64_accumulator():
 def test_mesh_axis_lint_flags_rogue_axis():
     from jax.sharding import AbstractMesh, PartitionSpec as P
 
-    from repro.distributed.compat import shard_map
-
-    mesh = AbstractMesh((("rogue", 4),))
-    fn = shard_map(lambda x: jax.lax.psum(x, "rogue"), mesh=mesh,
-                   in_specs=(P(),), out_specs=P(), check_vma=False)
+    mesh = AbstractMesh((4,), ("rogue",))
+    fn = jax.shard_map(lambda x: jax.lax.psum(x, "rogue"), mesh=mesh,
+                       in_specs=(P(),), out_specs=P(), check_vma=False)
     closed = jax.make_jaxpr(fn)(jnp.ones((8,)))
     rep = lint_mesh_axes(closed, "rogue-test")
     assert not rep.ok

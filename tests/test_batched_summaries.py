@@ -150,6 +150,33 @@ def test_pack_partitions_validates():
     np.testing.assert_array_equal(np.asarray(packed.X[1, 1:]), 0.0)
 
 
+@pytest.mark.parametrize("sizes,d,seed", [
+    ((6, 52, 53), 4, 4113),  # the ragged shape XLA:CPU once mis-fused
+    ((1, 300, 77, 300), 12, 0),
+])
+def test_pack_partitions_is_exact(sizes, d, seed):
+    """Every packed row, the f32 operand and the zero padding equal a
+    plain numpy pack of the same parts."""
+    key = jax.random.PRNGKey(seed)
+    parts = []
+    for j, n in enumerate(sizes):
+        kx, ky = jax.random.split(jax.random.fold_in(key, j))
+        parts.append((
+            jax.random.normal(kx, (n, d), dtype=jnp.float64),
+            jax.random.bernoulli(ky, 0.5, (n,)).astype(jnp.float64),
+        ))
+    packed = pack_partitions(parts)
+    want_X = np.zeros((len(sizes), max(sizes), d))
+    want_y = np.zeros((len(sizes), max(sizes)))
+    for s, (Xj, yj) in enumerate(parts):
+        want_X[s, :Xj.shape[0]] = np.asarray(Xj)
+        want_y[s, :yj.shape[0]] = np.asarray(yj)
+    np.testing.assert_array_equal(np.asarray(packed.X), want_X)
+    np.testing.assert_array_equal(np.asarray(packed.X32),
+                                  want_X.astype(np.float32))
+    np.testing.assert_array_equal(np.asarray(packed.y), want_y)
+
+
 # ----------------------------------------------------- secure_fit parity
 @pytest.mark.parametrize("backend", ["reference", "pallas"])
 def test_secure_fit_uneven_partitions_match_gold(study, backend):

@@ -85,8 +85,7 @@ def test_multiconfig_round_slots_bit_equal_per_study(agg, studies):
     for study in studies:
         packed = pack_partitions(study.parts)
         beta0 = jnp.zeros((DIM,), jnp.float64)
-        sm = batched_local_summaries(beta0, packed, backend="pallas",
-                                     interpret=True)
+        sm = batched_local_summaries(beta0, packed, backend="pallas")
         trees.append({"gradient": sm.gradient, "hessian": sm.hessian,
                       "deviance": sm.deviance})
     stacked = jax.tree_util.tree_map(
@@ -113,14 +112,14 @@ def test_multistudy_iteration_matches_independent(agg, studies, protect):
     betas0 = jnp.zeros((len(studies), DIM), jnp.float64)
     betas, objs, gnorms, snorms = fused_multistudy_iteration(
         betas0, key, packed.X, packed.X32, packed.y, packed.counts,
-        jnp.asarray(lams, jnp.float64), agg, protect, 0.0, True,
+        jnp.asarray(lams, jnp.float64), agg, protect, 0.0,
     )
     tol = quant_tol(agg)
     for m, study in enumerate(studies):
         p = pack_partitions(study.parts)
         b_ref, obj_ref, g_ref, s_ref = _fused_secure_iteration(
             betas0[m], jax.random.fold_in(key, m), p.X, p.X32, p.y,
-            p.counts, lams[m], agg, protect, 0.0, True,
+            p.counts, lams[m], agg, protect, 0.0,
         )
         assert np.abs(np.asarray(betas[m]) - np.asarray(b_ref)).max() <= tol
         assert abs(float(objs[m]) - float(obj_ref)) <= tol * NUM_INST
@@ -147,7 +146,7 @@ def test_multistudy_rounds_track_independent_fits(agg, studies):
         for r in range(num_rounds):
             beta, obj, _, _ = _fused_secure_iteration(
                 beta, jax.random.fold_in(key, r), p.X, p.X32, p.y,
-                p.counts, lams[m], agg, "both", 0.0, True,
+                p.counts, lams[m], agg, "both", 0.0,
             )
             # per-round quantization errors can compound through the
             # Newton updates; allow one tol per elapsed round
@@ -171,13 +170,13 @@ def test_ragged_studies_pad_with_silent_institutions(agg):
     betas0 = jnp.zeros((2, DIM), jnp.float64)
     betas, _, _, _ = fused_multistudy_iteration(
         betas0, key, packed.X, packed.X32, packed.y, packed.counts,
-        jnp.asarray([0.5, 0.5], jnp.float64), agg, "both", 0.0, True,
+        jnp.asarray([0.5, 0.5], jnp.float64), agg, "both", 0.0,
     )
     tol = quant_tol(agg)
     for m, study in enumerate((wide, slim)):
         p = pack_partitions(study.parts)
         b_ref, *_ = _fused_secure_iteration(
             betas0[m], jax.random.fold_in(key, m), p.X, p.X32, p.y,
-            p.counts, 0.5, agg, "both", 0.0, True,
+            p.counts, 0.5, agg, "both", 0.0,
         )
         assert np.abs(np.asarray(betas[m]) - np.asarray(b_ref)).max() <= tol

@@ -206,8 +206,7 @@ def test_cv_kernel_matches_simulation():
         for c in range(K + 1)
     ])
     fold_of = jnp.asarray(list(range(K)) + [-1], jnp.int32)
-    kw = dict(counts=packed.counts, interpret=True,
-              mxu_operand=packed.X32)
+    kw = dict(counts=packed.counts, mxu_operand=packed.X32)
     out_kernel = ops.fused_irls_cv(
         betas, packed.X, packed.y, fold_ids, fold_of, simulate=False, **kw
     )

@@ -12,7 +12,6 @@ import numpy as np
 import pytest
 
 from repro.launch.hlo_analysis import analyze_hlo
-from repro.distributed.compat import shard_map
 
 
 def _cost(fn, *specs):
@@ -81,7 +80,7 @@ def test_collective_conventions():
 
     from jax.sharding import PartitionSpec as P
 
-    fn = jax.jit(shard_map(f, mesh=mesh, in_specs=P(), out_specs=P()))
+    fn = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P()))
     txt = fn.lower(jax.ShapeDtypeStruct((1024,), jnp.float32)) \
             .compile().as_text()
     h = analyze_hlo(txt)

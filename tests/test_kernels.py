@@ -12,6 +12,22 @@ P31 = 2**31 - 1
 P31B = 2**31 - 19
 
 
+# ------------------------------------------------------------- backend switch
+def test_backend_decides_interpret(monkeypatch):
+    """Interpreted on the CPU, compiled on the TPU, refused elsewhere —
+    no caller chooses."""
+    from repro.kernels import backend
+
+    assert backend.interpret_kernels() is True
+    assert backend.resolve_interpret(False) is False
+    monkeypatch.setattr(backend.jax, "default_backend", lambda: "tpu")
+    assert backend.interpret_kernels() is False
+    assert backend.resolve_interpret(None) is False
+    monkeypatch.setattr(backend.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        backend.interpret_kernels()
+
+
 # ---------------------------------------------------------------- gram_hessian
 @pytest.mark.parametrize("n", [8, 100, 512, 1000])
 @pytest.mark.parametrize("d", [3, 84, 128, 200])

@@ -45,6 +45,9 @@ def test_mesh_env_builds_child_flags_without_touching_parent():
     env = mesh_env(host_device_count=6, base={"PATH": "/bin"})
     assert env["XLA_FLAGS"] == "--xla_force_host_platform_device_count=6"
     assert env["PATH"] == "/bin"
+    # a forced-host-device child is a CPU mesh: it must never claim the
+    # accelerator its parent holds
+    assert env["JAX_PLATFORMS"] == "cpu"
     assert os.environ.get("XLA_FLAGS") == before
 
 
@@ -120,7 +123,6 @@ _MESH_SCRIPT = textwrap.dedent("""
     from jax.sharding import PartitionSpec as P
     from repro.core.secure_agg import SecureAggregator, secure_psum
     from repro.core.flatbuf import pack_pytree, unpack_pytree_tile
-    from repro.distributed.compat import shard_map
     from repro.distributed.multihost import (
         pod_mesh, pod_share_mesh, secure_psum_2d, run_scanned_rounds)
     from repro.distributed.sharding import POD_AXIS
@@ -136,11 +138,11 @@ _MESH_SCRIPT = textwrap.dedent("""
     # the replicated out="tree" decode bitwise on an uneven (D=3) mesh.
     D = 3
     mesh = pod_mesh(D)
-    tree_out = shard_map(
+    tree_out = jax.shard_map(
         lambda: secure_psum(tree, POD_AXIS, jax.random.PRNGKey(5),
                             aggregator=agg, reveal="sharded"),
         mesh=mesh, in_specs=(), out_specs=P(), check_vma=False)()
-    tile_out = shard_map(
+    tile_out = jax.shard_map(
         lambda: secure_psum(tree, POD_AXIS, jax.random.PRNGKey(5),
                             aggregator=agg, reveal="sharded", out="tile"
                             ).gather(POD_AXIS),
@@ -169,7 +171,7 @@ _MESH_SCRIPT = textwrap.dedent("""
     # x public weight, psum over the share axis) must equal the 1D wire
     # bitwise -- same sharing polynomials, same field reconstruction.
     mesh2 = pod_share_mesh(3, agg.scheme.threshold)
-    out2 = shard_map(
+    out2 = jax.shard_map(
         lambda: secure_psum_2d(tree, jax.random.PRNGKey(5),
                                aggregator=agg),
         mesh=mesh2, in_specs=(), out_specs=P(), check_vma=False)()

@@ -107,3 +107,32 @@ def test_paper_datasets_all_converge_scaled():
         assert sec.converged, name
         assert sec.iterations <= 12, (name, sec.iterations)
         np.testing.assert_allclose(sec.beta, gold.beta, atol=1e-5)
+
+
+_STUDY_DIGEST = (
+    "import numpy as np\n"
+    "import repro.core\n"
+    "from repro.data import load_study\n"
+    "st = load_study('insurance', scale=0.01)\n"
+    "print(float(np.asarray(st.parts[0][0]).sum()))\n"
+)
+
+
+def test_study_rows_are_stable_across_processes():
+    """A study's rows derive from a stable digest of its name, not from
+    the per-process salted ``hash``: two processes with different hash
+    seeds draw the same data."""
+    import os
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    outs = []
+    for hash_seed in ("1", "2"):
+        env = dict(os.environ, PYTHONHASHSEED=hash_seed, PYTHONPATH=src,
+                   JAX_PLATFORMS="cpu")
+        r = subprocess.run([sys.executable, "-c", _STUDY_DIGEST], env=env,
+                           capture_output=True, text=True, timeout=300)
+        assert r.returncode == 0, r.stderr[-2000:]
+        outs.append(r.stdout.strip().splitlines()[-1])
+    assert outs[0] == outs[1]

@@ -12,7 +12,6 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.checkpoint import CheckpointManager, load_pytree, save_pytree
-from repro.distributed.compat import shard_map
 from repro.optim.compression import compressed_psum, init_error_feedback
 
 
@@ -25,7 +24,7 @@ def test_compressed_psum_error_feedback_converges(rng_key):
     e = init_error_feedback(g)
 
     def step(e):
-        return shard_map(
+        return jax.shard_map(
             lambda ee: compressed_psum(g, "pod", ee),
             mesh=mesh, in_specs=(P(),), out_specs=(P(), P()),
             check_vma=False,
@@ -43,7 +42,7 @@ def test_compressed_psum_quantization_bounded(rng_key):
     mesh = jax.make_mesh((1,), ("pod",))
     g = {"w": jax.random.normal(rng_key, (1024,), jnp.float32)}
     e = init_error_feedback(g)
-    mean, e2 = shard_map(
+    mean, e2 = jax.shard_map(
         lambda ee: compressed_psum(g, "pod", ee),
         mesh=mesh, in_specs=(P(),), out_specs=(P(), P()),
         check_vma=False,

@@ -23,7 +23,6 @@ from repro.core.secure_agg import (
     secure_psum,
 )
 from repro.core.shamir import ShamirScheme
-from repro.distributed.compat import shard_map
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -37,7 +36,7 @@ def _tree(key):
 
 def _run_psum(tree, agg, reveal, points=None):
     mesh = jax.make_mesh((1,), ("pod",))
-    return shard_map(
+    return jax.shard_map(
         lambda: secure_psum(tree, "pod", jax.random.PRNGKey(5),
                             aggregator=agg, reveal=reveal, points=points),
         mesh=mesh, in_specs=(), out_specs=P(), check_vma=False,
@@ -120,7 +119,6 @@ _MULTIDEV_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=3"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.distributed.compat import shard_map
     from repro.core.secure_agg import SecureAggregator, secure_psum
 
     D = 3  # does not divide the 8-row sublane alignment: rows pad to lcm
@@ -135,7 +133,7 @@ _MULTIDEV_SCRIPT = textwrap.dedent("""
                             ("pallas", "replicated"),
                             ("pallas", "sharded")):
         agg = SecureAggregator(backend=backend)
-        out = shard_map(
+        out = jax.shard_map(
             lambda: secure_psum(tree, "pod", jax.random.PRNGKey(5),
                                 aggregator=agg, reveal=reveal),
             mesh=mesh, in_specs=(), out_specs=P(), check_vma=False,

@@ -20,6 +20,27 @@ def test_logreg_driver_converges(tmp_path):
     assert m["iterations"] <= 10
 
 
+def test_compile_cache_follows_env_or_a_fixed_checkout_path(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when set, is left to JAX; otherwise the
+    cache goes to one fixed, gitignored directory in the checkout."""
+    import jax
+
+    from repro.launch import compile_cache
+
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: calls.append((name, value)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+    assert compile_cache.enable_compile_cache() == "/elsewhere"
+    assert calls == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = compile_cache.enable_compile_cache()
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert path == os.path.join(repo, ".jax_cache")
+    assert calls == [("jax_compilation_cache_dir", path)]
+    assert compile_cache.enable_compile_cache() == path  # no moving target
+
+
 def test_lm_driver_secure_agg_loss_decreases(tmp_path):
     out = tmp_path / "m.json"
     train_mod.main([
