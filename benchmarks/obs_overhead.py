@@ -29,8 +29,10 @@ instead of reading as fake overhead.
 Machine-readable rows land in BENCH_obs_overhead.json (``--quick`` is
 the bench_smoke gate size and writes BENCH_obs_overhead_smoke.json).
 ``--trace-out PREFIX`` additionally exports the final traced run as
-PREFIX.jsonl (the run ledger ``results/show.py`` renders) and
-PREFIX.trace.json (open in chrome://tracing or https://ui.perfetto.dev).
+PREFIX.jsonl (the run ledger ``results/show.py`` renders) and captures
+it under ``jax.profiler`` into the directory PREFIX.profile (open it in
+TensorBoard's profile plugin or Perfetto): ``enable(profiler=True)``
+puts the spans there as annotations, on the device ops' own clock.
 """
 from __future__ import annotations
 
@@ -136,13 +138,13 @@ def run(num_institutions: int = 4, dim: int = 64, records: int = 80_000,
         # export the LOOP driver: its protect/aggregate/reveal happen as
         # host calls, so the trace shows the whole span taxonomy (the
         # fused/scan graphs keep those phases in-graph under one span)
-        tracer = trace.enable(capacity=1 << 16)
-        _run_once(parts, "loop")
+        tracer = trace.enable(capacity=1 << 16, profiler=True)
+        with jax.profiler.trace(f"{trace_out}.profile"):
+            _run_once(parts, "loop")
         trace.disable()
         n = tracer.export_jsonl(f"{trace_out}.jsonl")
-        tracer.export_chrome_trace(f"{trace_out}.trace.json")
-        print(f"exported {n} spans -> {trace_out}.jsonl / "
-              f"{trace_out}.trace.json")
+        print(f"exported {n} spans -> {trace_out}.jsonl; profile -> "
+              f"{trace_out}.profile")
     return rows
 
 
@@ -158,8 +160,8 @@ def main(argv=None):
                     help="small config for the bench_smoke gate "
                          "(S=4, d=32, N=20000, 2 repeats; 10% gate)")
     ap.add_argument("--trace-out", default=None,
-                    help="also export a traced fused run as "
-                         "PREFIX.jsonl + PREFIX.trace.json")
+                    help="also export a traced loop run as "
+                         "PREFIX.jsonl + a profile in PREFIX.profile")
     ap.add_argument("--json", default=None,
                     help="machine-readable output path ('' to skip; "
                          "default BENCH_obs_overhead[_smoke].json)")

@@ -209,20 +209,22 @@ def _masked_irls_terms(beta, X, y, counts):
     deviance.  Single source of truth for every non-kernel backend —
     the "g/dev identical to the reference oracle" contract of the mixed
     backend holds by construction, not by keeping copies in sync."""
-    n = X.shape[1]
-    mask = (jnp.arange(n)[None, :] < counts[:, None]).astype(X.dtype)
-    z = jnp.einsum("snd,d->sn", X, beta.astype(X.dtype))
-    p = jax.nn.sigmoid(z)
-    w = p * (1.0 - p) * mask
-    g = jnp.einsum("snd,sn->sd", X, (y - p) * mask)
-    dev = -2.0 * jnp.sum((y * z - jnp.logaddexp(0.0, z)) * mask, axis=1)
-    return w, g, dev
+    with jax.named_scope("f64_terms"):
+        n = X.shape[1]
+        mask = (jnp.arange(n)[None, :] < counts[:, None]).astype(X.dtype)
+        z = jnp.einsum("snd,d->sn", X, beta.astype(X.dtype))
+        p = jax.nn.sigmoid(z)
+        w = p * (1.0 - p) * mask
+        g = jnp.einsum("snd,sn->sd", X, (y - p) * mask)
+        dev = -2.0 * jnp.sum((y * z - jnp.logaddexp(0.0, z)) * mask, axis=1)
+        return w, g, dev
 
 
 def _reference_summaries(beta, X, y, counts):
     """Masked batched oracle in the payload dtype (f64)."""
     w, g, dev = _masked_irls_terms(beta, X, y, counts)
-    H = jnp.einsum("sni,snj->sij", X * w[..., None], X)
+    with jax.named_scope("gram"):
+        H = jnp.einsum("sni,snj->sij", X * w[..., None], X)
     return H, g, dev
 
 
@@ -259,17 +261,18 @@ def _mixed_summaries(beta, X, X32, y, counts, chunk: int = MIXED_GRAM_CHUNK):
     w, g, dev = _masked_irls_terms(beta, X, y, counts)
     num_chunks = -(-n // chunk)
     pad = num_chunks * chunk - n
-    Xw32 = (X * w[..., None]).astype(jnp.float32)
 
     def slabs(a):
         a = jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
         return a.reshape(a.shape[0], num_chunks, chunk, d)
 
-    # (S, nc, d, d) f32 partial Grams, merged across slabs in f64
-    Hc = jax.lax.dot_general(
-        slabs(Xw32), slabs(X32), (((2,), (2,)), ((0, 1), (0, 1)))
-    )
-    H = jnp.sum(Hc.astype(jnp.float64), axis=1)
+    with jax.named_scope("gram"):
+        Xw32 = (X * w[..., None]).astype(jnp.float32)
+        # (S, nc, d, d) f32 partial Grams, merged across slabs in f64
+        Hc = jax.lax.dot_general(
+            slabs(Xw32), slabs(X32), (((2,), (2,)), ((0, 1), (0, 1)))
+        )
+        H = jnp.sum(Hc.astype(jnp.float64), axis=1)
     return H, g, dev
 
 
@@ -311,26 +314,27 @@ def _cv_common_terms(betas, X, y, tmask, vmask):
     """f64 z/g/dev/val terms shared by the reference and mixed rungs (and
     matching the sim's f64-accumulation contract).  Returns everything
     except the Gram, which is what the rungs differ on."""
-    s_dim = X.shape[0]
-    z = jnp.einsum("snd,cd->csn", X, betas.astype(X.dtype))
-    z = z.astype(jnp.float64)
-    p = jax.nn.sigmoid(z)
-    ll = y[None] * z - jnp.logaddexp(0.0, z)
-    dev_tr = -2.0 * jnp.sum(ll * tmask, axis=2)
-    dev_va = -2.0 * jnp.sum(ll * vmask, axis=2)
-    acc_va = jnp.sum(
-        jnp.where((z > 0.0) == (y[None] > 0.5), vmask, 0.0), axis=2
-    )
-    w = (p * (1.0 - p)) * tmask  # (C, S, N) train-fold IRLS weights
-    resid = (y[None] - p) * tmask
-    g = jnp.stack([
-        jax.lax.dot_general(
-            resid[:, s], X[s], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float64,
+    with jax.named_scope("f64_terms"):
+        s_dim = X.shape[0]
+        z = jnp.einsum("snd,cd->csn", X, betas.astype(X.dtype))
+        z = z.astype(jnp.float64)
+        p = jax.nn.sigmoid(z)
+        ll = y[None] * z - jnp.logaddexp(0.0, z)
+        dev_tr = -2.0 * jnp.sum(ll * tmask, axis=2)
+        dev_va = -2.0 * jnp.sum(ll * vmask, axis=2)
+        acc_va = jnp.sum(
+            jnp.where((z > 0.0) == (y[None] > 0.5), vmask, 0.0), axis=2
         )
-        for s in range(s_dim)
-    ], axis=1)  # (C, S, d)
-    return w, g, dev_tr, dev_va, acc_va
+        w = (p * (1.0 - p)) * tmask  # (C, S, N) train-fold IRLS weights
+        resid = (y[None] - p) * tmask
+        g = jnp.stack([
+            jax.lax.dot_general(
+                resid[:, s], X[s], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float64,
+            )
+            for s in range(s_dim)
+        ], axis=1)  # (C, S, d)
+        return w, g, dev_tr, dev_va, acc_va
 
 
 def batched_cv_summaries(
@@ -360,64 +364,66 @@ def batched_cv_summaries(
     """
     if backend not in BACKENDS:
         raise ValueError(f"backend must be one of {BACKENDS}")
-    fold_ids = fold_ids.astype(jnp.int32)
-    fold_of = fold_of.astype(jnp.int32)
-    if backend == "pallas":
-        from ..kernels import ops
+    with jax.named_scope("summaries"):
+        fold_ids = fold_ids.astype(jnp.int32)
+        fold_of = fold_of.astype(jnp.int32)
+        if backend == "pallas":
+            from ..kernels import ops
 
-        H, g, dev_tr, dev_va, acc_va, n_va = ops.fused_irls_cv(
-            betas, packed.X, packed.y, fold_ids, fold_of,
-            counts=packed.counts, block_n=block_n,
-            mxu_operand=packed.X32,
+            H, g, dev_tr, dev_va, acc_va, n_va = ops.fused_irls_cv(
+                betas, packed.X, packed.y, fold_ids, fold_of,
+                counts=packed.counts, block_n=block_n,
+                mxu_operand=packed.X32,
+            )
+            # train + held-out rows partition the valid rows exactly (also
+            # for fold_of == -1, where n_va == 0), so n_tr needs no dense
+            # (C, S, N) mask materialization inside the sweep scan
+            n_va = n_va.astype(jnp.float64)
+            n_tr = packed.counts[None, :].astype(jnp.float64) - n_va
+            return CVSummaries(
+                H.astype(jnp.float64), g.astype(jnp.float64),
+                dev_tr.astype(jnp.float64), n_tr,
+                dev_va.astype(jnp.float64), acc_va.astype(jnp.float64),
+                n_va,
+            )
+        X, y = packed.X, packed.y
+        tmask, vmask = _cv_masks(X, packed.counts, fold_ids, fold_of)
+        w, g, dev_tr, dev_va, acc_va = _cv_common_terms(
+            betas, X, y, tmask, vmask
         )
-        # train + held-out rows partition the valid rows exactly (also
-        # for fold_of == -1, where n_va == 0), so n_tr needs no dense
-        # (C, S, N) mask materialization inside the sweep scan
-        n_va = n_va.astype(jnp.float64)
-        n_tr = packed.counts[None, :].astype(jnp.float64) - n_va
-        return CVSummaries(
-            H.astype(jnp.float64), g.astype(jnp.float64),
-            dev_tr.astype(jnp.float64), n_tr,
-            dev_va.astype(jnp.float64), acc_va.astype(jnp.float64),
-            n_va,
-        )
-    X, y = packed.X, packed.y
-    tmask, vmask = _cv_masks(X, packed.counts, fold_ids, fold_of)
-    w, g, dev_tr, dev_va, acc_va = _cv_common_terms(
-        betas, X, y, tmask, vmask
-    )
-    s_dim, d = X.shape[0], X.shape[2]
-    if backend == "reference":
-        def gram_one(w_c):  # (S, N) f64 -> (S, d, d) f64
-            return jnp.stack([
-                (X[s] * w_c[s][:, None]).T @ X[s] for s in range(s_dim)
-            ])
+        s_dim, d = X.shape[0], X.shape[2]
+        if backend == "reference":
+            def gram_one(w_c):  # (S, N) f64 -> (S, d, d) f64
+                return jnp.stack([
+                    (X[s] * w_c[s][:, None]).T @ X[s] for s in range(s_dim)
+                ])
 
-        H = jax.lax.map(gram_one, w)
-    else:  # mixed: chunked f32 gemms merged in f64, per config
-        X32 = packed.X32
-        n = X.shape[1]
-        chunk = MIXED_GRAM_CHUNK
-        num_chunks = -(-n // chunk)
-        pad = num_chunks * chunk - n
+            with jax.named_scope("gram"):
+                H = jax.lax.map(gram_one, w)
+        else:  # mixed: chunked f32 gemms merged in f64, per config
+            X32 = packed.X32
+            n = X.shape[1]
+            chunk = MIXED_GRAM_CHUNK
+            num_chunks = -(-n // chunk)
+            pad = num_chunks * chunk - n
 
-        def slabs(a):
-            a = jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
-            return a.reshape(s_dim, num_chunks, chunk, d)
+            def slabs(a):
+                a = jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
+                return a.reshape(s_dim, num_chunks, chunk, d)
 
-        X32s = slabs(X32)
+            def gram_one(w_c):  # (S, N) -> (S, d, d): split accumulation
+                Xw32 = slabs((X * w_c[..., None]).astype(jnp.float32))
+                Hc = jax.lax.dot_general(
+                    Xw32, X32s, (((2,), (2,)), ((0, 1), (0, 1)))
+                )  # (S, nc, d, d) f32 partial Grams
+                return jnp.sum(Hc.astype(jnp.float64), axis=1)
 
-        def gram_one(w_c):  # (S, N) -> (S, d, d): split accumulation
-            Xw32 = slabs((X * w_c[..., None]).astype(jnp.float32))
-            Hc = jax.lax.dot_general(
-                Xw32, X32s, (((2,), (2,)), ((0, 1), (0, 1)))
-            )  # (S, nc, d, d) f32 partial Grams
-            return jnp.sum(Hc.astype(jnp.float64), axis=1)
-
-        H = jax.lax.map(gram_one, w)
-    n_tr = jnp.sum(tmask, axis=2)
-    n_va = jnp.sum(vmask, axis=2)
-    return CVSummaries(H, g, dev_tr, n_tr, dev_va, acc_va, n_va)
+            with jax.named_scope("gram"):
+                X32s = slabs(X32)
+                H = jax.lax.map(gram_one, w)
+        n_tr = jnp.sum(tmask, axis=2)
+        n_va = jnp.sum(vmask, axis=2)
+        return CVSummaries(H, g, dev_tr, n_tr, dev_va, acc_va, n_va)
 
 
 def batched_local_summaries(
@@ -436,27 +442,28 @@ def batched_local_summaries(
     """
     if backend not in BACKENDS:
         raise ValueError(f"backend must be one of {BACKENDS}")
-    if backend == "mixed":
-        H, g, dev = _mixed_summaries(
-            beta, packed.X, packed.X32, packed.y, packed.counts
-        )
-        return LocalSummaries(H, g, dev, packed.counts)
-    if backend == "pallas":
-        from ..kernels import ops
+    with jax.named_scope("summaries"):
+        if backend == "mixed":
+            H, g, dev = _mixed_summaries(
+                beta, packed.X, packed.X32, packed.y, packed.counts
+            )
+            return LocalSummaries(H, g, dev, packed.counts)
+        if backend == "pallas":
+            from ..kernels import ops
 
-        # the CPU runs the kernel's XLA simulation inside ops.fused_irls
-        # (block_n then has no effect); a TPU runs the compiled blocked
-        # kernel with VMEM-sized N tiles.
-        H, g, dev = ops.fused_irls(
-            beta, packed.X, packed.y, packed.counts,
-            block_n=block_n, mxu_operand=packed.X32,
-        )
-        # protocol dtype: the fixed-point encode needs f64 past 2**24
-        H = H.astype(jnp.float64)
-        g = g.astype(jnp.float64)
-        dev = dev.astype(jnp.float64)
-    else:
-        H, g, dev = _reference_summaries(
-            beta, packed.X, packed.y, packed.counts
-        )
-    return LocalSummaries(H, g, dev, packed.counts)
+            # the CPU runs the kernel's XLA simulation inside ops.fused_irls
+            # (block_n then has no effect); a TPU runs the compiled blocked
+            # kernel with VMEM-sized N tiles.
+            H, g, dev = ops.fused_irls(
+                beta, packed.X, packed.y, packed.counts,
+                block_n=block_n, mxu_operand=packed.X32,
+            )
+            # protocol dtype: the fixed-point encode needs f64 past 2**24
+            H = H.astype(jnp.float64)
+            g = g.astype(jnp.float64)
+            dev = dev.astype(jnp.float64)
+        else:
+            H, g, dev = _reference_summaries(
+                beta, packed.X, packed.y, packed.counts
+            )
+        return LocalSummaries(H, g, dev, packed.counts)

@@ -529,21 +529,22 @@ class SecureCollective:
         Reference backend: per-leaf share pytree of (w, R, ...) uint64.
         Pallas backend: a single ``FlatProtected`` share buffer.
         """
-        if self.backend == "pallas":
-            buf, layout = pack_pytree(tree)
-            if self.overflow_check:
-                self.codec.check_headroom(buf, what="protect")
-            shares = _protect_flat(
-                key, buf, self.scheme, self.codec.frac_bits, layout.rows
+        with jax.named_scope("protect"):
+            if self.backend == "pallas":
+                buf, layout = pack_pytree(tree)
+                if self.overflow_check:
+                    self.codec.check_headroom(buf, what="protect")
+                shares = _protect_flat(
+                    key, buf, self.scheme, self.codec.frac_bits, layout.rows
+                )
+                return FlatProtected(shares, layout)
+            encoded = jax.tree_util.tree_map(
+                functools.partial(self.codec.encode,
+                                  check=self.overflow_check),
+                tree,
             )
-            return FlatProtected(shares, layout)
-        encoded = jax.tree_util.tree_map(
-            functools.partial(self.codec.encode, check=self.overflow_check),
-            tree,
-        )
-        return self.scheme.share_pytree(key, encoded)
+            return self.scheme.share_pytree(key, encoded)
 
-    @_traced("protect")
     def protect_batched(self, key: jax.Array, tree):
         """Protect S institutions' summaries in ONE kernel launch.
 
@@ -556,22 +557,24 @@ class SecureCollective:
         """
         if self.backend != "pallas":
             raise ValueError("protect_batched requires the pallas backend")
-        buf, layout = pack_pytree_batched(tree)
-        if self.overflow_check:
-            # the S slices will be summed: bound each by capacity / S so
-            # the AGGREGATE cannot overflow (the headroom_ok contract)
-            self.codec.check_headroom(
-                buf, num_addends=buf.shape[0], what="protect_batched"
+        with jax.named_scope("protect"):
+            buf, layout = pack_pytree_batched(tree)
+            if self.overflow_check:
+                # the S slices will be summed: bound each by capacity / S
+                # so the AGGREGATE cannot overflow (the headroom_ok
+                # contract)
+                self.codec.check_headroom(
+                    buf, num_addends=buf.shape[0], what="protect_batched"
+                )
+            s_dim, rows = buf.shape[0], layout.rows
+            shares = _protect_flat(
+                key, buf.reshape(s_dim * rows, LANES), self.scheme,
+                self.codec.frac_bits, s_dim * rows,
+            )  # (w, R, S*rows, 128)
+            w, num_r = shares.shape[0], shares.shape[1]
+            return FlatProtected(
+                shares.reshape(w, num_r, s_dim, rows, LANES), layout
             )
-        s_dim, rows = buf.shape[0], layout.rows
-        shares = _protect_flat(
-            key, buf.reshape(s_dim * rows, LANES), self.scheme,
-            self.codec.frac_bits, s_dim * rows,
-        )  # (w, R, S*rows, 128)
-        w, num_r = shares.shape[0], shares.shape[1]
-        return FlatProtected(
-            shares.reshape(w, num_r, s_dim, rows, LANES), layout
-        )
 
     # computation-center side -------------------------------------------------
     @_traced("aggregate")
@@ -587,13 +590,14 @@ class SecureCollective:
             raise ValueError("nothing to aggregate")
         if len(protected) == 1:
             return protected[0]
-        field = self.scheme.field
-        check_aggregation_headroom(len(protected), field)
-        # leaves are (w, R, ...) protect outputs: residue axis 1 (same
-        # contract as secure_add)
-        return _fold_sum_streaming(tuple(protected), field, residue_axis=1)
+        with jax.named_scope("aggregate"):
+            field = self.scheme.field
+            check_aggregation_headroom(len(protected), field)
+            # leaves are (w, R, ...) protect outputs: residue axis 1
+            # (same contract as secure_add)
+            return _fold_sum_streaming(tuple(protected), field,
+                                       residue_axis=1)
 
-    @_traced("aggregate")
     def aggregate_batched(self, protected: FlatProtected) -> FlatProtected:
         """Reduce the institution axis of a ``protect_batched`` output.
 
@@ -601,9 +605,11 @@ class SecureCollective:
         share buffer — Algorithm 2 for all S submissions in a single
         dispatch, with no per-submission stacking step.
         """
-        check_aggregation_headroom(protected.buf.shape[2], self.scheme.field)
-        buf = fsum(protected.buf, self.scheme.field, axis=2, residue_axis=1)
-        return FlatProtected(buf, protected.layout)
+        with jax.named_scope("aggregate"):
+            field = self.scheme.field
+            check_aggregation_headroom(protected.buf.shape[2], field)
+            buf = fsum(protected.buf, field, axis=2, residue_axis=1)
+            return FlatProtected(buf, protected.layout)
 
     def allreduce(self, shares, axis_name: str, residue_axis: int = 1,
                   scatter_axis: int | None = None):
@@ -641,7 +647,6 @@ class SecureCollective:
             )
         return points
 
-    @_traced("secure_round")
     def secure_round_batched(self, key: jax.Array, tree,
                              points: Sequence[int] | None = None,
                              dtype=jnp.float64):
@@ -667,7 +672,6 @@ class SecureCollective:
             dtype=dtype,
         )
 
-    @_traced("secure_round")
     def secure_round_multiconfig(self, key: jax.Array, tree,
                                  points: Sequence[int] | None = None,
                                  dtype=jnp.float64):
@@ -713,17 +717,20 @@ class SecureCollective:
         by_config = prot.buf.reshape(w, num_r, c_dim, s_dim, rows, lanes)
         # Algorithm 2 per config: exact uint64 reduction over institutions
         check_aggregation_headroom(s_dim, self.scheme.field)
-        aggd = fsum(by_config, self.scheme.field, axis=3, residue_axis=1)
+        with jax.named_scope("aggregate"):
+            aggd = fsum(by_config, self.scheme.field, axis=3,
+                        residue_axis=1)
         sel = jnp.asarray([p - 1 for p in points])
         stacked = aggd[sel].reshape(len(points), num_r, c_dim * rows, lanes)
-        flat = _reveal_flat(
-            stacked, self.scheme, self.codec.frac_bits, points
-        )  # (C * rows, 128) float64
         from .flatbuf import unpack_pytree_batched
 
-        return unpack_pytree_batched(
-            flat.reshape(c_dim, rows, lanes), prot.layout, dtype=dtype
-        )
+        with jax.named_scope("reveal"):
+            flat = _reveal_flat(
+                stacked, self.scheme, self.codec.frac_bits, points
+            )  # (C * rows, 128) float64
+            return unpack_pytree_batched(
+                flat.reshape(c_dim, rows, lanes), prot.layout, dtype=dtype
+            )
 
     @_traced("reveal")
     def reveal(self, protected, points=None, dtype=jnp.float64):
@@ -741,46 +748,48 @@ class SecureCollective:
         non-contiguous center subset (then they must match the slice
         count).
         """
-        t = self.scheme.threshold
-        if isinstance(protected, FlatProtected):
-            k = protected.buf.shape[0]
-            if k < t:
-                raise ValueError(
-                    f"need >= t={t} shares, got {k} "
-                    "(information-theoretically irrecoverable below "
-                    "threshold)"
+        with jax.named_scope("reveal"):
+            t = self.scheme.threshold
+            if isinstance(protected, FlatProtected):
+                k = protected.buf.shape[0]
+                if k < t:
+                    raise ValueError(
+                        f"need >= t={t} shares, got {k} "
+                        "(information-theoretically irrecoverable below "
+                        "threshold)"
+                    )
+                if points is None:
+                    buf = protected.buf[:t] if k > t else protected.buf
+                    pts = self._validated_points(None)
+                else:
+                    buf = protected.buf
+                    pts = self._validated_points(points)
+                    if len(pts) != k:
+                        raise ValueError("points must match share count")
+                flat = _reveal_flat(
+                    buf, self.scheme, self.codec.frac_bits, pts
                 )
+                return unpack_pytree(flat, protected.layout, dtype=dtype)
             if points is None:
-                buf = protected.buf[:t] if k > t else protected.buf
-                pts = self._validated_points(None)
-            else:
-                buf = protected.buf
-                pts = self._validated_points(points)
-                if len(pts) != k:
-                    raise ValueError("points must match share count")
-            flat = _reveal_flat(
-                buf, self.scheme, self.codec.frac_bits, pts
-            )
-            return unpack_pytree(flat, protected.layout, dtype=dtype)
-        if points is None:
-            # same t-subset default as the flat path: slice each leaf's
-            # holder axis down to the first t shares before reconstructing
-            leaves = jax.tree_util.tree_leaves(protected)
-            k = leaves[0].shape[0] if leaves else 0
-            if k < t:
-                raise ValueError(
-                    f"need >= t={t} shares, got {k} "
-                    "(information-theoretically irrecoverable below "
-                    "threshold)"
+                # same t-subset default as the flat path: slice each
+                # leaf's holder axis down to the first t shares before
+                # reconstructing
+                leaves = jax.tree_util.tree_leaves(protected)
+                k = leaves[0].shape[0] if leaves else 0
+                if k < t:
+                    raise ValueError(
+                        f"need >= t={t} shares, got {k} "
+                        "(information-theoretically irrecoverable below "
+                        "threshold)"
+                    )
+                protected = jax.tree_util.tree_map(
+                    lambda s: s[:t], protected
                 )
-            protected = jax.tree_util.tree_map(
-                lambda s: s[:t], protected
+                points = self._validated_points(None)
+            recon = self.scheme.reconstruct_pytree(protected, list(points))
+            return jax.tree_util.tree_map(
+                lambda v: self.codec.decode(v, dtype=dtype), recon
             )
-            points = self._validated_points(None)
-        recon = self.scheme.reconstruct_pytree(protected, list(points))
-        return jax.tree_util.tree_map(
-            lambda v: self.codec.decode(v, dtype=dtype), recon
-        )
 
     def reveal_wire(self, buf, points: tuple[int, ...]):
         """Reveal a raw (k, R, rows, 128) aggregated share buffer in-graph.

@@ -179,12 +179,13 @@ def newton_step(
     and the one XLA:TPU implements in float64 (its LU decomposition is
     float32-only).
     """
-    d = beta.shape[0]
-    A = hessian + lam * jnp.eye(d, dtype=hessian.dtype)
-    rhs = gradient - lam * beta
-    return beta + jax.scipy.linalg.cho_solve(
-        jax.scipy.linalg.cho_factor(A), rhs
-    )
+    with jax.named_scope("newton_solve"):
+        d = beta.shape[0]
+        A = hessian + lam * jnp.eye(d, dtype=hessian.dtype)
+        rhs = gradient - lam * beta
+        return beta + jax.scipy.linalg.cho_solve(
+            jax.scipy.linalg.cho_factor(A), rhs
+        )
 
 
 def _soft_threshold(x, t):
@@ -215,31 +216,33 @@ def prox_newton_step(
     """
     if l1 == 0.0:
         return newton_step(beta, hessian, gradient, lam)
-    d = beta.shape[0]
-    A = hessian + lam * jnp.eye(d, dtype=hessian.dtype)
-    # Lipschitz constant of the quadratic part: A's largest eigenvalue
-    # (A is symmetric, so this is its spectral norm)
-    L = jnp.linalg.eigvalsh(A)[-1] + 1e-12
-    # gradient of the smooth part at b: A (b - beta) - g + lam*beta
-    #   (expand: H(b-beta) + lam*b - g ... careful) — derive:
-    #   m_smooth(b) = -g^T(b-beta) + .5 (b-beta)^T H (b-beta) + lam/2 b^T b
-    #   grad = -g + H (b - beta) + lam b
+    with jax.named_scope("newton_solve"):
+        d = beta.shape[0]
+        A = hessian + lam * jnp.eye(d, dtype=hessian.dtype)
+        # Lipschitz constant of the quadratic part: A's largest eigenvalue
+        # (A is symmetric, so this is its spectral norm)
+        L = jnp.linalg.eigvalsh(A)[-1] + 1e-12
+        # gradient of the smooth part at b: A (b - beta) - g + lam*beta
+        #   (expand: H(b-beta) + lam*b - g ... careful) — derive:
+        #   m_smooth(b) = -g^T(b-beta) + .5 (b-beta)^T H (b-beta)
+        #                 + lam/2 b^T b
+        #   grad = -g + H (b - beta) + lam b
 
-    def grad_smooth(b):
-        return -gradient + hessian @ (b - beta) + lam * b
+        def grad_smooth(b):
+            return -gradient + hessian @ (b - beta) + lam * b
 
-    def fista(carry, _):
-        b, z, t = carry
-        b_new = _soft_threshold(z - grad_smooth(z) / L, l1 / L)
-        t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * t * t))
-        z_new = b_new + ((t - 1.0) / t_new) * (b_new - b)
-        return (b_new, z_new, t_new), None
+        def fista(carry, _):
+            b, z, t = carry
+            b_new = _soft_threshold(z - grad_smooth(z) / L, l1 / L)
+            t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * t * t))
+            z_new = b_new + ((t - 1.0) / t_new) * (b_new - b)
+            return (b_new, z_new, t_new), None
 
-    (b, _, _), _ = jax.lax.scan(
-        fista, (beta, beta, jnp.asarray(1.0, beta.dtype)), None,
-        length=inner_steps,
-    )
-    return b
+        (b, _, _), _ = jax.lax.scan(
+            fista, (beta, beta, jnp.asarray(1.0, beta.dtype)), None,
+            length=inner_steps,
+        )
+        return b
 
 
 def centralized_fit(

@@ -50,7 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import metrics as _metrics
-from ..obs.trace import traced as _traced
+from ..obs.trace import span as _span, traced as _traced
 from .batched_summaries import (
     BACKENDS as SUMMARY_BACKENDS,
     pack_cache_evict,
@@ -161,8 +161,16 @@ def _round_bytes(d: int, cohort_size: int, protect: str,
 
 
 class StudyCoordinator:
-    """Drives Algorithm 1 across institutions + centers, fault-tolerantly."""
+    """Drives Algorithm 1 across institutions + centers, fault-tolerantly.
 
+    With tracing on (``repro.obs.trace``) a scan fit's host work shows as
+    spans named ``StudyCoordinator.<phase>``: ``__init__``, and inside
+    ``step_block`` ``cohort`` (responders, live centers, stragglers),
+    ``pack``, ``dispatch``, ``readback`` and ``reports``; ``result`` is
+    the final beta's transfer in ``run``.
+    """
+
+    @_traced("coordinator")
     def __init__(
         self,
         institutions: Sequence[Institution],
@@ -519,69 +527,75 @@ class StudyCoordinator:
             raise RuntimeError("step_block requires rounds='scan'")
         from .scanfit import fit_scan_block
 
-        cohort = self.cohort()
-        if self.protect != "none":
-            self.live_centers()
-        stragglers = [
-            i.name for i in self.institutions
-            if i.online and i not in cohort
-        ]
-        num_live = sum(1 for c in self.centers if c.online)
-        d = cohort[0].X.shape[1]
-        nbytes = _round_bytes(d, len(cohort), self.protect, self.agg,
-                              num_live)
-        if num_rounds is None:
-            # 50 is run()'s default max_iter — the whole-study budget
-            num_rounds = self.rounds_per_sync or max(50 - self.iteration, 1)
-        self._fire_midround_hooks()
-        if self.protect != "none":
-            points = tuple(c.index for c in self.live_centers())
-        else:
-            points = None
-        packed = pack_partitions([(i.X, i.y) for i in cohort])
-        carry, objs, actives, gnorms, snorms = fit_scan_block(
-            self.beta,
-            jnp.asarray(self._obj_prev, jnp.float64),
-            jnp.asarray(self.converged),
-            jnp.zeros((), jnp.int32),
-            self.key,
-            jnp.asarray(self._round_base, jnp.int32),
-            packed.X, packed.X32, packed.y, packed.counts, self.lam,
-            agg=self.agg, protect=self.protect, l1=0.0,
-            tol=float(self.tol), points=points, include_count=True,
-            summaries_backend=self.summaries_backend,
-            num_rounds=num_rounds, num_parts=len(cohort),
-            max_rounds=num_rounds,
-        )
-        # host-sync: the block's ONE readback — trace + metric leaves +
-        # scalar carry in a single transfer (beta stays on device)
-        objs, actives, gnorms, snorms, obj_prev_h, conv_h, base_h = \
-            jax.device_get(
-                (objs, actives, gnorms, snorms,
-                 carry[1], carry[2], carry[4])
+        with _span("coordinator", "StudyCoordinator.cohort"):
+            cohort = self.cohort()
+            if self.protect != "none":
+                self.live_centers()
+            stragglers = [
+                i.name for i in self.institutions
+                if i.online and i not in cohort
+            ]
+            num_live = sum(1 for c in self.centers if c.online)
+            d = cohort[0].X.shape[1]
+            nbytes = _round_bytes(d, len(cohort), self.protect, self.agg,
+                                  num_live)
+            if num_rounds is None:
+                # 50 is run()'s default max_iter — the whole-study budget
+                num_rounds = self.rounds_per_sync or max(
+                    50 - self.iteration, 1)
+            self._fire_midround_hooks()
+            if self.protect != "none":
+                points = tuple(c.index for c in self.live_centers())
+            else:
+                points = None
+        with _span("coordinator", "StudyCoordinator.pack"):
+            packed = pack_partitions([(i.X, i.y) for i in cohort])
+        with _span("coordinator", "StudyCoordinator.dispatch"):
+            carry, objs, actives, gnorms, snorms = fit_scan_block(
+                self.beta,
+                jnp.asarray(self._obj_prev, jnp.float64),
+                jnp.asarray(self.converged),
+                jnp.zeros((), jnp.int32),
+                self.key,
+                jnp.asarray(self._round_base, jnp.int32),
+                packed.X, packed.X32, packed.y, packed.counts, self.lam,
+                agg=self.agg, protect=self.protect, l1=0.0,
+                tol=float(self.tol), points=points, include_count=True,
+                summaries_backend=self.summaries_backend,
+                num_rounds=num_rounds, num_parts=len(cohort),
+                max_rounds=num_rounds,
             )
+        with _span("coordinator", "StudyCoordinator.readback"):
+            # host-sync: the block's ONE readback — trace + metric leaves
+            # + scalar carry in a single transfer (beta stays on device)
+            objs, actives, gnorms, snorms, obj_prev_h, conv_h, base_h = \
+                jax.device_get(
+                    (objs, actives, gnorms, snorms,
+                     carry[1], carry[2], carry[4])
+                )
         new_reports: list[RoundReport] = []
-        for r in range(num_rounds):
-            if not actives[r]:
-                break
-            self.iteration += 1
-            self.trace.append(float(objs[r]))
-            new_reports.append(RoundReport(
-                self.iteration,
-                [i.name for i in cohort],
-                stragglers,
-                [c.index for c in self.centers if c.online],
-                float(objs[r]),
-                nbytes,
-                grad_norm=float(gnorms[r]),
-                step_norm=float(snorms[r]),
-            ))
-            self.reports.append(new_reports[-1])
-            _metrics.observe_round(
-                "coordinator_scan", nbytes,
-                objective=float(objs[r]),
-                grad_norm=float(gnorms[r]), step_norm=float(snorms[r]),
-            )
+        with _span("coordinator", "StudyCoordinator.reports"):
+            for r in range(num_rounds):
+                if not actives[r]:
+                    break
+                self.iteration += 1
+                self.trace.append(float(objs[r]))
+                new_reports.append(RoundReport(
+                    self.iteration,
+                    [i.name for i in cohort],
+                    stragglers,
+                    [c.index for c in self.centers if c.online],
+                    float(objs[r]),
+                    nbytes,
+                    grad_norm=float(gnorms[r]),
+                    step_norm=float(snorms[r]),
+                ))
+                self.reports.append(new_reports[-1])
+                _metrics.observe_round(
+                    "coordinator_scan", nbytes,
+                    objective=float(objs[r]),
+                    grad_norm=float(gnorms[r]), step_norm=float(snorms[r]),
+                )
         self.beta = carry[0]
         self._obj_prev = float(obj_prev_h)
         self.converged = bool(conv_h)
@@ -629,7 +643,8 @@ class StudyCoordinator:
                 self.step_block(min(block, max_iter - self.iteration))
             else:
                 self.step()
-        return np.asarray(self.beta)
+        with _span("coordinator", "StudyCoordinator.result"):
+            return np.asarray(self.beta)
 
     # -- checkpointing ----------------------------------------------------------
     def state_dict(self) -> dict:

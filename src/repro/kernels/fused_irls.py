@@ -153,6 +153,7 @@ def fused_irls_pallas(
             jax.ShapeDtypeStruct((s_dim, 1, 128), X.dtype),
         ],
         interpret=resolve_interpret(interpret),
+        name="fused_irls_pallas",
     )(counts.astype(jnp.int32), beta.reshape(1, d), X, Xm,
       y.reshape(s_dim, 1, n))
     return H, g[:, 0], dev[:, 0, 0]
@@ -160,33 +161,34 @@ def fused_irls_pallas(
 
 def _sim_terms(beta, X, y, counts):
     """The simulation's f64 z/g/dev terms and the f32 IRLS weights."""
-    n = X.shape[1]
-    mask = (
-        jnp.arange(n, dtype=jnp.int32)[None, :] < counts[:, None]
-    ).astype(jnp.float64)
-    if X.dtype == jnp.float32:
-        z = jax.lax.dot_general(
-            X, beta.astype(jnp.float32), (((2,), (0,)), ((), ())),
-            preferred_element_type=jnp.float64,
-        )
-    else:
-        z = jnp.einsum("snd,d->sn", X, beta.astype(X.dtype))
-    p = jax.nn.sigmoid(z)
-    w32 = ((p * (1.0 - p)) * mask).astype(jnp.float32)
-    resid = (y - p) * mask
-    if X.dtype == jnp.float32:
-        r32 = resid.astype(jnp.float32)
-        g = jnp.stack([
-            jax.lax.dot_general(
-                r32[j], X[j], (((0,), (0,)), ((), ())),
+    with jax.named_scope("f64_terms"):
+        n = X.shape[1]
+        mask = (
+            jnp.arange(n, dtype=jnp.int32)[None, :] < counts[:, None]
+        ).astype(jnp.float64)
+        if X.dtype == jnp.float32:
+            z = jax.lax.dot_general(
+                X, beta.astype(jnp.float32), (((2,), (0,)), ((), ())),
                 preferred_element_type=jnp.float64,
             )
-            for j in range(X.shape[0])
-        ])
-    else:
-        g = jnp.einsum("snd,sn->sd", X, resid)
-    dev = -2.0 * jnp.sum((y * z - jnp.logaddexp(0.0, z)) * mask, axis=1)
-    return w32, g, dev
+        else:
+            z = jnp.einsum("snd,d->sn", X, beta.astype(X.dtype))
+        p = jax.nn.sigmoid(z)
+        w32 = ((p * (1.0 - p)) * mask).astype(jnp.float32)
+        resid = (y - p) * mask
+        if X.dtype == jnp.float32:
+            r32 = resid.astype(jnp.float32)
+            g = jnp.stack([
+                jax.lax.dot_general(
+                    r32[j], X[j], (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float64,
+                )
+                for j in range(X.shape[0])
+            ])
+        else:
+            g = jnp.einsum("snd,sn->sd", X, resid)
+        dev = -2.0 * jnp.sum((y * z - jnp.logaddexp(0.0, z)) * mask, axis=1)
+        return w32, g, dev
 
 
 @jax.jit
@@ -220,13 +222,14 @@ def fused_irls_sim(beta, X, Xm, y, counts):
     Xm.
     """
     w32, g, dev = _sim_terms(beta, X, y, counts)
-    H = jnp.stack([
-        jnp.einsum(
-            "n,ni,nj->ij", w32[j], Xm[j], Xm[j],
-            preferred_element_type=jnp.float32,
-        )
-        for j in range(X.shape[0])
-    ])
+    with jax.named_scope("gram"):
+        H = jnp.stack([
+            jnp.einsum(
+                "n,ni,nj->ij", w32[j], Xm[j], Xm[j],
+                preferred_element_type=jnp.float32,
+            )
+            for j in range(X.shape[0])
+        ])
     return H, g, dev
 
 
@@ -338,6 +341,7 @@ def fused_irls_cv_pallas(
             scalar, scalar, scalar, scalar,
         ],
         interpret=resolve_interpret(interpret),
+        name="fused_irls_cv_pallas",
     )(counts.astype(jnp.int32), fold_of.astype(jnp.int32),
       betas.reshape(c_dim, 1, d), X, Xm, y.reshape(s_dim, 1, n),
       fold_ids.astype(jnp.int32).reshape(s_dim, 1, n))
@@ -347,35 +351,36 @@ def fused_irls_cv_pallas(
 def _cv_sim_terms(betas, X, y, counts, fold_ids, fold_of):
     """The CV simulation's f64 terms: train weights (f32), g, train/val
     deviance, held-out correct and count."""
-    s_dim, n = X.shape[0], X.shape[1]
-    row_ok = jnp.arange(n, dtype=jnp.int32)[None, :] < counts[:, None]
-    on_fold = fold_ids[None] == fold_of[:, None, None]  # (C, S, N)
-    hold = row_ok[None] & on_fold
-    tmask = (row_ok[None] & ~on_fold).astype(jnp.float64)
-    vmask = hold.astype(jnp.float64)
-    z = jax.lax.dot_general(
-        X, betas.astype(X.dtype), (((2,), (1,)), ((), ())),
-        preferred_element_type=jnp.float64,
-    )  # (S, N, C)
-    z = jnp.moveaxis(z, -1, 0)  # (C, S, N)
-    p = jax.nn.sigmoid(z)
-    ll = y[None] * z - jnp.logaddexp(0.0, z)
-    dev_tr = -2.0 * jnp.sum(ll * tmask, axis=2)
-    dev_va = -2.0 * jnp.sum(ll * vmask, axis=2)
-    acc_va = jnp.sum(
-        jnp.where((z > 0.0) == (y[None] > 0.5), vmask, 0.0), axis=2
-    )
-    n_va = jnp.sum(vmask, axis=2)
-    resid = (y[None] - p) * tmask  # (C, S, N) f64
-    g = jnp.stack([
-        jax.lax.dot_general(
-            resid[:, s], X[s], (((1,), (0,)), ((), ())),
+    with jax.named_scope("f64_terms"):
+        s_dim, n = X.shape[0], X.shape[1]
+        row_ok = jnp.arange(n, dtype=jnp.int32)[None, :] < counts[:, None]
+        on_fold = fold_ids[None] == fold_of[:, None, None]  # (C, S, N)
+        hold = row_ok[None] & on_fold
+        tmask = (row_ok[None] & ~on_fold).astype(jnp.float64)
+        vmask = hold.astype(jnp.float64)
+        z = jax.lax.dot_general(
+            X, betas.astype(X.dtype), (((2,), (1,)), ((), ())),
             preferred_element_type=jnp.float64,
+        )  # (S, N, C)
+        z = jnp.moveaxis(z, -1, 0)  # (C, S, N)
+        p = jax.nn.sigmoid(z)
+        ll = y[None] * z - jnp.logaddexp(0.0, z)
+        dev_tr = -2.0 * jnp.sum(ll * tmask, axis=2)
+        dev_va = -2.0 * jnp.sum(ll * vmask, axis=2)
+        acc_va = jnp.sum(
+            jnp.where((z > 0.0) == (y[None] > 0.5), vmask, 0.0), axis=2
         )
-        for s in range(s_dim)
-    ], axis=1)  # (C, S, d)
-    w32 = ((p * (1.0 - p)) * tmask).astype(jnp.float32)
-    return w32, g, dev_tr, dev_va, acc_va, n_va
+        n_va = jnp.sum(vmask, axis=2)
+        resid = (y[None] - p) * tmask  # (C, S, N) f64
+        g = jnp.stack([
+            jax.lax.dot_general(
+                resid[:, s], X[s], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float64,
+            )
+            for s in range(s_dim)
+        ], axis=1)  # (C, S, d)
+        w32 = ((p * (1.0 - p)) * tmask).astype(jnp.float32)
+        return w32, g, dev_tr, dev_va, acc_va, n_va
 
 
 @jax.jit
@@ -409,7 +414,8 @@ def fused_irls_cv_sim(betas, X, Xm, y, counts, fold_ids, fold_of):
             for s in range(s_dim)
         ])
 
-    H = jax.lax.map(gram_one_config, w32)  # (C, S, d, d)
+    with jax.named_scope("gram"):
+        H = jax.lax.map(gram_one_config, w32)  # (C, S, d, d)
     return H, g, dev_tr, dev_va, acc_va, n_va
 
 
@@ -447,4 +453,5 @@ def gram_hessian_pallas(
         out_specs=pl.BlockSpec((d, d), lambda i: (ZERO, ZERO)),
         out_shape=jax.ShapeDtypeStruct((d, d), jnp.float32),
         interpret=resolve_interpret(interpret),
+        name="gram_hessian_pallas",
     )(X, w.reshape(1, n))

@@ -112,15 +112,18 @@ def fused_irls(beta, X, y, counts=None, block_n: int = 512,
         counts = jnp.full((s_dim,), n, jnp.int32)
     counts = counts.astype(jnp.int32)
     if _use_simulation(simulate):
-        Xm = X.astype(jnp.float32) if mxu_operand is None else mxu_operand
+        with jax.named_scope("operands"):
+            Xm = X.astype(jnp.float32) if mxu_operand is None \
+                else mxu_operand
         return fused_irls_sim(beta, X, Xm, y, counts)
     bn = _block(n, block_n)
-    Xp, Xmp, yp = _kernel_operands(X, mxu_operand, y, bn)
-    H, g, dev = fused_irls_pallas(
-        _pad_to(beta, 128, 0).astype(Xp.dtype), Xp, Xmp, yp, counts,
-        block_n=bn,
-    )
-    H = H[:, :d, :d]
+    with jax.named_scope("operands"):
+        Xp, Xmp, yp = _kernel_operands(X, mxu_operand, y, bn)
+        betap = _pad_to(beta, 128, 0).astype(Xp.dtype)
+    with jax.named_scope("gram"):
+        H, g, dev = fused_irls_pallas(betap, Xp, Xmp, yp, counts,
+                                      block_n=bn)
+        H = H[:, :d, :d]
     if Xp.dtype == X.dtype:
         return H, g[:, :d], dev
     _, g, dev = _sim_terms(beta, X, y, counts)
@@ -148,16 +151,20 @@ def fused_irls_cv(betas, X, y, fold_ids, fold_of, counts=None,
     fold_ids = fold_ids.astype(jnp.int32)
     fold_of = fold_of.astype(jnp.int32)
     if _use_simulation(simulate):
-        Xm = X.astype(jnp.float32) if mxu_operand is None else mxu_operand
+        with jax.named_scope("operands"):
+            Xm = X.astype(jnp.float32) if mxu_operand is None \
+                else mxu_operand
         return fused_irls_cv_sim(betas, X, Xm, y, counts, fold_ids, fold_of)
     bn = _block(n, block_n)
-    Xp, Xmp, yp = _kernel_operands(X, mxu_operand, y, bn)
-    fidp = _pad_to(fold_ids, bn, 1)  # padded rows are row-masked anyway
-    H, g, dtr, dva, acc, nva = fused_irls_cv_pallas(
-        _pad_to(betas, 128, 1).astype(Xp.dtype), Xp, Xmp, yp, counts,
-        fidp, fold_of, block_n=bn,
-    )
-    H = H[:, :, :d, :d]
+    with jax.named_scope("operands"):
+        Xp, Xmp, yp = _kernel_operands(X, mxu_operand, y, bn)
+        fidp = _pad_to(fold_ids, bn, 1)  # padded rows are row-masked
+        betasp = _pad_to(betas, 128, 1).astype(Xp.dtype)
+    with jax.named_scope("gram"):
+        H, g, dtr, dva, acc, nva = fused_irls_cv_pallas(
+            betasp, Xp, Xmp, yp, counts, fidp, fold_of, block_n=bn,
+        )
+        H = H[:, :, :d, :d]
     if Xp.dtype == X.dtype:
         return H, g[:, :, :d], dtr, dva, acc, nva
     _, g, dtr, dva, acc, nva = _cv_sim_terms(

@@ -111,9 +111,11 @@ def _share_kernel(secret_ref, coeffs_ref, out_ref, *, points, moduli):
             )
 
 
-def _share_residues(secret, coeffs, points, moduli, block_rows, interpret):
+def _share_residues(secret, coeffs, points, moduli, block_rows, interpret,
+                    name):
     """(R, rows, 128) residues + (R, t-1, rows, 128) coefficients ->
-    (R, len(points), rows, 128) uint32 shares in one launch."""
+    (R, len(points), rows, 128) uint32 shares in one launch, under the
+    kernel name ``name`` (what a device trace shows for the launch)."""
     num_residues, rows, lanes = secret.shape
     assert lanes == 128 and rows % block_rows == 0, "ops.py reshapes/pads"
     assert len(moduli) == num_residues == coeffs.shape[0]
@@ -136,6 +138,7 @@ def _share_residues(secret, coeffs, points, moduli, block_rows, interpret):
             (num_residues, len(points), rows, 128), jnp.uint32
         ),
         interpret=resolve_interpret(interpret),
+        name=name,
     )(secret, coeffs)
 
 
@@ -154,7 +157,7 @@ def shamir_poly_pallas(
     """Returns (num_shares, rows, 128) uint32 shares (one residue)."""
     out = _share_residues(
         secret[None], coeffs[None], tuple(range(1, num_shares + 1)),
-        (modulus,), block_rows, interpret,
+        (modulus,), block_rows, interpret, "shamir_poly_pallas",
     )
     return out[0]
 
@@ -215,5 +218,5 @@ def shamir_encode_share_pallas(
     assert all(1 <= p <= num_shares for p in points)
     return _share_residues(
         encode_residues(x, moduli, frac_bits), coeffs, tuple(points),
-        tuple(moduli), block_rows, interpret,
+        tuple(moduli), block_rows, interpret, "shamir_encode_share_pallas",
     )

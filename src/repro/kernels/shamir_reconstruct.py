@@ -134,4 +134,5 @@ def shamir_reconstruct_pallas(
             (num_residues, rows, 128), jnp.uint32
         ),
         interpret=resolve_interpret(interpret),
+        name="shamir_reconstruct_pallas",
     )(shares)

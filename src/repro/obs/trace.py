@@ -11,19 +11,19 @@ Exporters:
 
 * :meth:`SpanTracer.export_jsonl` — one JSON object per line, the run
   ledger ``results/show.py`` renders;
-* :meth:`SpanTracer.export_chrome_trace` — the Chrome trace-event JSON
-  (``ph: "X"`` duration events, microsecond timestamps) that opens
-  directly in ``chrome://tracing`` or https://ui.perfetto.dev;
 * :meth:`SpanTracer.summary_lines` — the per-kind wall-time table the
   examples print.
 
 Optional ``jax.profiler`` hook: ``enable(profiler=True)`` additionally
 wraps every span in a ``jax.profiler.TraceAnnotation`` so spans land
-inside a captured XLA profile.  The import is lazy and failure-tolerant
-on purpose — this module must import WITHOUT jax (the jax-free
-``runtime.supervisor`` layer uses it), and the obs purity lint
-(``repro.analysis.lints.lint_obs_purity``) pins that no module-level jax
-import, host callback, or device materialization ever creeps in here.
+inside a captured XLA profile, on the device ops' own clock (capture
+one with ``jax.profiler.trace(log_dir)``; the device ops carry the
+``jax.named_scope`` names of the round's phases).  The import is lazy
+and failure-tolerant on purpose — this module must import WITHOUT jax
+(the jax-free ``runtime.supervisor`` layer uses it), and the obs purity
+lint (``repro.analysis.lints.lint_obs_purity``) pins that no
+module-level jax import, host callback, or device materialization ever
+creeps in here.
 """
 from __future__ import annotations
 
@@ -171,29 +171,6 @@ class SpanTracer:
                 fh.write(json.dumps(s.to_dict()) + "\n")
         return len(spans)
 
-    def export_chrome_trace(self, path) -> int:
-        """Chrome trace-event JSON (open in chrome://tracing / Perfetto)."""
-        with self._lock:
-            spans = list(self.spans)
-        t_origin = min((s.t0 for s in spans), default=0.0)
-        events = [
-            {
-                "name": s.name,
-                "cat": s.kind,
-                "ph": "X",
-                "ts": (s.t0 - t_origin) * 1e6,
-                "dur": s.duration * 1e6,
-                "pid": 0,
-                "tid": s.tid,
-                "args": {k: _jsonable(v) for k, v in s.attrs.items()},
-            }
-            for s in spans
-        ]
-        with open(path, "w") as fh:
-            json.dump({"traceEvents": events,
-                       "displayTimeUnit": "ms"}, fh)
-        return len(events)
-
     # -- summaries ---------------------------------------------------------
     def summary(self) -> dict:
         """Per-kind {count, total_s, mean_s, max_s} aggregates."""
@@ -225,11 +202,6 @@ class SpanTracer:
                 f"{rec['max_s'] * 1e3:>9.3f}"
             )
         return lines
-
-
-def _jsonable(v):
-    return v if isinstance(v, (int, float, str, bool, type(None))) \
-        else str(v)
 
 
 # -- module-level tracer (what the drivers call) ----------------------------

@@ -3,7 +3,9 @@
 The tentpole invariants:
 
 * disabled tracing is invisible (no spans, bit-identical fits);
-* the span exporters round-trip (JSONL) and emit valid Chrome traces;
+* the span exporter round-trips (JSONL);
+* a coordinator's scan fit records its host phases as spans inside
+  ``step_block`` and ``run``;
 * the privacy ledger counts every host-wrapper invocation of a
   declassification boundary, and the audit reconciles those counts
   against the static gate's certified jaxpr census — with the
@@ -106,16 +108,11 @@ def test_jsonl_roundtrip_and_chrome_trace(tmp_path):
         for line in fh:
             back.record(json.loads(line))
     assert back.summary() == tracer.summary()
-
-    tracer.export_chrome_trace(tmp_path / "run.trace.json")
-    doc = json.loads((tmp_path / "run.trace.json").read_text())
-    events = doc["traceEvents"]
-    assert {e["ph"] for e in events} == {"X"}
-    assert all(e["ts"] >= 0 and e["dur"] >= 0 for e in events)
-    by_name = {e["name"]: e for e in events}
+    by_name = {s.name: s for s in back.spans}
     # the reveal span nests inside the protect span on the timeline
-    assert by_name["r"]["ts"] >= by_name["p"]["ts"]
-    assert by_name["p"]["args"] == {"rows": 8}
+    assert by_name["p"].t0 <= by_name["r"].t0 <= by_name["r"].t1 \
+        <= by_name["p"].t1
+    assert by_name["p"].attrs == {"rows": 8}
 
 
 def test_driver_emits_spans(study):
@@ -128,6 +125,42 @@ def test_driver_emits_spans(study):
     assert {"newton", "protect", "aggregate", "reveal"} <= kinds
 
 
+COORDINATOR_SPANS = ["StudyCoordinator.__init__", "StudyCoordinator.cohort",
+                     "StudyCoordinator.pack", "StudyCoordinator.dispatch",
+                     "StudyCoordinator.readback", "StudyCoordinator.reports",
+                     "StudyCoordinator.result"]
+
+
+def test_coordinator_phases_are_spans_inside_its_fit(study):
+    from repro.core import Institution, SecureCollective, StudyCoordinator
+
+    sites = [Institution(f"s{j}", X, y)
+             for j, (X, y) in enumerate(study.parts)]
+    tracer = trace.enable()
+    try:
+        coord = StudyCoordinator(
+            sites, lam=1.0, protect="both",
+            aggregator=SecureCollective(backend="pallas"), fused=True,
+            rounds="scan", rounds_per_sync=2)
+        coord.run(max_iter=6)
+    finally:
+        trace.disable()
+    spans = list(tracer.spans)
+    assert set(COORDINATOR_SPANS) <= {s.name for s in spans}
+    assert {s.kind for s in spans if s.name in COORDINATOR_SPANS} == {
+        "coordinator"}
+    blocks = [s for s in spans if s.name == "StudyCoordinator.step_block"]
+    assert len(blocks) == -(-coord.iteration // 2) >= 2
+    for phase in ("cohort", "pack", "dispatch", "readback", "reports"):
+        inner = [s for s in spans if s.name == f"StudyCoordinator.{phase}"]
+        assert len(inner) == len(blocks)
+        for s, b in zip(inner, blocks):
+            assert b.t0 <= s.t0 <= s.t1 <= b.t1
+    (init,) = [s for s in spans if s.name == "StudyCoordinator.__init__"]
+    (result,) = [s for s in spans if s.name == "StudyCoordinator.result"]
+    assert init.t1 <= blocks[0].t0 and blocks[-1].t1 <= result.t0
+
+
 def test_tracing_is_bit_invisible(study):
     from repro.core.newton import SecureFitDriver
 
@@ -138,11 +171,27 @@ def test_tracing_is_bit_invisible(study):
         d.run(max_iter=6)
         return np.asarray(d.beta)
 
-    off = fit()
-    trace.enable()
-    on = fit()
+    def coordinator_fit():
+        from repro.core import Institution, StudyCoordinator
+
+        sites = [Institution(f"s{j}", X, y)
+                 for j, (X, y) in enumerate(study.parts)]
+        c = StudyCoordinator(sites, lam=1.0, protect="both",
+                             aggregator=SecureAggregator(backend="pallas"),
+                             fused=True, rounds="scan", rounds_per_sync=2)
+        beta = c.run(max_iter=6)
+        return beta, np.asarray(c.trace), c.iteration
+
+    off, coord_off = fit(), coordinator_fit()
+    tracer = trace.enable(profiler=True)  # spans as profiler annotations
+    on, coord_on = fit(), coordinator_fit()
     trace.disable()
     np.testing.assert_array_equal(off, on)
+    # the coordinator's phase spans fired and changed none of its answers
+    assert "StudyCoordinator.readback" in {s.name for s in tracer.spans}
+    np.testing.assert_array_equal(coord_off[0], coord_on[0])
+    np.testing.assert_array_equal(coord_off[1], coord_on[1])
+    assert coord_off[2] == coord_on[2]
 
 
 # ---------------------------------------------------------- privacy ledger

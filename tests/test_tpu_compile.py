@@ -4,18 +4,32 @@ Each test lowers with ``interpret=False`` at the widths the secure fit
 runs (d = 128 features, 2-of-3 Shamir over the CRT field, N = 25,000 rows
 per institution) against a described, unattached v5e chip, so a
 construct the TPU compiler refuses fails here rather than on the chip.
-Nothing runs: these say nothing about results or speed.
+Nothing runs: these say nothing about results or speed.  The names a
+device trace reads are checked here too: each launch's kernel name, and
+the named scopes of the fit program as the chip runs it.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+import repro.kernels.backend as kernel_backend
+import repro.kernels.ops as kernel_ops
+from repro.core import SecureCollective
+from repro.core.batched_summaries import pack_partitions
 from repro.core.field import FIELD_WIDE
 from repro.core.newton import newton_step
-from repro.kernels.fused_irls import fused_irls_cv_pallas, fused_irls_pallas
+from repro.core.scanfit import fit_scan_block
+from repro.data import generate_synthetic
+from repro.kernels.fused_irls import (
+    fused_irls_cv_pallas,
+    fused_irls_pallas,
+    gram_hessian_pallas,
+)
 from repro.kernels.shamir_poly import (
     shamir_encode_share_pallas,
     shamir_poly_pallas,
@@ -24,6 +38,8 @@ from repro.kernels.shamir_reconstruct import (
     lagrange_weights_host,
     shamir_reconstruct_pallas,
 )
+
+from bench.scopes import SCOPES, hlo_index
 
 D = 128  # features (the acceptance width)
 S = 8  # institutions
@@ -136,3 +152,112 @@ def test_newton_step_compiles_in_float64(one_chip):
         ((D,), jnp.float64),
     )
     assert c.as_text()
+
+
+# -- names a device trace reads ----------------------------------------------
+
+def _shapes(*specs):
+    return [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in specs]
+
+
+KERNEL_NAMES = {
+    "fused_irls_pallas": (
+        lambda b, x, y, c: fused_irls_pallas(b, x, x, y, c, block_n=256,
+                                             interpret=False),
+        _shapes(((D,), jnp.float32), ((2, 512, D), jnp.float32),
+                ((2, 512), jnp.float32), ((2,), jnp.int32))),
+    "fused_irls_cv_pallas": (
+        lambda b, x, y, c, f, o: fused_irls_cv_pallas(
+            b, x, x, y, c, f, o, block_n=512, interpret=False),
+        _shapes(((3, D), jnp.float32), ((2, 512, D), jnp.float32),
+                ((2, 512), jnp.float32), ((2,), jnp.int32),
+                ((2, 512), jnp.int32), ((3,), jnp.int32))),
+    "gram_hessian_pallas": (
+        lambda x, w: gram_hessian_pallas(x, w, block_n=256,
+                                         interpret=False),
+        _shapes(((512, D), jnp.float32), ((512,), jnp.float32))),
+    "shamir_encode_share_pallas": (
+        lambda x, c: shamir_encode_share_pallas(
+            x, c, W, MODULI, 28, block_rows=8, interpret=False),
+        _shapes(((8, 128), jnp.float64),
+                ((len(MODULI), T - 1, 8, 128), jnp.uint32))),
+    "shamir_poly_pallas": (
+        lambda s, c: shamir_poly_pallas(s, c, W, MODULI[0], block_rows=8,
+                                        interpret=False),
+        _shapes(((8, 128), jnp.uint32), ((T - 1, 8, 128), jnp.uint32))),
+    "shamir_reconstruct_pallas": (
+        lambda s: shamir_reconstruct_pallas(
+            s, lagrange_weights_host((1, 2), MODULI), MODULI, garner=True,
+            block_rows=8, interpret=False),
+        _shapes(((len(MODULI), T, 8, 128), jnp.uint32))),
+}
+
+
+def _on(chip, args):
+    return [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)
+            for a in args]
+
+
+@pytest.mark.parametrize("name", sorted(KERNEL_NAMES))
+def test_kernel_launches_carry_their_names(name, one_chip):
+    fn, args = KERNEL_NAMES[name]
+    text = jax.jit(fn).lower(*_on(one_chip, args)).as_text()
+    assert re.findall(r'kernel_name = "([^"]*)"', text) == [name]
+
+
+def _fit_hlo(rung, key, sites, rows, dim, chip=None):
+    """The HLO of a two-round scan block of the ``rung`` summaries: as
+    XLA compiles it for this host's device, or lowered for ``chip``."""
+    parts = generate_synthetic(jax.random.PRNGKey(key), num_institutions=sites,
+                               records_per_institution=rows, dim=dim).parts
+    packed = pack_partitions(list(parts))
+    args = (jnp.zeros(dim, jnp.float64), jnp.asarray(np.inf),
+            jnp.asarray(False), jnp.zeros((), jnp.int32),
+            jax.random.PRNGKey(0), jnp.zeros((), jnp.int32),
+            packed.X, packed.X32, packed.y, packed.counts,
+            jnp.asarray(1.0))
+    lowered = fit_scan_block.lower(
+        *(args if chip is None else _on(chip, args)),
+        agg=SecureCollective(backend="pallas"), protect="both", l1=0.0,
+        tol=1e-10, points=(1, 2), include_count=True,
+        summaries_backend=rung, num_rounds=2, num_parts=sites,
+        max_rounds=2)
+    if chip is None:
+        return lowered.compile().as_text()
+    return lowered.compiler_ir("hlo").as_hlo_module().to_string()
+
+
+@pytest.mark.parametrize("rung, on_chip", [
+    ("pallas", False), ("reference", False), ("pallas", True)])
+def test_fit_program_carries_every_scope(rung, on_chip, request,
+                                         monkeypatch):
+    """The fit program names every phase of a secure round, as
+    ``bench/scopes.py`` reads it: on this host's ``pallas`` (simulated)
+    and ``reference`` rungs, and the ``pallas`` rung lowered for the chip
+    (the kernels compiled, not interpreted).  The host's rungs take the
+    pre-cast Gram operand, so only the chip pads or casts per call
+    (``summaries/operands``)."""
+    if on_chip:
+        chip = request.getfixturevalue("one_chip")
+        monkeypatch.setattr(kernel_backend, "interpret_kernels",
+                            lambda: False)
+        monkeypatch.setattr(kernel_ops, "interpret_kernels", lambda: False)
+        # shapes no other test traces, and the caches cleared after: no
+        # caller on this host may reuse a trace that holds compiled kernels
+        try:
+            hlo = _fit_hlo(rung, 4, 2, 37, 5, chip)
+        finally:
+            jax.clear_caches()
+    else:
+        hlo = _fit_hlo(rung, 3, 3, 40, 4)
+    index = hlo_index([hlo])
+    found = {scope for entries in index.values() for _, scope in entries}
+    # a part of ``summaries`` found is found under it
+    want = set(SCOPES) - {"summaries"} - (
+        set() if on_chip else {"summaries/operands"})
+    assert want - found == set()
+    if on_chip:
+        # the summaries kernel's launch sits under summaries/gram
+        assert {scope for entries in index.values()
+                for text, scope in entries
+                if "jit(fused_irls_pallas)" in text} == {"summaries/gram"}
