@@ -1,6 +1,6 @@
 """Run the secure consortium fit on one TPU chip and check what comes out.
 
-    python chip_smoke.py             # one chip: phases `paper` and `kernels`
+    python chip_smoke.py             # one chip: `paper`, `kernels`, `sliced`
     python chip_smoke.py --chips 4   # four chips: phase `collective` only
 
 Phases (each prints its name, cold wall seconds with compilation, and its
@@ -17,6 +17,14 @@ checks on one line of JSON):
   beta must lie within the rung's fixed-point contract, (S + 1) / 2**28,
   of ``centralized_fit``, and the compiled round program must hold
   Mosaic kernels (``tpu_custom_call``), not interpreted ones.
+* ``sliced`` — the float64 gradient and deviance terms from bf16 slices
+  of X (``repro.kernels.sliced_terms``) at d=128 on 64 ragged sites,
+  against numpy float64: the slices cut on the chip must give the chip's
+  X back to 2**-55 of each row's largest entry, every f32 partial of the
+  MXU dots must be an integer under 2**24 (an MXU that did not
+  accumulate them exactly would show here first), and z, g and dev must
+  lie within a float64 dot's error bound.  64 sites, because XLA fused
+  the float64 level sum into the dot there and not at 16.
 * ``collective`` (``--chips 4``) — the SPMD secret-shared all-reduce on
   1e6 f32 parameters: the 1D pod mesh of 4 with the sharded reveal and
   the (2, 2) pod x share mesh, each against ``jax.lax.psum`` of the same
@@ -105,13 +113,13 @@ def phase_kernels(num_institutions: int = 8, dim: int = 128,
     bound = (num_institutions + 1) / agg.codec.scale
 
     # the round program exactly as the coordinator dispatches it
-    packed = pack_partitions(list(study.parts))
+    packed = pack_partitions(list(study.parts), backend="pallas")
     rounds = 50
     text = fit_scan_block.lower(
         jnp.zeros((dim,), jnp.float64), jnp.asarray(np.inf),
         jnp.asarray(False), jnp.zeros((), jnp.int32), coord.key,
-        jnp.zeros((), jnp.int32), packed.X, packed.X32, packed.y,
-        packed.counts, 1.0, agg=agg, protect="both", l1=0.0,
+        jnp.zeros((), jnp.int32), packed.X, packed.X32, packed.slices,
+        packed.y, packed.counts, 1.0, agg=agg, protect="both", l1=0.0,
         tol=float(coord.tol),
         points=tuple(c.index for c in coord.live_centers()),
         include_count=True,
@@ -130,6 +138,91 @@ def phase_kernels(num_institutions: int = 8, dim: int = 128,
         "converged_ok": coord.converged and gold.converged,
         "gold_err_ok": err <= bound,
         "compiled_kernels_ok": "tpu_custom_call" in text,
+    })
+
+
+def phase_sliced(sizes=(1, 900, 3000) + (3760,) * 61, dim: int = 128,
+                 seed: int = 0) -> bool:
+    """Sliced float64 terms on the chip against numpy float64."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import repro.core  # noqa: F401  (float64 on)
+    from repro.kernels.sliced_terms import (
+        K, cut_slices, g_levels, sliced_terms, z_levels)
+
+    t0 = time.perf_counter()
+    rng = np.random.default_rng(seed)
+    n = max(sizes)
+    X = rng.standard_normal((len(sizes), n, dim))
+    X[..., 0] = 1.0  # the intercept
+    X[2, 5] *= 2.0 ** 20  # rows far from the others in size
+    X[2, 6] *= 2.0 ** -20
+    X[3, 7] = 0.0
+    y = (rng.random((len(sizes), n)) < 0.5).astype(np.float64)
+    counts = np.asarray(sizes, np.int32)
+    for s, m in enumerate(sizes):
+        X[s, m:] = 0.0
+        y[s, m:] = 0.0
+    beta = rng.uniform(-0.2, 0.2, dim)
+    slices = cut_slices(jnp.asarray(X))
+    _, g, dev = jax.jit(sliced_terms)(jnp.asarray(beta), slices,
+                                      jnp.asarray(y), jnp.asarray(counts))
+    zl, bscale = jax.jit(z_levels)(jnp.asarray(beta), slices.digits)
+    q = rng.uniform(-1.0, 1.0, slices.scale.shape) * np.asarray(
+        slices.scale)
+    gl, _ = jax.jit(g_levels)(jnp.asarray(q), slices.digits)
+    digits = np.asarray(slices.digits, np.float64)
+    scale = np.asarray(slices.scale)
+    back = sum(digits[..., i * dim:(i + 1) * dim] * 2.0 ** (-8 * (i + 1))
+               for i in range(K)) * scale[..., None]
+    # against X as the chip holds it: a TPU keeps float64 as a pair of
+    # f32, some 48 bits, so the numpy X does not survive the transfer
+    X_chip = np.asarray(jnp.asarray(X))
+    row_max = np.abs(X_chip).max(axis=2)
+    cut_err = float(np.max(np.abs(back[:, :n] - X_chip).max(axis=2)
+                           / np.where(row_max > 0, row_max, 1.0)))
+    partials = [np.asarray(zl, np.float64), np.asarray(gl, np.float64)]
+
+    u = 2.0 ** -53
+    mask = (np.arange(n)[None, :] < counts[:, None]).astype(np.float64)
+    z = np.einsum("snd,d->sn", X, beta)
+    zfix = sum(partials[0][lv] * 2.0 ** (-8 * (lv + 2)) for lv in range(K))
+    got_z = (zfix * scale * float(bscale))[:, :n]
+    p = 1.0 / (1.0 + np.exp(-z))
+    r = (y - p) * mask
+    ll = (y * z - np.logaddexp(0.0, z)) * mask
+    xb = np.einsum("snd,d->sn", np.abs(X), np.abs(beta))
+    bound_g = n * u * np.einsum("snd,sn->sd", np.abs(X), np.abs(r))
+    bound_dev = 2.0 * u * (n * np.abs(ll).sum(axis=1)
+                           + dim * (xb * mask).sum(axis=1))
+
+    def worst(err, bound):
+        return float(np.max(err / np.where(bound > 0, bound, 1.0)))
+
+    z_ratio = worst(np.abs(got_z - z), dim * u * xb)
+    g_ratio = worst(np.abs(np.asarray(g) - np.einsum("snd,sn->sd", X, r)),
+                    bound_g)
+    dev_ratio = worst(np.abs(np.asarray(dev) + 2.0 * ll.sum(axis=1)),
+                      bound_dev)
+    return _emit("sliced", time.perf_counter() - t0, {
+        "sites": len(sizes),
+        "rows": n,
+        "features": dim,
+        "cut_err_over_row_max": cut_err,
+        "z_err_over_bound": z_ratio,
+        "g_err_over_bound": g_ratio,
+        "dev_err_over_bound": dev_ratio,
+        "digits_ok": bool(np.all(digits == np.round(digits))
+                          and np.abs(digits).max() <= 128),
+        "cut_ok": cut_err <= 2.0 ** -55,
+        "partials_ok": all(bool(np.all(v == np.round(v))
+                                and np.abs(v).max() < 2.0 ** 24)
+                           for v in partials),
+        "z_ok": z_ratio <= 1.0,
+        "g_ok": g_ratio <= 1.0,
+        "dev_ok": dev_ratio <= 1.0,
     })
 
 
@@ -216,8 +309,8 @@ def phase_collective(params: int = 1_000_000, seed: int = 0) -> bool:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
-                    help="1: the paper and kernels phases on one chip; "
-                         "4: the cross-chip collective phase only")
+                    help="1: the paper, kernels and sliced phases on "
+                         "one chip; 4: the cross-chip collective phase only")
     args = ap.parse_args(argv)
 
     import jax
@@ -236,8 +329,8 @@ def main(argv=None) -> int:
     from repro.launch.compile_cache import enable_compile_cache
 
     enable_compile_cache()
-    phases = [phase_collective] if args.chips == 4 else [phase_paper,
-                                                         phase_kernels]
+    phases = [phase_collective] if args.chips == 4 else [
+        phase_paper, phase_kernels, phase_sliced]
     ok = True
     for phase in phases:
         try:

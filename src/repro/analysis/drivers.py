@@ -83,9 +83,15 @@ def _aggregator():
 
 
 def _packed(num_parts=3, n=8, d=4):
-    from ..core.batched_summaries import pack_partitions
+    """The toy pack, with the digit slices the compiled ``pallas`` rung
+    reads cut here too, so every spec traces the path that reads them."""
+    import dataclasses
 
-    return pack_partitions(toy_parts(num_parts, n, d))
+    from ..core.batched_summaries import pack_partitions
+    from ..kernels.sliced_terms import cut_slices
+
+    packed = pack_partitions(toy_parts(num_parts, n, d))
+    return dataclasses.replace(packed, slices=cut_slices(packed.X))
 
 
 def _fused_spec(name: str, protect: str, include_count: bool):
@@ -97,17 +103,19 @@ def _fused_spec(name: str, protect: str, include_count: bool):
         beta = jnp.zeros((packed.dim,), jnp.float64)
         key = jax.random.PRNGKey(0)
 
-        def fn(beta, key, X, X32, y, counts):
+        def fn(beta, key, X, X32, slices, y, counts):
             return _fused_secure_iteration(
-                beta, key, X, X32, y, counts, 1.0, agg, protect, 0.0,
-                points=None, include_count=include_count,
+                beta, key, X, X32, slices, y, counts, 1.0, agg, protect,
+                0.0, points=None, include_count=include_count,
                 summaries_backend="pallas",
             )
 
         closed = jax.make_jaxpr(fn)(
-            beta, key, packed.X, packed.X32, packed.y, packed.counts
+            beta, key, packed.X, packed.X32, packed.slices, packed.y,
+            packed.counts,
         )
-        taints = [PUBLIC, PUBLIC, SECRET, SECRET, SECRET, SECRET]
+        # the slices (digits, scale) are X's, so secret like X
+        taints = [PUBLIC, PUBLIC] + [SECRET] * 6
         return closed, taints
 
     def runner():
@@ -117,8 +125,9 @@ def _fused_spec(name: str, protect: str, include_count: bool):
         packed = _packed()
         beta = jnp.zeros((packed.dim,), jnp.float64)
         out = _fused_secure_iteration(
-            beta, jax.random.PRNGKey(0), packed.X, packed.X32, packed.y,
-            packed.counts, 1.0, agg, protect, 0.0, points=None,
+            beta, jax.random.PRNGKey(0), packed.X, packed.X32,
+            packed.slices, packed.y, packed.counts, 1.0, agg, protect, 0.0,
+            points=None,
             include_count=include_count, summaries_backend="pallas",
         )
         jax.block_until_ready(out)
@@ -137,10 +146,10 @@ def _scan_spec(name: str, protect: str, include_count: bool):
         key = jax.random.PRNGKey(0)
 
         def fn(beta, obj_prev, conv, iters, key, rbase,
-               X, X32, y, counts):
+               X, X32, slices, y, counts):
             return fit_scan_block(
                 beta, obj_prev, conv, iters, key, rbase,
-                X, X32, y, counts, 1.0,
+                X, X32, slices, y, counts, 1.0,
                 agg=agg, protect=protect, l1=0.0, tol=1e-10,
                 points=None,
                 include_count=include_count,
@@ -151,9 +160,9 @@ def _scan_spec(name: str, protect: str, include_count: bool):
         closed = jax.make_jaxpr(fn)(
             beta, jnp.asarray(np.inf), jnp.asarray(False),
             jnp.zeros((), jnp.int32), key, jnp.zeros((), jnp.int32),
-            packed.X, packed.X32, packed.y, packed.counts,
+            packed.X, packed.X32, packed.slices, packed.y, packed.counts,
         )
-        taints = [PUBLIC] * 6 + [SECRET] * 4
+        taints = [PUBLIC] * 6 + [SECRET] * 6
         return closed, taints
 
     def runner():
@@ -166,8 +175,8 @@ def _scan_spec(name: str, protect: str, include_count: bool):
             beta, jnp.asarray(np.inf), jnp.asarray(False),
             jnp.zeros((), jnp.int32), jax.random.PRNGKey(0),
             jnp.zeros((), jnp.int32),
-            packed.X, packed.X32, packed.y, packed.counts, 1.0,
-            agg=agg, protect=protect, l1=0.0, tol=1e-10,
+            packed.X, packed.X32, packed.slices, packed.y, packed.counts,
+            1.0, agg=agg, protect=protect, l1=0.0, tol=1e-10,
             points=None, include_count=include_count,
             summaries_backend="pallas", num_rounds=3,
             num_parts=packed.num_institutions, max_rounds=3,

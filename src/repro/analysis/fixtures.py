@@ -94,23 +94,23 @@ def _callback_leak_build():
     packed = _packed()
     beta = jnp.zeros((packed.dim,), jnp.float64)
 
-    def fn(beta, key, X, X32, y, counts):
+    def fn(beta, key, X, X32, slices, y, counts):
         sm = batched_local_summaries(
-            beta, PackedPartitions(X, X32, y, counts),
+            beta, PackedPartitions(X, X32, y, counts, slices),
             backend="pallas",
         )
         # LEAK: per-institution deviances shipped to a host logging hook
         jax.debug.callback(lambda d: None, sm.deviance)
         return _fused_secure_iteration(
-            beta, key, X, X32, y, counts, 1.0, agg, "both", 0.0,
+            beta, key, X, X32, slices, y, counts, 1.0, agg, "both", 0.0,
             summaries_backend="pallas",
         )
 
     closed = jax.make_jaxpr(fn)(
-        beta, jax.random.PRNGKey(0), packed.X, packed.X32, packed.y,
-        packed.counts,
+        beta, jax.random.PRNGKey(0), packed.X, packed.X32, packed.slices,
+        packed.y, packed.counts,
     )
-    return closed, [PUBLIC, PUBLIC, SECRET, SECRET, SECRET, SECRET]
+    return closed, [PUBLIC, PUBLIC] + [SECRET] * 6
 
 
 def leak_fixture_specs() -> list:

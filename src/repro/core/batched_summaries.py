@@ -35,6 +35,8 @@ import jax.numpy as jnp
 import numpy as np
 from typing import NamedTuple
 
+from ..kernels.sliced_terms import MAX_DIM, XSlices, cut_slices
+from ..obs import metrics as _metrics
 from .logreg import LocalSummaries
 
 __all__ = ["PackedPartitions", "pack_partitions", "batched_local_summaries",
@@ -54,13 +56,17 @@ class PackedPartitions:
     the fused ``secure_fit`` packs — ``X`` and ``X32`` are the SAME
     array; with float64 (the oracle/test payload) both live side by
     side.  ``y`` stays f64 either way: labels are 0/1 (exact in any
-    float) and the gradient/deviance accumulate in f64.
+    float) and the gradient/deviance accumulate in f64.  ``slices`` are
+    the bf16 digit slices of a float64 ``X`` (``kernels.sliced_terms``),
+    from which the compiled ``pallas`` rung takes its float64 gradient
+    and deviance; ``pack_partitions`` cuts them only for that rung.
     """
 
     X: jnp.ndarray  # (S, N_max, d) payload (f32 or f64)
     X32: jnp.ndarray  # (S, N_max, d) float32 MXU operand
     y: jnp.ndarray  # (S, N_max) float64
     counts: jnp.ndarray  # (S,) int32 true row counts
+    slices: XSlices | None = None  # digit slices of X, or None
 
     @property
     def num_institutions(self) -> int:
@@ -151,9 +157,30 @@ def pack_cache_len() -> int:
     return len(_PACK_CACHE)
 
 
+def _wants_slices(packed: PackedPartitions, backend: str | None) -> bool:
+    """The compiled kernel with a float64 payload of at most ``MAX_DIM``
+    features reads the slices."""
+    from ..kernels.backend import interpret_kernels
+
+    return (backend == "pallas" and packed.slices is None
+            and packed.X.dtype == jnp.float64 and packed.dim <= MAX_DIM
+            and not interpret_kernels())
+
+
+def _with_slices(packed: PackedPartitions, key) -> PackedPartitions:
+    """``packed`` with its slices cut, in its cache entry too."""
+    out = dataclasses.replace(packed, slices=cut_slices(packed.X))
+    _metrics.inc("repro_f64_slice_packs_total")
+    entry = _PACK_CACHE.get(key)
+    if entry is not None and entry[1] is packed:
+        _PACK_CACHE[key] = (entry[0], out)
+    return out
+
+
 def pack_partitions(
     parts: Sequence[tuple[jnp.ndarray, jnp.ndarray]],
     dtype=jnp.float64,
+    backend: str | None = None,
 ) -> PackedPartitions:
     """Stack S ragged (X_j, y_j) partitions into one masked batch.
 
@@ -166,6 +193,10 @@ def pack_partitions(
     benchmark scale; doing it eagerly per part costs 2-3x that).  ``dtype`` is the X payload:
     float64 keeps the exact oracle payload (plus a separate f32 MXU
     operand); float32 stores one f32 buffer total — the TPU layout.
+    ``backend`` names the summaries rung that will read the pack: for
+    the compiled ``pallas`` rung with a float64 payload the pack also
+    carries the slices of X, cut on first use and kept with the cached
+    pack.
     """
     if not parts:
         raise ValueError("need at least one partition")
@@ -183,6 +214,8 @@ def pack_partitions(
         hit = _PACK_CACHE.get(key)
         if hit is not None:
             _PACK_CACHE.move_to_end(key)
+            if _wants_slices(hit[1], backend):
+                return _with_slices(hit[1], key)
             return hit[1]
     counts = np.asarray([Xj.shape[0] for Xj in (p[0] for p in parts)],
                         np.int32)
@@ -201,6 +234,8 @@ def pack_partitions(
         _PACK_CACHE[key] = (refs, packed)
         while len(_PACK_CACHE) > _PACK_CACHE_SIZE:
             _PACK_CACHE.popitem(last=False)
+    if _wants_slices(packed, backend):
+        return _with_slices(packed, key)
     return packed
 
 
@@ -457,6 +492,7 @@ def batched_local_summaries(
             H, g, dev = ops.fused_irls(
                 beta, packed.X, packed.y, packed.counts,
                 block_n=block_n, mxu_operand=packed.X32,
+                slices=packed.slices,
             )
             # protocol dtype: the fixed-point encode needs f64 past 2**24
             H = H.astype(jnp.float64)

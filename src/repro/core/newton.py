@@ -306,7 +306,7 @@ def _iteration_bytes(d: int, num_parts: int, protect: str,
     jax.jit, static_argnames=("agg", "protect", "l1", "points",
                               "include_count", "summaries_backend")
 )
-def _fused_secure_iteration(beta, key, X, X32, y, counts, lam,
+def _fused_secure_iteration(beta, key, X, X32, slices, y, counts, lam,
                             agg: SecureCollective, protect: str, l1: float,
                             points: tuple[int, ...] | None = None,
                             include_count: bool = False,
@@ -333,8 +333,10 @@ def _fused_secure_iteration(beta, key, X, X32, y, counts, lam,
     oracle (the mid-run Newton transient amplifies Hessian perturbation
     ~10-40x, so f32-Gram backends hold only converged-beta parity),
     "pallas"/"mixed" for f32-Gram speed under that relaxed contract.
+    ``X, X32, slices, y, counts`` are the pack's fields
+    (``PackedPartitions``).
     """
-    packed = PackedPartitions(X, X32, y, counts)
+    packed = PackedPartitions(X, X32, y, counts, slices)
     sm = batched_local_summaries(
         beta, packed, backend=summaries_backend
     )
@@ -697,7 +699,7 @@ class SecureFitDriver:
         values, since reconstruction from any >= t points is the same
         field arithmetic wherever it happens.
         """
-        packed = pack_partitions(parts)
+        packed = pack_partitions(parts, backend=self.summaries_backend)
         pts = self._post_protect_points(points)
         if pts is not None and len(pts) == self.agg.scheme.num_shares:
             # all centers live: the default first-t reveal secure_fit
@@ -705,8 +707,9 @@ class SecureFitDriver:
             pts = None
         self.key, sub = jax.random.split(self.key)
         beta_new, obj, grad_norm, step_norm = _fused_secure_iteration(
-            self.beta, sub, packed.X, packed.X32, packed.y, packed.counts,
-            self.lam, self.agg, self.protect, self.l1, points=pts,
+            self.beta, sub, packed.X, packed.X32, packed.slices, packed.y,
+            packed.counts, self.lam, self.agg, self.protect, self.l1,
+            points=pts,
             summaries_backend=self.summaries_backend,
         )
         # host-sync: the one readback per fused iteration — objective plus
@@ -759,7 +762,7 @@ class SecureFitDriver:
             num_rounds = self.rounds_per_sync or max(
                 self.max_iter - self.iteration, 1
             )
-        packed = pack_partitions(parts)
+        packed = pack_partitions(parts, backend=self.summaries_backend)
         pts = self._post_protect_points(points)
         if pts is not None and len(pts) == self.agg.scheme.num_shares:
             pts = None  # the all-live first-t default (cache-friendly)
@@ -770,8 +773,8 @@ class SecureFitDriver:
             jnp.zeros((), jnp.int32),
             self.key,
             jnp.asarray(self._round_base, jnp.int32),
-            packed.X, packed.X32, packed.y, packed.counts, self.lam,
-            agg=self.agg, protect=self.protect, l1=self.l1,
+            packed.X, packed.X32, packed.slices, packed.y, packed.counts,
+            self.lam, agg=self.agg, protect=self.protect, l1=self.l1,
             tol=float(self.tol), points=pts, include_count=False,
             summaries_backend=self.summaries_backend,
             num_rounds=num_rounds, num_parts=len(parts),

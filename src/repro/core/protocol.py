@@ -489,11 +489,12 @@ class StudyCoordinator:
             points = tuple(c.index for c in self.live_centers())
         else:
             points = None
-        packed = pack_partitions([(i.X, i.y) for i in cohort])
+        packed = pack_partitions([(i.X, i.y) for i in cohort],
+                                 backend=self.summaries_backend)
         self.key, sub = jax.random.split(self.key)
         beta_new, obj, grad_norm, step_norm = _fused_secure_iteration(
-            self.beta, sub, packed.X, packed.X32, packed.y, packed.counts,
-            self.lam, self.agg, self.protect, 0.0,
+            self.beta, sub, packed.X, packed.X32, packed.slices, packed.y,
+            packed.counts, self.lam, self.agg, self.protect, 0.0,
             points=points, include_count=True,
             summaries_backend=self.summaries_backend,
         )
@@ -549,7 +550,8 @@ class StudyCoordinator:
             else:
                 points = None
         with _span("coordinator", "StudyCoordinator.pack"):
-            packed = pack_partitions([(i.X, i.y) for i in cohort])
+            packed = pack_partitions([(i.X, i.y) for i in cohort],
+                                     backend=self.summaries_backend)
         with _span("coordinator", "StudyCoordinator.dispatch"):
             carry, objs, actives, gnorms, snorms = fit_scan_block(
                 self.beta,
@@ -558,8 +560,8 @@ class StudyCoordinator:
                 jnp.zeros((), jnp.int32),
                 self.key,
                 jnp.asarray(self._round_base, jnp.int32),
-                packed.X, packed.X32, packed.y, packed.counts, self.lam,
-                agg=self.agg, protect=self.protect, l1=0.0,
+                packed.X, packed.X32, packed.slices, packed.y, packed.counts,
+                self.lam, agg=self.agg, protect=self.protect, l1=0.0,
                 tol=float(self.tol), points=points, include_count=True,
                 summaries_backend=self.summaries_backend,
                 num_rounds=num_rounds, num_parts=len(cohort),

@@ -66,7 +66,7 @@ def scan_rounds(round_fn, skip_fn, settled_fn, carry0, num_rounds: int):
                      "num_parts", "max_rounds"),
 )
 def fit_scan_block(beta, obj_prev, converged, iters, key, round_base,
-                   X, X32, y, counts, lam,
+                   X, X32, slices, y, counts, lam,
                    agg: SecureCollective, protect: str, l1: float,
                    tol: float,
                    points: tuple[int, ...] | None,
@@ -77,8 +77,11 @@ def fit_scan_block(beta, obj_prev, converged, iters, key, round_base,
     The single-λ mirror of the selection sweep's ``_cv_sweep_block``:
     every slot runs the full protect -> aggregate -> reveal -> Newton
     round in-graph, with the protect rng folded from ``(key, slot)``.
-    Returns ``(carry, objs, actives, grad_norms, step_norms)`` where
-    carry is ``(beta, obj_prev, converged, iters, slot)`` and the
+    ``X``, ``X32``, ``slices``, ``y``, ``counts`` are the pack's fields
+    (``PackedPartitions``; ``slices`` is None unless the compiled
+    ``pallas`` rung reads them), constants of the whole fit.  Returns
+    ``(carry, objs, actives, grad_norms, step_norms)`` where carry is
+    ``(beta, obj_prev, converged, iters, slot)`` and the
     ``(num_rounds,)`` objective/active/metric traces are the caller's
     only host readback.  The metric leaves (||revealed global
     gradient||, ||beta_new - beta|| per executed slot; 0.0 on skipped
@@ -107,7 +110,7 @@ def fit_scan_block(beta, obj_prev, converged, iters, key, round_base,
     )
     from .collective import declassify_sum
 
-    packed = PackedPartitions(X, X32, y, counts)
+    packed = PackedPartitions(X, X32, y, counts, slices)
     scale = agg.codec.scale
 
     def round_fn(carry):

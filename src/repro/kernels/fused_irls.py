@@ -29,7 +29,8 @@ changes the trajectory, not the answer; g/dev precision is what bounds the
 final beta and the deviance-based convergence test.  Mosaic has no 64-bit
 types, so a compiled launch sees f32 operands only: with a float64 payload
 ``ops.fused_irls`` takes the Gram from the kernel and the float64 g/dev
-from XLA ops beside it.
+from the pack's bf16 slices of X (``sliced_terms``), exact MXU dots
+combined in float64.
 
 TPU layout: labels (and fold ids) stream as lane-dense (1, block_n) rows,
 per-institution counts (and held-out folds) ride in SMEM as scalar
@@ -160,7 +161,9 @@ def fused_irls_pallas(
 
 
 def _sim_terms(beta, X, y, counts):
-    """The simulation's f64 z/g/dev terms and the f32 IRLS weights."""
+    """The simulation's f64 z/g/dev terms and the f32 IRLS weights: f64
+    contractions over X, which a TPU emulates (``sliced_terms`` is what
+    a compiled launch with a float64 payload and cut slices uses)."""
     with jax.named_scope("f64_terms"):
         n = X.shape[1]
         mask = (
@@ -222,15 +225,19 @@ def fused_irls_sim(beta, X, Xm, y, counts):
     Xm.
     """
     w32, g, dev = _sim_terms(beta, X, y, counts)
+    return _sim_gram(w32, Xm), g, dev
+
+
+def _sim_gram(w32, Xm):
+    """The simulation's f32 Gram, X^T diag(w) X per institution."""
     with jax.named_scope("gram"):
-        H = jnp.stack([
+        return jnp.stack([
             jnp.einsum(
                 "n,ni,nj->ij", w32[j], Xm[j], Xm[j],
                 preferred_element_type=jnp.float32,
             )
-            for j in range(X.shape[0])
+            for j in range(Xm.shape[0])
         ])
-    return H, g, dev
 
 
 # -- cross-validated variant: fold masks composed into the row masks ---------

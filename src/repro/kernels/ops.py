@@ -17,6 +17,7 @@ import numpy as np
 from .backend import interpret_kernels
 from .fused_irls import (
     _cv_sim_terms,
+    _sim_gram,
     _sim_terms,
     fused_irls_cv_pallas,
     fused_irls_cv_sim,
@@ -24,6 +25,7 @@ from .fused_irls import (
     fused_irls_sim,
     gram_hessian_pallas,
 )
+from .sliced_terms import sliced_terms
 from .shamir_poly import shamir_encode_share_pallas, shamir_poly_pallas
 from .shamir_reconstruct import (
     lagrange_weights_host,
@@ -88,7 +90,8 @@ def _block(n: int, block_n: int) -> int:
 
 
 def fused_irls(beta, X, y, counts=None, block_n: int = 512,
-               mxu_operand=None, simulate: bool | None = None):
+               mxu_operand=None, simulate: bool | None = None,
+               slices=None):
     """Batched masked IRLS summaries: (H (S,d,d) f32, g (S,d), dev (S,)).
 
     X: (S, N_max, d); y: (S, N_max); counts: (S,) true (ragged) row counts,
@@ -104,8 +107,12 @@ def fused_irls(beta, X, y, counts=None, block_n: int = 512,
     forces the real kernel through the interpreter (tests do, to pin
     kernel == simulation).  On a TPU the compiled kernel always runs; with
     a float64 payload it streams the f32 operand for the Gram, and the
-    gradient and deviance — which fix the Newton fixed point — keep the
-    simulation's float64 terms, as XLA ops beside the kernel.
+    gradient and deviance — which fix the Newton fixed point — stay
+    float64: computed from ``slices``, the pack's ``XSlices`` of X, as
+    exact bf16 dots on the MXU (``sliced_terms``), or, for a caller
+    without slices, as the simulation's float64 contractions, which XLA
+    emulates.  Given ``slices``, the simulation takes its terms from them
+    too.
     """
     s_dim, n, d = X.shape
     if counts is None:
@@ -115,7 +122,10 @@ def fused_irls(beta, X, y, counts=None, block_n: int = 512,
         with jax.named_scope("operands"):
             Xm = X.astype(jnp.float32) if mxu_operand is None \
                 else mxu_operand
-        return fused_irls_sim(beta, X, Xm, y, counts)
+        if slices is None:
+            return fused_irls_sim(beta, X, Xm, y, counts)
+        w32, g, dev = sliced_terms(beta, slices, y, counts)
+        return _sim_gram(w32, Xm), g, dev
     bn = _block(n, block_n)
     with jax.named_scope("operands"):
         Xp, Xmp, yp = _kernel_operands(X, mxu_operand, y, bn)
@@ -126,7 +136,10 @@ def fused_irls(beta, X, y, counts=None, block_n: int = 512,
         H = H[:, :d, :d]
     if Xp.dtype == X.dtype:
         return H, g[:, :d], dev
-    _, g, dev = _sim_terms(beta, X, y, counts)
+    if slices is None:
+        _, g, dev = _sim_terms(beta, X, y, counts)
+    else:
+        _, g, dev = sliced_terms(beta, slices, y, counts)
     return H, g, dev
 
 

@@ -7,7 +7,10 @@ Two halves:
   round (``repro_rounds_total``, ``repro_round_bytes``,
   ``repro_objective`` / ``repro_grad_norm`` / ``repro_step_norm`` from
   the in-graph metric leaves) and the audit folds the privacy ledger
-  into (``repro_declass_total{site=...}``).
+  into (``repro_declass_total{site=...}``);
+  ``repro_f64_slice_packs_total`` counts the packs whose float64 X was
+  cut into bf16 slices for the compiled summaries kernel (once per
+  study, not per fit).
   :func:`render_prometheus` / :func:`export_textfile` emit the standard
   Prometheus text exposition format, ready for the node-exporter
   textfile collector — the scrape surface ROADMAP direction 1's study

@@ -269,3 +269,165 @@ def test_protect_batched_roundtrip(rng_key):
     )
     with pytest.raises(ValueError, match="pallas"):
         SecureAggregator().protect_batched(rng_key, tree)
+
+
+# ------------------------------------------- float64 terms from bf16 slices
+D128 = 128  # the acceptance width; the slices hold exact up to it
+
+
+def _slice_case(case):
+    """(X (S, N, d) packed float64, y, counts) for one accuracy case."""
+    rng = np.random.default_rng(151)
+    sizes = (1, 130, 300, 77)
+    X = rng.standard_normal((len(sizes), max(sizes), D128))
+    if case == "intercept":
+        X[..., 0] = 1.0
+    elif case == "zero_row":
+        X[2, 40] = 0.0
+    elif case == "scaled_rows":
+        X[2, 5] *= 2.0 ** 20
+        X[2, 6] *= 2.0 ** -20
+        X[3, 70] *= 2.0 ** -20
+    elif case == "standardized":
+        X[..., 0] = 1.0
+        X[..., 1:] = 3.0 + 40.0 * X[..., 1:]
+        pooled = np.concatenate([X[s, :n] for s, n in enumerate(sizes)])
+        X[..., 1:] = ((X[..., 1:] - pooled[:, 1:].mean(0))
+                      / pooled[:, 1:].std(0))
+    elif case == "unit_scale":
+        X = rng.random(X.shape)
+    counts = np.asarray(sizes, np.int32)
+    y = (rng.random(X.shape[:2]) < 0.5).astype(np.float64)
+    for s, n in enumerate(sizes):  # padding rows hold zeros, as packed
+        X[s, n:] = 0.0
+        y[s, n:] = 0.0
+    return X, y, counts
+
+
+@pytest.mark.parametrize("case", ["ragged", "zero_row", "intercept",
+                                  "scaled_rows", "standardized",
+                                  "unit_scale"])
+def test_sliced_terms_hold_float64_bounds(case):
+    """z, g and dev from the bf16 slices of X against numpy float64, each
+    within a float64 dot's error bound: d 2**-53 sum|x||beta| per row for
+    z, N 2**-53 sum_n |x_nk||r_n| per column for g; for dev, the N-term
+    sum's bound plus what the error of z moves it by."""
+    from repro.kernels.sliced_terms import cut_slices, sliced_terms
+
+    X, y, counts = _slice_case(case)
+    s_dim, n, d = X.shape
+    beta = np.random.default_rng(7).uniform(-0.2, 0.2, d)
+    beta[0] = 0.3
+    _, g, dev = jax.jit(sliced_terms)(
+        jnp.asarray(beta), cut_slices(jnp.asarray(X)), jnp.asarray(y),
+        jnp.asarray(counts))
+    mask = (np.arange(n)[None, :] < counts[:, None]).astype(np.float64)
+    z = np.einsum("snd,d->sn", X, beta)
+    p = 1.0 / (1.0 + np.exp(-z))
+    r = (y - p) * mask
+    want_g = np.einsum("snd,sn->sd", X, r)
+    ll = (y * z - np.logaddexp(0.0, z)) * mask
+    want_dev = -2.0 * ll.sum(axis=1)
+    u = 2.0 ** -53
+    xb = np.einsum("snd,d->sn", np.abs(X), np.abs(beta))
+    bound_g = n * u * np.einsum("snd,sn->sd", np.abs(X), np.abs(r))
+    bound_dev = 2.0 * u * (n * np.abs(ll).sum(axis=1)
+                           + d * (xb * mask).sum(axis=1))
+    assert np.all(np.abs(np.asarray(g) - want_g) <= bound_g)
+    assert np.all(np.abs(np.asarray(dev) - want_dev) <= bound_dev)
+    # z itself, from the same levels the terms combine
+    from repro.kernels.sliced_terms import K, z_levels
+    digits, scale = cut_slices(jnp.asarray(X))
+    zl, bscale = z_levels(jnp.asarray(beta), digits)
+    zfix = sum(np.asarray(zl[lv], np.float64) * 2.0 ** (-8 * (lv + 2))
+               for lv in range(K))
+    got_z = (zfix * np.asarray(scale) * float(bscale))[:, :n]
+    assert np.all(np.abs(got_z - z) <= d * u * xb)
+
+
+def test_sliced_partials_are_exact_integers():
+    """Every digit is an integer of magnitude at most 128, X is its slices
+    to within 2**-55 of its row's largest entry, and every f32 partial of
+    the two dots is an integer under 2**24: the slice width holds."""
+    from repro.kernels.sliced_terms import (
+        K, KQ, SLAB, cut_slices, g_levels, z_levels)
+
+    X, y, counts = _slice_case("scaled_rows")
+    s_dim, n, d = X.shape
+    digits, scale = cut_slices(jnp.asarray(X))
+    a = np.asarray(digits, np.float64)
+    assert a.shape == (s_dim, -(-n // SLAB) * SLAB, K * d)
+    assert np.all(a == np.round(a)) and np.abs(a).max() <= 128
+    back = sum(a[..., i * d:(i + 1) * d] * 2.0 ** (-8 * (i + 1))
+               for i in range(K)) * np.asarray(scale)[..., None]
+    row_max = np.abs(X).max(axis=2, keepdims=True)
+    assert np.all(np.abs(back[:, :n] - X) <= 2.0 ** -55 * row_max)
+    beta = jnp.asarray(np.random.default_rng(3).uniform(-1.0, 1.0, d))
+    zl, _ = z_levels(beta, digits)
+    q = np.random.default_rng(4).uniform(-1.0, 1.0, a.shape[:2])
+    q = q * np.asarray(scale)
+    gl, _ = g_levels(jnp.asarray(q), digits)
+    assert zl.dtype == gl.dtype == jnp.float32
+    assert gl.shape == (s_dim, a.shape[1] // SLAB, KQ, d)
+    for part in (np.asarray(zl, np.float64), np.asarray(gl, np.float64)):
+        assert np.all(part == np.round(part))
+        assert np.abs(part).max() < 2.0 ** 24
+
+
+def test_secure_fit_through_sliced_terms_matches_float64_terms(study):
+    """A whole secure fit whose gradient and deviance come from the slices
+    runs the same rounds as the fit on ``_sim_terms`` (float64
+    contractions over X) and lands on the same beta within 1e-12."""
+    from repro.core import SecureCollective
+    from repro.core.scanfit import fit_scan_block
+    from repro.kernels.sliced_terms import cut_slices
+
+    packed = pack_partitions(_uneven_parts(study))
+    agg = SecureCollective(backend="pallas")
+    dim = packed.dim
+
+    def fit(slices):
+        carry, _, actives, _, _ = fit_scan_block(
+            jnp.zeros(dim, jnp.float64), jnp.asarray(np.inf),
+            jnp.asarray(False), jnp.zeros((), jnp.int32),
+            jax.random.PRNGKey(0), jnp.zeros((), jnp.int32),
+            packed.X, packed.X32, slices, packed.y, packed.counts,
+            jnp.asarray(1.0), agg=agg, protect="both", l1=0.0, tol=1e-10,
+            points=None, include_count=True, summaries_backend="pallas",
+            num_rounds=12, num_parts=packed.num_institutions,
+            max_rounds=12)
+        return np.asarray(carry[0]), int(carry[3]), bool(carry[2])
+
+    beta_sim, rounds_sim, conv_sim = fit(None)
+    beta_cut, rounds_cut, conv_cut = fit(cut_slices(packed.X))
+    assert conv_sim and conv_cut
+    assert rounds_cut == rounds_sim
+    np.testing.assert_allclose(beta_cut, beta_sim, rtol=0, atol=1e-12)
+
+
+def test_pack_cuts_slices_once_for_the_compiled_float64_rung(
+        study, monkeypatch):
+    """Only the compiled ``pallas`` rung with a float64 payload of at
+    most 128 features gets slices: cut on its first pack, kept with the
+    cached pack and counted once; the CPU's simulation, the other rungs,
+    an f32 payload and a wider X get none."""
+    import repro.kernels.backend as kernel_backend
+    from repro.obs import metrics
+
+    parts = _uneven_parts(study)
+    pack_cache_clear()
+    assert pack_partitions(parts, backend="pallas").slices is None
+    before = metrics.get("repro_f64_slice_packs_total") or 0.0
+    monkeypatch.setattr(kernel_backend, "interpret_kernels", lambda: False)
+    for rung in ("reference", "mixed"):
+        assert pack_partitions(parts, backend=rung).slices is None
+    assert pack_partitions(parts, dtype=jnp.float32,
+                           backend="pallas").slices is None
+    wide = [(jnp.ones((3, 129)), jnp.ones(3))]
+    assert pack_partitions(wide, backend="pallas").slices is None
+    first = pack_partitions(parts, backend="pallas")
+    assert first.slices is not None
+    assert pack_partitions(parts, backend="pallas") is first
+    assert pack_partitions(parts) is first  # the cache keeps the slices
+    assert metrics.get("repro_f64_slice_packs_total") == before + 1.0
+    pack_cache_clear()

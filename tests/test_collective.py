@@ -118,8 +118,8 @@ def test_multistudy_iteration_matches_independent(agg, studies, protect):
     for m, study in enumerate(studies):
         p = pack_partitions(study.parts)
         b_ref, obj_ref, g_ref, s_ref = _fused_secure_iteration(
-            betas0[m], jax.random.fold_in(key, m), p.X, p.X32, p.y,
-            p.counts, lams[m], agg, protect, 0.0,
+            betas0[m], jax.random.fold_in(key, m), p.X, p.X32, p.slices,
+            p.y, p.counts, lams[m], agg, protect, 0.0,
         )
         assert np.abs(np.asarray(betas[m]) - np.asarray(b_ref)).max() <= tol
         assert abs(float(objs[m]) - float(obj_ref)) <= tol * NUM_INST
@@ -145,8 +145,8 @@ def test_multistudy_rounds_track_independent_fits(agg, studies):
         beta = jnp.zeros((DIM,), jnp.float64)
         for r in range(num_rounds):
             beta, obj, _, _ = _fused_secure_iteration(
-                beta, jax.random.fold_in(key, r), p.X, p.X32, p.y,
-                p.counts, lams[m], agg, "both", 0.0,
+                beta, jax.random.fold_in(key, r), p.X, p.X32, p.slices,
+                p.y, p.counts, lams[m], agg, "both", 0.0,
             )
             # per-round quantization errors can compound through the
             # Newton updates; allow one tol per elapsed round
@@ -176,7 +176,7 @@ def test_ragged_studies_pad_with_silent_institutions(agg):
     for m, study in enumerate((wide, slim)):
         p = pack_partitions(study.parts)
         b_ref, *_ = _fused_secure_iteration(
-            betas0[m], jax.random.fold_in(key, m), p.X, p.X32, p.y,
-            p.counts, 0.5, agg, "both", 0.0,
+            betas0[m], jax.random.fold_in(key, m), p.X, p.X32, p.slices,
+            p.y, p.counts, 0.5, agg, "both", 0.0,
         )
         assert np.abs(np.asarray(betas[m]) - np.asarray(b_ref)).max() <= tol

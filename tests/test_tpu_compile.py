@@ -38,6 +38,7 @@ from repro.kernels.shamir_reconstruct import (
     lagrange_weights_host,
     shamir_reconstruct_pallas,
 )
+from repro.kernels.sliced_terms import K, SLAB, XSlices, sliced_terms
 
 from bench.scopes import SCOPES, hlo_index
 
@@ -194,8 +195,9 @@ KERNEL_NAMES = {
 
 
 def _on(chip, args):
-    return [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)
-            for a in args]
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        list(args))
 
 
 @pytest.mark.parametrize("name", sorted(KERNEL_NAMES))
@@ -205,17 +207,19 @@ def test_kernel_launches_carry_their_names(name, one_chip):
     assert re.findall(r'kernel_name = "([^"]*)"', text) == [name]
 
 
-def _fit_hlo(rung, key, sites, rows, dim, chip=None):
+def _fit_hlo(rung, key, sites, rows, dim, chip=None, slices=True):
     """The HLO of a two-round scan block of the ``rung`` summaries: as
-    XLA compiles it for this host's device, or lowered for ``chip``."""
+    XLA compiles it for this host's device, or lowered for ``chip``,
+    with the pack's slices of X as ``pack_partitions`` cuts them for the
+    rung (``slices=False`` drops them: the float64 contractions)."""
     parts = generate_synthetic(jax.random.PRNGKey(key), num_institutions=sites,
                                records_per_institution=rows, dim=dim).parts
-    packed = pack_partitions(list(parts))
+    packed = pack_partitions(list(parts), backend=rung)
     args = (jnp.zeros(dim, jnp.float64), jnp.asarray(np.inf),
             jnp.asarray(False), jnp.zeros((), jnp.int32),
             jax.random.PRNGKey(0), jnp.zeros((), jnp.int32),
-            packed.X, packed.X32, packed.y, packed.counts,
-            jnp.asarray(1.0))
+            packed.X, packed.X32, packed.slices if slices else None,
+            packed.y, packed.counts, jnp.asarray(1.0))
     lowered = fit_scan_block.lower(
         *(args if chip is None else _on(chip, args)),
         agg=SecureCollective(backend="pallas"), protect="both", l1=0.0,
@@ -261,3 +265,63 @@ def test_fit_program_carries_every_scope(rung, on_chip, request,
         assert {scope for entries in index.values()
                 for text, scope in entries
                 if "jit(fused_irls_pallas)" in text} == {"summaries/gram"}
+
+
+def _f64_contractions_over_x(hlo, shape):
+    """The dots and reduces of ``hlo`` with a float64 operand of the
+    payload's (S, N, d) ``shape``: each operand's type is read from the
+    instruction that defines it."""
+    x_type = "f64[" + ",".join(str(n) for n in shape) + "]"
+    defined = re.compile(r"\s*(?:ROOT )?(%[\w.\-]+) = (\S+?)(?:\{| )")
+    types = {m.group(1): m.group(2) for m in map(defined.match,
+                                                   hlo.splitlines()) if m}
+    found = []
+    for line in hlo.splitlines():
+        m = re.search(r" (dot|reduce)\(([^)]*)\)", line)
+        if m and any(types.get(name) == x_type
+                     for name in re.findall(r"%[\w.\-]+", m.group(2))):
+            found.append(line.strip())
+    return found
+
+
+def test_chip_fit_program_contracts_no_float64_x(one_chip, monkeypatch):
+    """The ``pallas`` fit program lowered for the chip takes its float64
+    terms from the slices: no dot or reduce reads X as float64, which a
+    TPU would emulate by re-splitting X every round.  Without the
+    slices the same program holds such a contraction, so the check sees
+    what it guards against."""
+    monkeypatch.setattr(kernel_backend, "interpret_kernels", lambda: False)
+    monkeypatch.setattr(kernel_ops, "interpret_kernels", lambda: False)
+    sites, rows, dim = 3, 41, 6
+    try:
+        sliced = _fit_hlo("pallas", 5, sites, rows, dim, one_chip)
+        plain = _fit_hlo("pallas", 5, sites, rows, dim, one_chip,
+                         slices=False)
+    finally:
+        jax.clear_caches()
+    assert "bf16" in sliced
+    assert _f64_contractions_over_x(sliced, (sites, rows, dim)) == []
+    assert _f64_contractions_over_x(plain, (sites, rows, dim)) != []
+
+
+def test_sliced_terms_keep_float64_out_of_their_dots(one_chip):
+    """At 64 sites of 3,760 rows the two dots of the sliced terms each
+    write their f32 levels and nothing more.  Without the barrier XLA
+    fused the float64 level sum, an f32 pair, into the z dot at this
+    shape, and on a v5e that program returned wrong z."""
+    sites, rows = 64, 3760
+    padded = -(-rows // SLAB) * SLAB
+    c = _compile(
+        lambda beta, digits, scale, y, counts: sliced_terms(
+            beta, XSlices(digits, scale), y, counts),
+        one_chip,
+        ((D,), jnp.float64),
+        ((sites, padded, K * D), jnp.bfloat16),
+        ((sites, padded), jnp.float64),
+        ((sites, rows), jnp.float64),
+        ((sites,), jnp.int32),
+    )
+    dots = [line for line in c.as_text().splitlines()
+            if "kind=kOutput" in line and "dot_general" in line]
+    assert len(dots) == 2
+    assert all(re.match(r"\s*%[\w.\-]+ = f32\[", line) for line in dots)
