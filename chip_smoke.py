@@ -1,7 +1,7 @@
 """Run the secure consortium fit on one TPU chip and check what comes out.
 
     python chip_smoke.py             # one chip: `paper`, `kernels`, `sliced`
-    python chip_smoke.py --chips 4   # four chips: phase `collective` only
+    python chip_smoke.py --chips 4   # four chips: `collective`, `pod_fit`
 
 Phases (each prints its name, cold wall seconds with compilation, and its
 checks on one line of JSON):
@@ -29,6 +29,13 @@ checks on one line of JSON):
   1e6 f32 parameters: the 1D pod mesh of 4 with the sharded reveal and
   the (2, 2) pod x share mesh, each against ``jax.lax.psum`` of the same
   per-pod trees, and the 2D reveal bitwise against the 1D wire.
+* ``pod_fit`` (``--chips 4``) — ``StudyCoordinator(pods=4)`` at 16 ragged
+  sites, d=128, the ``pallas`` rung with the slices of X: four sites a
+  chip, the pods' shares summed on the exact cross-chip wire.  Same
+  rounds and the same beta, to 1e-12 of its largest entry, as the fit
+  of the same sites on one chip; within the fixed-point contract of
+  ``centralized_fit``; the compiled pod program holds the kernels and
+  one all-reduce over the four chips, under ``pod_wire``.
 
 The last line of standard output is ``{"ok": true, "device": {...}}``
 with the device as JAX reports it.  The script exits non-zero, and
@@ -306,11 +313,105 @@ def phase_collective(params: int = 1_000_000, seed: int = 0) -> bool:
     })
 
 
+def phase_pod_fit(sizes=(9000, 1200, 7000, 300, 8000, 5000, 2500, 6000,
+                          4000, 7500, 650, 3000, 8500, 1800, 5500, 4200),
+                  dim: int = 128, seed: int = 0) -> bool:
+    """The fit with its sites sharded over four chips vs one chip."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core import (
+        Institution,
+        SecureAggregator,
+        StudyCoordinator,
+        centralized_fit,
+    )
+    from repro.core.batched_summaries import pack_partitions
+    from repro.core.scanfit import fit_scan_block, pod_partitioner
+    from repro.distributed.multihost import pod_mesh
+    from repro.obs import metrics
+
+    t0 = time.perf_counter()
+    rng = np.random.default_rng(seed)
+    beta_true = rng.uniform(-0.1, 0.1, dim)
+    parts = []
+    for n in sizes:
+        X = np.concatenate([np.ones((n, 1)),
+                            rng.standard_normal((n, dim - 1))], axis=1)
+        y = rng.random(n) < 1.0 / (1.0 + np.exp(-X @ beta_true))
+        parts.append((jnp.asarray(X), jnp.asarray(y, jnp.float64)))
+    agg = SecureAggregator(backend="pallas")
+
+    def fit(pods):
+        coord = StudyCoordinator(
+            [Institution(f"site{j}", X, y) for j, (X, y) in enumerate(parts)],
+            lam=1.0, protect="both", aggregator=agg, seed=seed, fused=True,
+            rounds="scan", summaries_backend="pallas", pods=pods,
+        )
+        t = time.perf_counter()
+        beta = np.asarray(coord.run())
+        return coord, beta, time.perf_counter() - t
+
+    one, b1, one_s = fit(1)
+    metrics.reset()
+    four, b4, four_s = fit(4)
+    wire = metrics.get("repro_pod_wire_bytes_total")
+    gold = centralized_fit(jnp.concatenate([X for X, _ in parts]),
+                           jnp.concatenate([y for _, y in parts]), lam=1.0)
+    gap = float(np.max(np.abs(b4 - b1)) / np.max(np.abs(b1)))
+    err = float(np.max(np.abs(b4 - np.asarray(gold.beta))))
+    bound = (len(sizes) + 1) / agg.codec.scale
+
+    # the pod program as the coordinator dispatches it
+    mesh = pod_mesh(4)
+    packed = pack_partitions(parts, backend="pallas", mesh=mesh)
+    with pod_partitioner(mesh):
+        text = fit_scan_block.lower(
+            jnp.zeros((dim,), jnp.float64), jnp.asarray(np.inf),
+            jnp.asarray(False), jnp.zeros((), jnp.int32), four.key,
+            jnp.zeros((), jnp.int32), packed.X, packed.X32, packed.slices,
+            packed.y, packed.counts, 1.0, agg=agg, protect="both", l1=0.0,
+            tol=float(four.tol),
+            points=tuple(c.index for c in four.live_centers()),
+            include_count=True, summaries_backend="pallas", num_rounds=50,
+            num_parts=len(sizes), max_rounds=50, mesh=mesh,
+        ).compile().as_text()
+    reduces = [line for line in text.splitlines()
+               if re.search(r" all-reduce(-start)?\(", line)]
+    return _emit("pod_fit", time.perf_counter() - t0, {
+        "sites": len(sizes),
+        "rows": sum(sizes),
+        "features": dim,
+        "rounds": [one.iteration, four.iteration],
+        "fit_seconds": [one_s, four_s],
+        "beta_gap_over_max": gap,
+        "max_abs_err_vs_gold": err,
+        "contract_bound": bound,
+        "wire_bytes": wire,
+        "sites_per_chip": [int(s.data.shape[0])
+                           for s in packed.X.addressable_shards],
+        "slices_ok": packed.slices is not None,
+        "converged_ok": one.converged and four.converged,
+        "rounds_ok": one.iteration == four.iteration,
+        "beta_ok": gap <= 1e-12,
+        "gold_err_ok": err <= bound,
+        "wire_bytes_ok": wire == four.iteration * agg.pod_wire_bytes(
+            dim, include_count=True),
+        "one_all_reduce_ok": len(reduces) == 1
+        and "/aggregate/pod_wire/" in reduces[0],
+        "compiled_kernels_ok": "tpu_custom_call" in text,
+    })
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
                     help="1: the paper, kernels and sliced phases on "
-                         "one chip; 4: the cross-chip collective phase only")
+                         "one chip; 4: the cross-chip collective and pod "
+                         "fit phases")
     args = ap.parse_args(argv)
 
     import jax
@@ -329,7 +430,7 @@ def main(argv=None) -> int:
     from repro.launch.compile_cache import enable_compile_cache
 
     enable_compile_cache()
-    phases = [phase_collective] if args.chips == 4 else [
+    phases = [phase_collective, phase_pod_fit] if args.chips == 4 else [
         phase_paper, phase_kernels, phase_sliced]
     ok = True
     for phase in phases:

@@ -3,7 +3,7 @@
 Each :class:`DriverSpec` names one secure driver round graph, builds its
 closed jaxpr on tiny synthetic shapes (``jax.make_jaxpr`` — no kernel
 ever executes, Pallas included), and labels every flat input with its
-taint.  The five ISSUE-mandated drivers map to eight specs:
+taint.  The secure drivers map to these specs:
 
 * ``secure_fit_fused``   — ``SecureFitDriver.step``'s fused round
   (``newton._fused_secure_iteration``).
@@ -11,6 +11,10 @@ taint.  The five ISSUE-mandated drivers map to eight specs:
   fused trim (``include_count=True``, the coordinator wire tree).
 * ``secure_fit_scan``    — ``rounds="scan"``'s whole-block graph
   (``scanfit.fit_scan_block``), shared by driver and coordinator.
+* ``pod_fit_scan``       — the same block with the institutions sharded
+  over a pod mesh of 4 (``StudyCoordinator(pods=4)``), traced through
+  ``shard_map`` over an AbstractMesh: the pods' share sums cross the
+  wire, the reveal is replicated.
 * ``selection_scan``     — the CV sweep's multi-config scan body
   (``selection.path._cv_sweep_block``).
 * ``secure_psum_replicated`` / ``secure_psum_sharded`` /
@@ -184,6 +188,52 @@ def _scan_spec(name: str, protect: str, include_count: bool):
         jax.block_until_ready(out)
 
     return DriverSpec(name=name, build=build, runner=runner,
+                      threshold=_aggregator().scheme.threshold)
+
+
+def _pod_scan_spec(name: str, num_pods: int = 4):
+    """The scan fit with its institutions sharded over a pod mesh
+    (``fit_scan_block(mesh=)``): two toy institutions a pod, traced over
+    an AbstractMesh, executed over the real one by the runner."""
+    def args(packed):
+        return (jnp.zeros((packed.dim,), jnp.float64), jnp.asarray(np.inf),
+                jnp.asarray(False), jnp.zeros((), jnp.int32),
+                jax.random.PRNGKey(0), jnp.zeros((), jnp.int32),
+                packed.X, packed.X32, packed.slices, packed.y,
+                packed.counts)
+
+    def fit(mesh, *a):
+        from ..core.scanfit import fit_scan_block
+
+        return fit_scan_block(
+            *a, 1.0, agg=_aggregator(), protect="both", l1=0.0, tol=1e-10,
+            points=None, include_count=True, summaries_backend="pallas",
+            num_rounds=3, num_parts=2 * num_pods, max_rounds=3, mesh=mesh,
+        )
+
+    def build():
+        from jax.sharding import AbstractMesh
+
+        from ..distributed.sharding import POD_AXIS
+
+        mesh = AbstractMesh((num_pods,), (POD_AXIS,))
+        closed = jax.make_jaxpr(lambda *a: fit(mesh, *a))(
+            *args(_packed(2 * num_pods)))
+        # X, X32, the slices (digits, scale), y and counts of every pod
+        taints = [PUBLIC] * 6 + [SECRET] * 6
+        return closed, taints
+
+    def runner():
+        from ..core.batched_summaries import _with_slices, pack_partitions
+        from ..distributed.multihost import pod_mesh
+
+        mesh = pod_mesh(num_pods)
+        packed = _with_slices(
+            pack_partitions(toy_parts(2 * num_pods), mesh=mesh), None, mesh)
+        jax.block_until_ready(fit(mesh, *args(packed)))
+
+    return DriverSpec(name=name, build=build, runner=runner,
+                      min_devices=num_pods,
                       threshold=_aggregator().scheme.threshold)
 
 
@@ -381,6 +431,7 @@ def all_driver_specs() -> list:
         _scan_spec("secure_fit_scan[protect=both]", "both", False),
         _scan_spec("secure_fit_scan[protect=gradient]", "gradient",
                    False),
+        _pod_scan_spec("pod_fit_scan[pods=4]"),
         _selection_spec("selection_scan[protect=both]", "both"),
         _selection_spec("selection_scan[protect=gradient]", "gradient"),
         _psum_spec("secure_psum[replicated]", "replicated", "tree"),

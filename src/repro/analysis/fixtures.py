@@ -17,6 +17,9 @@ unless every fixture produces an error finding.
 * ``callback_leak``           — ships a per-institution deviance into a
   ``jax.debug.callback`` (a print/telemetry hook): host code outside
   the protocol would observe institution-local data.
+* ``pod_plain_wire``          — on a pod mesh, sums each pod's plaintext
+  summaries across the pods with ``psum``: institution data crosses the
+  wire between devices unprotected.
 """
 from __future__ import annotations
 
@@ -113,6 +116,38 @@ def _callback_leak_build():
     return closed, [PUBLIC, PUBLIC] + [SECRET] * 6
 
 
+def _pod_plain_wire_build():
+    from jax.sharding import AbstractMesh, PartitionSpec as P
+
+    from ..core.batched_summaries import batched_local_summaries
+    from ..core.batched_summaries import PackedPartitions
+    from ..core.newton import newton_step
+    from ..distributed.sharding import POD_AXIS
+
+    packed = _packed(num_parts=4)
+    beta = jnp.zeros((packed.dim,), jnp.float64)
+
+    def pod(beta, X, X32, y, counts):
+        sm = batched_local_summaries(
+            beta, PackedPartitions(X, X32, y, counts), backend="pallas",
+        )
+        # LEAK: the pods' plaintext sums cross the wire, no protect
+        H = jax.lax.psum(jnp.sum(sm.hessian, axis=0), POD_AXIS)
+        g = jax.lax.psum(jnp.sum(sm.gradient, axis=0), POD_AXIS)
+        return newton_step(beta, H, g, 1.0)
+
+    sites = P(POD_AXIS)
+    fn = jax.shard_map(
+        pod, mesh=AbstractMesh((4,), (POD_AXIS,)),
+        in_specs=(P(), sites, sites, sites, sites), out_specs=P(),
+        check_vma=False,
+    )
+    closed = jax.make_jaxpr(fn)(
+        beta, packed.X, packed.X32, packed.y, packed.counts
+    )
+    return closed, [PUBLIC, SECRET, SECRET, SECRET, SECRET]
+
+
 def leak_fixture_specs() -> list:
     """The negative controls, as DriverSpecs the same runner consumes."""
     t = _aggregator().scheme.threshold
@@ -121,4 +156,5 @@ def leak_fixture_specs() -> list:
         DriverSpec("LEAKY:reveal_institution_slice", _reveal_slice_build,
                    t),
         DriverSpec("LEAKY:callback_leak", _callback_leak_build, t),
+        DriverSpec("LEAKY:pod_plain_wire", _pod_plain_wire_build, t),
     ]

@@ -56,6 +56,9 @@ class AnalysisReport:
     # sanctioned declassification sites the taint pass certified: the
     # audit trail of every place SECRET data legally became PUBLIC
     declassifications: list = dataclasses.field(default_factory=list)
+    # every sum collective over a mesh axis, with its operand's taint:
+    # "<eqn path>: <TAINT>" (what crosses the wire between devices)
+    wire_operands: list = dataclasses.field(default_factory=list)
 
     def add(self, finding: Finding):
         if finding not in self.findings:
@@ -90,4 +93,5 @@ class AnalysisReport:
             "ok": self.ok,
             "findings": [dataclasses.asdict(f) for f in self.findings],
             "declassifications": list(self.declassifications),
+            "wire_operands": list(self.wire_operands),
         }

@@ -26,6 +26,9 @@ Transitions the verifier recognizes (everything else joins its inputs):
   taint, so slicing tricks cannot launder a single contribution.
 * ``psum`` / ``reduce_scatter`` over a mesh axis of size >= 2 on a
   PROTECTED operand: the SPMD form of Algorithm 2 -> PROTECTED_AGG.
+  A SECRET operand is a violation: institution data would cross the
+  wire between devices in the clear.  Every such collective's operand
+  taint is listed in the report's ``wire_operands``.
 * ``jit(_reveal_flat)`` — the fused Lagrange+CRT reconstruction: the
   ONLY declassification of share material.  Requires (a) input taint
   exactly PROTECTED_AGG (SECRET means protect was skipped; PROTECTED
@@ -95,6 +98,12 @@ class _Ctx:
     def add(self, severity, where, message):
         if not self.mute:
             self.report.add(Finding("taint", severity, where, message))
+
+    def wired(self, where, taint):
+        if not self.mute:
+            entry = f"{where}: {TAINT_NAMES[taint]}"
+            if entry not in self.report.wire_operands:
+                self.report.wire_operands.append(entry)
 
     def declassified(self, where, what):
         if not self.mute:
@@ -388,7 +397,15 @@ def _eval_eqn(eqn, ins, ctx, where):
 
     if prim in _SUM_COLLECTIVES:
         taint = _join(ins)
-        if taint == PROTECTED:
+        ctx.wired(where, taint)
+        if taint == SECRET:
+            ctx.add(
+                "error", where,
+                f"SECRET institution-local data summed over a mesh axis "
+                f"by '{prim}': only share buffers may cross the wire "
+                "between devices",
+            )
+        elif taint == PROTECTED:
             size = _collective_axis_size(params, ctx)
             if size is None:
                 ctx.add(

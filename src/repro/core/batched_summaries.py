@@ -146,7 +146,7 @@ def pack_cache_evict(parts, dtype=None):
     """
     ids = {id(b) for part in parts for b in part}
     for key in list(_PACK_CACHE):
-        part_ids, dt_name = key
+        part_ids, dt_name = key[:2]
         if dtype is not None and dt_name != jnp.dtype(dtype).name:
             continue
         if any(i in ids or j in ids for i, j in part_ids):
@@ -167,9 +167,22 @@ def _wants_slices(packed: PackedPartitions, backend: str | None) -> bool:
             and not interpret_kernels())
 
 
-def _with_slices(packed: PackedPartitions, key) -> PackedPartitions:
-    """``packed`` with its slices cut, in its cache entry too."""
-    out = dataclasses.replace(packed, slices=cut_slices(packed.X))
+def _with_slices(packed: PackedPartitions, key,
+                 mesh=None) -> PackedPartitions:
+    """``packed`` with its slices cut, in its cache entry too.  On a pod
+    ``mesh`` each device cuts the slices of its own institutions."""
+    if mesh is None:
+        slices = cut_slices(packed.X)
+    else:
+        from jax.sharding import PartitionSpec as P
+
+        from ..distributed.sharding import POD_AXIS
+
+        sites = P(POD_AXIS)
+        slices = jax.jit(jax.shard_map(
+            cut_slices, mesh=mesh, in_specs=sites, out_specs=sites,
+        ))(packed.X)
+    out = dataclasses.replace(packed, slices=slices)
     _metrics.inc("repro_f64_slice_packs_total")
     entry = _PACK_CACHE.get(key)
     if entry is not None and entry[1] is packed:
@@ -177,10 +190,52 @@ def _with_slices(packed: PackedPartitions, key) -> PackedPartitions:
     return out
 
 
+def _stack_on_pods(parts, dtype, mesh) -> PackedPartitions:
+    """The pack with its institution axis sharded over ``mesh``'s pods.
+
+    Pod p holds institutions ``p * k`` to ``p * k + k - 1`` of ``parts``
+    in order, ``k = ceil(S / pods)``, each padded to the largest site as
+    on one device; the last pods are filled up with empty sites (count
+    0), whose summaries, and so whose shares, are zeros.  Each pod's
+    rows are stacked on its own device, from where its parts are or are
+    copied to, and the pods' stacks make one array.
+    """
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ..distributed.sharding import POD_AXIS
+
+    devices = list(mesh.devices.flat)
+    per = -(-len(parts) // len(devices))
+    d = parts[0][0].shape[1]
+    n_max = max(Xj.shape[0] for Xj, _ in parts)
+    empty = (np.zeros((0, d), dtype), np.zeros((0,), np.float64))
+    counts = np.zeros((len(devices) * per,), np.int32)
+    counts[:len(parts)] = [Xj.shape[0] for Xj, _ in parts]
+    Xs, ys = [], []
+    for p, dev in enumerate(devices):
+        mine = list(parts[p * per:(p + 1) * per])
+        mine = jax.device_put(mine + [empty] * (per - len(mine)), dev)
+        Xp, yp = _stack_pad([Xj for Xj, _ in mine], [yj for _, yj in mine],
+                            n_max, jnp.dtype(dtype).name)
+        Xs.append(Xp)
+        ys.append(yp)
+    sharding = NamedSharding(mesh, P(POD_AXIS))
+
+    def one_array(blocks):
+        return jax.make_array_from_single_device_arrays(
+            (len(devices) * per,) + blocks[0].shape[1:], sharding, blocks)
+
+    X = one_array(Xs)
+    X32 = X if X.dtype == jnp.float32 else _to_f32(X)
+    return PackedPartitions(X, X32, one_array(ys),
+                            jax.device_put(counts, sharding))
+
+
 def pack_partitions(
     parts: Sequence[tuple[jnp.ndarray, jnp.ndarray]],
     dtype=jnp.float64,
     backend: str | None = None,
+    mesh=None,
 ) -> PackedPartitions:
     """Stack S ragged (X_j, y_j) partitions into one masked batch.
 
@@ -196,7 +251,8 @@ def pack_partitions(
     ``backend`` names the summaries rung that will read the pack: for
     the compiled ``pallas`` rung with a float64 payload the pack also
     carries the slices of X, cut on first use and kept with the cached
-    pack.
+    pack.  ``mesh``: a pod mesh to shard the institution axis over
+    (``_stack_on_pods``); the pack is cached under the mesh too.
     """
     if not parts:
         raise ValueError("need at least one partition")
@@ -210,22 +266,27 @@ def pack_partitions(
         for Xj, yj in parts
     )
     key = _pack_cache_key(parts, dtype)
+    if mesh is not None:
+        key += (mesh,)
     if cacheable:
         hit = _PACK_CACHE.get(key)
         if hit is not None:
             _PACK_CACHE.move_to_end(key)
             if _wants_slices(hit[1], backend):
-                return _with_slices(hit[1], key)
+                return _with_slices(hit[1], key, mesh)
             return hit[1]
-    counts = np.asarray([Xj.shape[0] for Xj in (p[0] for p in parts)],
-                        np.int32)
-    n_max = int(counts.max())
-    Xs, ys = _stack_pad(
-        [p[0] for p in parts], [p[1] for p in parts], n_max,
-        jnp.dtype(dtype).name,
-    )
-    X32 = Xs if Xs.dtype == jnp.float32 else _to_f32(Xs)
-    packed = PackedPartitions(Xs, X32, ys, jnp.asarray(counts))
+    if mesh is not None:
+        packed = _stack_on_pods(parts, dtype, mesh)
+    else:
+        counts = np.asarray([Xj.shape[0] for Xj in (p[0] for p in parts)],
+                            np.int32)
+        n_max = int(counts.max())
+        Xs, ys = _stack_pad(
+            [p[0] for p in parts], [p[1] for p in parts], n_max,
+            jnp.dtype(dtype).name,
+        )
+        X32 = Xs if Xs.dtype == jnp.float32 else _to_f32(Xs)
+        packed = PackedPartitions(Xs, X32, ys, jnp.asarray(counts))
     if cacheable:
         # evict-on-collect: if ANY part buffer dies, the ids in `key` may
         # be recycled, so the entry must go before a lookup can alias it
@@ -235,7 +296,7 @@ def pack_partitions(
         while len(_PACK_CACHE) > _PACK_CACHE_SIZE:
             _PACK_CACHE.popitem(last=False)
     if _wants_slices(packed, backend):
-        return _with_slices(packed, key)
+        return _with_slices(packed, key, mesh)
     return packed
 
 

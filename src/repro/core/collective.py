@@ -598,17 +598,29 @@ class SecureCollective:
             return _fold_sum_streaming(tuple(protected), field,
                                        residue_axis=1)
 
-    def aggregate_batched(self, protected: FlatProtected) -> FlatProtected:
+    def aggregate_batched(self, protected: FlatProtected,
+                          axis_name: str | None = None) -> FlatProtected:
         """Reduce the institution axis of a ``protect_batched`` output.
 
         One exact uint64 reduction over axis 2 of the (w, R, S, rows, 128)
         share buffer — Algorithm 2 for all S submissions in a single
-        dispatch, with no per-submission stacking step.
+        dispatch, with no per-submission stacking step.  With
+        ``axis_name`` (inside ``shard_map`` over a pod mesh, each device
+        holding S of the institutions) the local sum is then summed
+        across the mesh axis on the exact wire (:meth:`allreduce`, scope
+        ``aggregate/pod_wire``), so every device holds the sum of all
+        S x pods submissions; the headroom check counts all of them.
         """
         with jax.named_scope("aggregate"):
             field = self.scheme.field
-            check_aggregation_headroom(protected.buf.shape[2], field)
+            addends = protected.buf.shape[2]
+            if axis_name is not None:
+                addends *= jax.lax.axis_size(axis_name)
+            check_aggregation_headroom(addends, field)
             buf = fsum(protected.buf, field, axis=2, residue_axis=1)
+            if axis_name is not None:
+                with jax.named_scope("pod_wire"):
+                    buf = self.allreduce(buf, axis_name)
             return FlatProtected(buf, protected.layout)
 
     def allreduce(self, shares, axis_name: str, residue_axis: int = 1,
@@ -649,7 +661,8 @@ class SecureCollective:
 
     def secure_round_batched(self, key: jax.Array, tree,
                              points: Sequence[int] | None = None,
-                             dtype=jnp.float64):
+                             dtype=jnp.float64,
+                             axis_name: str | None = None):
         """One whole Algorithm-1+2 round over S-leading summaries.
 
         protect_batched (ONE encode+share launch) -> aggregate_batched
@@ -662,10 +675,19 @@ class SecureCollective:
         reducing over a short share axis.  Fully traceable — this is the
         round helper both the fused ``secure_fit`` iteration and the
         fused ``StudyCoordinator.step`` run inside one jitted graph.
+
+        ``axis_name``: the institutions are sharded over that mesh axis
+        (the pod fit, inside ``shard_map``).  Each device folds its axis
+        index into the key (no two pods share sharing polynomials), sums
+        its own institutions' shares and then the pods' sums on the
+        exact wire; the reveal is replicated, and reveals the same field
+        elements as one device holding every institution.
         """
         points = self._validated_points(points)
+        if axis_name is not None:
+            key = self.round_key(key, jax.lax.axis_index(axis_name))
         prot = self.protect_batched(key, tree)
-        aggd = self.aggregate_batched(prot)
+        aggd = self.aggregate_batched(prot, axis_name)
         sel = jnp.asarray([p - 1 for p in points])
         return self.reveal(
             FlatProtected(aggd.buf[sel], aggd.layout), points=points,
@@ -809,10 +831,21 @@ class SecureCollective:
         return max_abs * num_institutions < self.codec.capacity()
 
     # byte telemetry ----------------------------------------------------------
+    def pod_wire_bytes(self, d: int, include_count: bool = False) -> int:
+        """Bytes one device puts on the cross-chip wire per pod-fit round.
+
+        The operand of :meth:`aggregate_batched`'s all-reduce over the pod
+        axis: the aggregated share buffer of the fully protected tree (all
+        w slices, one institution's worth), each field element as two
+        16-bit limbs in uint32 (:func:`_exact_psum`), twice its bytes.
+        """
+        return 2 * self.round_bytes(d, 1, "both", include_count)
+
     def round_bytes(self, d: int, num_parts: int, protect: str,
                     include_count: bool = False,
                     num_live_centers: int | None = None,
-                    num_configs: int = 1, extra_scalars: int = 0) -> int:
+                    num_configs: int = 1, extra_scalars: int = 0,
+                    pods: int = 1) -> int:
         """Per-round wire bytes from static shapes/dtypes alone.
 
         The ONE size model behind every driver's telemetry
@@ -833,7 +866,9 @@ class SecureCollective:
         every config ships its own summary tree per round — and
         ``extra_scalars`` accounts for the selection path's additional
         held-out-metric leaves (val deviance / correct / count) riding
-        in each config's protected buffer.
+        in each config's protected buffer.  ``pods > 1`` (institutions
+        sharded over a pod mesh) adds what crosses chips: every pod's
+        :meth:`pod_wire_bytes`.
         """
         extra = (2 if include_count else 1) + extra_scalars
         n_protected = 0
@@ -861,7 +896,10 @@ class SecureCollective:
             n_plain += d * d
         if protect == "none":
             n_plain += extra
-        return num_configs * num_parts * (share_bytes + n_plain * 8)
+        total = num_configs * num_parts * (share_bytes + n_plain * 8)
+        if pods > 1:
+            total += pods * self.pod_wire_bytes(d, include_count)
+        return total
 
     # in-SPMD wires -----------------------------------------------------------
     def psum(self, tree, axis_name: str, key: jax.Array,

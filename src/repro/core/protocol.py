@@ -141,7 +141,8 @@ class ComputationCenter:
 # every aggregator config a long-lived process ever constructs
 @functools.lru_cache(maxsize=64)
 def _round_bytes(d: int, cohort_size: int, protect: str,
-                 agg: SecureCollective, num_live_centers: int) -> int:
+                 agg: SecureCollective, num_live_centers: int,
+                 pods: int = 1) -> int:
     """Per-round wire bytes from static shapes/dtypes alone.
 
     Every round moves the same messages for a given (cohort size, protect
@@ -152,11 +153,12 @@ def _round_bytes(d: int, cohort_size: int, protect: str,
     leaf, and each online center receives a 1/w slice of the share
     buffer (uint32 flat tiles on pallas, uint64 leaf tensors on
     reference).  ``tests/test_protocol.py`` pins this formula against a
-    per-leaf walk of the actual messages.
+    per-leaf walk of the actual messages.  On a pod mesh the pods' share
+    sums cross chips as well (``pods``).
     """
     return agg.round_bytes(
         d, cohort_size, protect, include_count=True,
-        num_live_centers=num_live_centers,
+        num_live_centers=num_live_centers, pods=pods,
     )
 
 
@@ -166,8 +168,17 @@ class StudyCoordinator:
     With tracing on (``repro.obs.trace``) a scan fit's host work shows as
     spans named ``StudyCoordinator.<phase>``: ``__init__``, and inside
     ``step_block`` ``cohort`` (responders, live centers, stragglers),
-    ``pack``, ``dispatch``, ``readback`` and ``reports``; ``result`` is
-    the final beta's transfer in ``run``.
+    ``pack`` (``pod_pack`` on a pod mesh), ``dispatch``, ``readback``
+    and ``reports``; ``result`` is the final beta's transfer in ``run``.
+
+    ``pods > 1`` runs the scan fit with the cohort's institutions
+    sharded over a mesh of that many devices
+    (``distributed.multihost.pod_mesh``): each device computes and
+    protects its own institutions' summaries and sums their shares, the
+    pods' sums meet on the exact cross-chip wire, and reveal and solve
+    run on every device alike (``scanfit.fit_scan_block(mesh=)``).
+    Each fit's readback adds the bytes every device put on that wire to
+    the counter ``repro_pod_wire_bytes_total``.
     """
 
     @_traced("coordinator")
@@ -186,6 +197,7 @@ class StudyCoordinator:
         summaries_backend: str | None = None,
         rounds: str = "step",
         rounds_per_sync: int | None = None,
+        pods: int = 1,
     ):
         self.institutions = list(institutions)
         self.lam = lam
@@ -212,6 +224,20 @@ class StudyCoordinator:
                              "one scan block per run)")
         self.rounds = rounds
         self.rounds_per_sync = rounds_per_sync
+        if pods < 1:
+            raise ValueError("pods must be >= 1")
+        if pods > 1 and (rounds != "scan" or protect != "both"):
+            raise ValueError(
+                "pods > 1 runs the scan fit (rounds='scan') with every "
+                "summary protected (protect='both'): only shares cross "
+                "chips, on the exact field wire"
+            )
+        self.pods = pods
+        self._mesh = None
+        if pods > 1:
+            from ..distributed.multihost import pod_mesh
+
+            self._mesh = pod_mesh(pods)
         # Precision ladder for the fused round's batched summaries.
         # "reference" (default) — f64, per-ROUND beta parity with the loop
         # oracle at the f64 rounding floor (well inside fixed-point
@@ -539,7 +565,7 @@ class StudyCoordinator:
             num_live = sum(1 for c in self.centers if c.online)
             d = cohort[0].X.shape[1]
             nbytes = _round_bytes(d, len(cohort), self.protect, self.agg,
-                                  num_live)
+                                  num_live, self.pods)
             if num_rounds is None:
                 # 50 is run()'s default max_iter — the whole-study budget
                 num_rounds = self.rounds_per_sync or max(
@@ -549,9 +575,11 @@ class StudyCoordinator:
                 points = tuple(c.index for c in self.live_centers())
             else:
                 points = None
-        with _span("coordinator", "StudyCoordinator.pack"):
+        pack_span = "pack" if self._mesh is None else "pod_pack"
+        with _span("coordinator", f"StudyCoordinator.{pack_span}"):
             packed = pack_partitions([(i.X, i.y) for i in cohort],
-                                     backend=self.summaries_backend)
+                                     backend=self.summaries_backend,
+                                     mesh=self._mesh)
         with _span("coordinator", "StudyCoordinator.dispatch"):
             carry, objs, actives, gnorms, snorms = fit_scan_block(
                 self.beta,
@@ -565,7 +593,7 @@ class StudyCoordinator:
                 tol=float(self.tol), points=points, include_count=True,
                 summaries_backend=self.summaries_backend,
                 num_rounds=num_rounds, num_parts=len(cohort),
-                max_rounds=num_rounds,
+                max_rounds=num_rounds, mesh=self._mesh,
             )
         with _span("coordinator", "StudyCoordinator.readback"):
             # host-sync: the block's ONE readback — trace + metric leaves
@@ -598,6 +626,9 @@ class StudyCoordinator:
                     objective=float(objs[r]),
                     grad_norm=float(gnorms[r]), step_norm=float(snorms[r]),
                 )
+            if self.pods > 1:
+                _metrics.inc("repro_pod_wire_bytes_total", len(new_reports)
+                             * self.agg.pod_wire_bytes(d, include_count=True))
         self.beta = carry[0]
         self._obj_prev = float(obj_prev_h)
         self.converged = bool(conv_h)

@@ -21,6 +21,9 @@ round-graph shape so every secure driver can use it:
   graph behind ``SecureFitDriver(rounds="scan")`` and
   ``StudyCoordinator(rounds="scan")``; ``selection/path.py`` runs its
   multi-config variant through the same :func:`scan_rounds` skeleton.
+  Given a pod mesh (``StudyCoordinator(pods=...)``) the same round runs
+  each device's institutions under ``shard_map`` and sums the pods'
+  share buffers on the exact cross-chip wire.
 
 rng-scheme note: the per-round drivers split a host key every round
 (``key, sub = jax.random.split``) while the scan folds slots from one
@@ -32,15 +35,16 @@ against the per-round oracles at fixed-point-quantization tolerance.
 """
 from __future__ import annotations
 
-import functools
+import contextlib
 
 import jax
 import jax.numpy as jnp
 
+from ..distributed.sharding import POD_AXIS
 from .batched_summaries import PackedPartitions, batched_local_summaries
 from .collective import SecureCollective
 
-__all__ = ["scan_rounds", "fit_scan_block"]
+__all__ = ["scan_rounds", "fit_scan_block", "pod_partitioner"]
 
 
 def scan_rounds(round_fn, skip_fn, settled_fn, carry0, num_rounds: int):
@@ -59,19 +63,25 @@ def scan_rounds(round_fn, skip_fn, settled_fn, carry0, num_rounds: int):
     return jax.lax.scan(body, carry0, None, length=num_rounds)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("agg", "protect", "l1", "tol", "points",
-                     "include_count", "summaries_backend", "num_rounds",
-                     "num_parts", "max_rounds"),
-)
-def fit_scan_block(beta, obj_prev, converged, iters, key, round_base,
-                   X, X32, slices, y, counts, lam,
-                   agg: SecureCollective, protect: str, l1: float,
-                   tol: float,
-                   points: tuple[int, ...] | None,
-                   include_count: bool, summaries_backend: str,
-                   num_rounds: int, num_parts: int, max_rounds: int):
+def pod_partitioner(mesh):
+    """The partitioner the fit program is lowered and compiled with.
+
+    One device: the default.  A pod mesh: GSPMD, because XLA:TPU refuses
+    a float64 Cholesky in a program that Shardy partitions over several
+    devices ("A tuple parameter that is being flattened shouldn't have
+    frontend attributes"), and the pod fit's Newton solve is the one
+    the one-device fit runs.  :func:`fit_scan_block` enters it for its
+    call; a caller that lowers and compiles the pod program itself
+    enters it around both.
+    """
+    if mesh is None:
+        return contextlib.nullcontext()
+    from jax._src import config
+
+    return config.use_shardy_partitioner(False)
+
+
+def fit_scan_block(*args, mesh=None, **kwargs):
     """``num_rounds`` secure Newton rounds as ONE jitted ``lax.scan``.
 
     The single-λ mirror of the selection sweep's ``_cv_sweep_block``:
@@ -89,6 +99,17 @@ def fit_scan_block(beta, obj_prev, converged, iters, key, round_base,
     aggregates, so the graph is identical whether or not observability
     consumes them.
 
+    ``mesh``: a pod mesh (``distributed.multihost.pod_mesh``) over whose
+    ``POD_AXIS`` the pack's institution axis is sharded
+    (``pack_partitions(mesh=)``).  Each round then takes every device's
+    own institutions through summaries, protect and the local share sum
+    under ``shard_map``, sums the pods' share buffers with one exact
+    all-reduce and reveals on every device alike
+    (``SecureCollective.secure_round_batched(axis_name=)``); the Newton
+    solve and the stopping rule run on the replicated aggregates, so
+    every device holds the same beta and ``converged``.  ``None`` (one
+    device) traces no collective.
+
     Semantics pinned to the per-round drivers:
 
     * a round that trips ``should_stop`` keeps the beta its objective was
@@ -101,7 +122,24 @@ def fit_scan_block(beta, obj_prev, converged, iters, key, round_base,
       matching ``driver.iteration``; the slot counter advances every
       slot, executed or skipped, so the rng fold of round r is always
       ``fold_in(key, round_base + r)``.
+
+    Arguments: ``(beta, obj_prev, converged, iters, key, round_base, X,
+    X32, slices, y, counts, lam)``, then the static ``agg, protect, l1,
+    tol, points, include_count, summaries_backend, num_rounds,
+    num_parts, max_rounds`` by name.
     """
+    with pod_partitioner(mesh):
+        return _fit_scan_block(*args, mesh=mesh, **kwargs)
+
+
+def _fit_scan_block(beta, obj_prev, converged, iters, key, round_base,
+                    X, X32, slices, y, counts, lam,
+                    agg: SecureCollective, protect: str, l1: float,
+                    tol: float,
+                    points: tuple[int, ...] | None,
+                    include_count: bool, summaries_backend: str,
+                    num_rounds: int, num_parts: int, max_rounds: int,
+                    mesh=None):
     from .newton import (
         _protected_tree,
         prox_newton_step,
@@ -110,21 +148,24 @@ def fit_scan_block(beta, obj_prev, converged, iters, key, round_base,
     )
     from .collective import declassify_sum
 
-    packed = PackedPartitions(X, X32, y, counts, slices)
     scale = agg.codec.scale
+    axis_name = None if mesh is None else POD_AXIS
+    if mesh is not None and protect != "both":
+        raise ValueError("a pod mesh carries shares only: protect='both'")
 
-    def round_fn(carry):
-        beta, obj_prev, converged, iters, slot = carry
-        kr = agg.round_key(key, slot)
+    def aggregates(beta, kr, X, X32, slices, y, counts):
+        """Summaries, protect and share sum of the institutions at hand,
+        then the revealed (or annotated plaintext) global sums."""
         sm = batched_local_summaries(
-            beta, packed, backend=summaries_backend,
+            beta, PackedPartitions(X, X32, y, counts, slices),
+            backend=summaries_backend,
         )
         tree = _protected_tree(protect, sm.hessian, sm.gradient,
                                sm.deviance)
         if tree and include_count:
             tree["count"] = counts.astype(jnp.float64)
-        revealed = agg.secure_round_batched(kr, tree, points=points) \
-            if tree else {}
+        revealed = agg.secure_round_batched(
+            kr, tree, points=points, axis_name=axis_name) if tree else {}
         # unprotected leaves leave the round ONLY as cross-institution
         # sums — the annotated declassification the static gate checks
         H = revealed["hessian"] if protect in ("hessian", "both") \
@@ -133,6 +174,23 @@ def fit_scan_block(beta, obj_prev, converged, iters, key, round_base,
             else declassify_sum(sm.gradient, axis=0)
         dev = revealed["deviance"] if protect != "none" \
             else declassify_sum(sm.deviance, axis=0)
+        return H, g, dev
+
+    if mesh is not None:
+        # only the institutions' side is a manual computation: XLA:TPU
+        # refuses a float64 Cholesky inside one
+        from jax.sharding import PartitionSpec as P
+
+        public, sites = P(), P(POD_AXIS)
+        aggregates = jax.shard_map(
+            aggregates, mesh=mesh, in_specs=(public, public) + (sites,) * 5,
+            out_specs=public, check_vma=False,
+        )
+
+    def round_fn(carry):
+        beta, obj_prev, converged, iters, slot = carry
+        kr = agg.round_key(key, slot)
+        H, g, dev = aggregates(beta, kr, X, X32, slices, y, counts)
         obj = regularized_objective(dev, beta, lam, l1)
         active = ~converged & (iters < max_rounds)
         stop = should_stop(obj_prev, obj, tol, num_parts, scale)
@@ -166,3 +224,14 @@ def fit_scan_block(beta, obj_prev, converged, iters, key, round_base,
         round_fn, skip_fn, settled, carry0, num_rounds
     )
     return carry, objs, actives, grad_norms, step_norms
+
+
+# the program keeps its name in traces and in HLO: ``jit(fit_scan_block)``
+_fit_scan_block.__name__ = _fit_scan_block.__qualname__ = "fit_scan_block"
+_fit_scan_block = jax.jit(
+    _fit_scan_block,
+    static_argnames=("agg", "protect", "l1", "tol", "points",
+                     "include_count", "summaries_backend", "num_rounds",
+                     "num_parts", "max_rounds", "mesh"),
+)
+fit_scan_block.lower = _fit_scan_block.lower

@@ -10,7 +10,9 @@ Two halves:
   into (``repro_declass_total{site=...}``);
   ``repro_f64_slice_packs_total`` counts the packs whose float64 X was
   cut into bf16 slices for the compiled summaries kernel (once per
-  study, not per fit).
+  study, not per fit); ``repro_pod_wire_bytes_total`` the bytes each
+  device of a pod fit handed the cross-chip wire (executed rounds times
+  ``SecureCollective.pod_wire_bytes``, added at each fit's readback).
   :func:`render_prometheus` / :func:`export_textfile` emit the standard
   Prometheus text exposition format, ready for the node-exporter
   textfile collector — the scrape surface ROADMAP direction 1's study

@@ -22,6 +22,7 @@ from repro.analysis.lints import (SummaryBounds, lint_headroom,
                                   lint_host_sync, lint_kernel_knobs,
                                   lint_mesh_axes, lint_no_callbacks)
 from repro.analysis.report import AnalysisReport, Finding
+from repro.analysis.taint import SECRET, iter_eqns
 
 _SPECS = {s.name: s for s in all_driver_specs()}
 
@@ -50,6 +51,70 @@ def test_2d_mesh_uses_distributed_reveal():
     assert any("_distributed_reveal" in d for d in rep.declassifications)
 
 
+def test_pod_fit_sends_only_shares_across_chips():
+    """The pod fit's graph: X, X32 and the slices enter SECRET on every
+    pod (each pod's block of the sharded institution axis), every sum
+    over the pod axis carries share material only, and the revealed sum
+    is the one declassification."""
+    from repro.distributed.sharding import POD_AXIS
+
+    spec = _SPECS["pod_fit_scan[pods=4]"]
+    closed, taints = spec.build()
+    # X, X32, digits, scale, y and counts enter SECRET ...
+    assert taints[6:] == [SECRET] * 6
+    assert [v.aval.shape[0] for v in closed.jaxpr.invars[6:]] == [8] * 6
+    # ... and each pod takes its own block of their institution axis
+    (pod,) = [e for _, e, _ in iter_eqns(closed.jaxpr)
+              if e.primitive.name == "shard_map"]
+    split = [v.aval.shape for v, spec_ in zip(pod.invars,
+                                               pod.params["in_specs"])
+             if spec_ == (POD_AXIS,)]
+    assert len(split) == 6 and all(s[0] == 8 for s in split)
+    rep = _analyze_spec(spec)
+    assert rep.ok, rep.format(verbose=True)
+    assert rep.wire_operands
+    assert all(w.endswith(("PROTECTED", "PROTECTED_AGG"))
+               for w in rep.wire_operands), rep.wire_operands
+    (only,) = rep.declassifications
+    assert "jit(_reveal_flat)" in only
+
+
+def test_pod_aggregate_headroom_counts_every_pods_institutions():
+    """At a field where 52 addends fit the uint64 accumulator and 208 do
+    not, the pod aggregate (52 institutions on each of 4 pods) refuses,
+    and the same 52 on one device pass."""
+    from jax.sharding import AbstractMesh, PartitionSpec as P
+
+    from repro.core import SecureCollective
+    from repro.core.collective import FlatProtected
+    from repro.core.field import FieldSpec
+    from repro.core.fixed_point import FixedPointCodec
+    from repro.core.flatbuf import pack_pytree
+    from repro.core.shamir import ShamirScheme
+    from repro.distributed.sharding import POD_AXIS
+
+    narrow = FieldSpec("narrow57", (2 ** 57 - 13,))
+    assert 52 * narrow.moduli[0] < 2 ** 64 <= 208 * narrow.moduli[0]
+    agg = SecureCollective(scheme=ShamirScheme(field=narrow),
+                           codec=FixedPointCodec(field=narrow),
+                           backend="pallas")
+    _, layout = pack_pytree({"g": jnp.zeros((8,))})
+    shares = jax.ShapeDtypeStruct((3, 1, 52, layout.rows, 128), jnp.uint32)
+
+    def local(buf, axis_name=None):
+        return agg.aggregate_batched(FlatProtected(buf, layout),
+                                     axis_name).buf
+
+    jax.eval_shape(local, shares)
+    pods = jax.shard_map(
+        lambda buf: local(buf, POD_AXIS),
+        mesh=AbstractMesh((4,), (POD_AXIS,)), in_specs=P(),
+        out_specs=P(), check_vma=False,
+    )
+    with pytest.raises(ValueError, match="cannot aggregate 208"):
+        jax.eval_shape(pods, shares)
+
+
 # -- negative controls -----------------------------------------------------
 
 
@@ -73,6 +138,16 @@ def test_reveal_slice_fixture_caught_at_the_reveal_eqn():
     (f,) = [f for f in rep.errors() if "_reveal_flat" in f.where]
     assert "PER-INSTITUTION" in f.message
     assert "/eqn[" in f.where
+
+
+def test_pod_plain_wire_fixture_caught_at_the_psum():
+    """Plaintext summaries summed across pods: the finding names the
+    collective that carries them."""
+    rep = _fixture("LEAKY:pod_plain_wire")
+    assert not rep.ok
+    wire = [f for f in rep.errors() if f.where.endswith(":psum")]
+    assert wire and all("only share buffers" in f.message for f in wire)
+    assert all(w.endswith("SECRET") for w in rep.wire_operands)
 
 
 def test_callback_fixture_caught_at_the_callback_eqn():

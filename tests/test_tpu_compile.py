@@ -23,7 +23,7 @@ from repro.core import SecureCollective
 from repro.core.batched_summaries import pack_partitions
 from repro.core.field import FIELD_WIDE
 from repro.core.newton import newton_step
-from repro.core.scanfit import fit_scan_block
+from repro.core.scanfit import fit_scan_block, pod_partitioner
 from repro.data import generate_synthetic
 from repro.kernels.fused_irls import (
     fused_irls_cv_pallas,
@@ -51,7 +51,7 @@ W, T = 3, 2  # 2-of-3 Shamir
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -66,8 +66,13 @@ def one_chip():
     was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was_on)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 def _compile(fn, one_chip, *shapes):
@@ -325,3 +330,53 @@ def test_sliced_terms_keep_float64_out_of_their_dots(one_chip):
             if "kind=kOutput" in line and "dot_general" in line]
     assert len(dots) == 2
     assert all(re.match(r"\s*%[\w.\-]+ = f32\[", line) for line in dots)
+
+
+def test_pod_fit_program_compiles_for_a_v5e_2x2(v5e_2x2, monkeypatch):
+    """The fit with its institutions sharded over the four chips of a
+    v5e 2x2, kernels compiled: each round sums the pods' share buffers
+    with one all-reduce over the four devices, under ``pod_wire``, and
+    the summaries kernel runs on every chip's own sites."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.distributed.sharding import POD_AXIS
+
+    monkeypatch.setattr(kernel_backend, "interpret_kernels", lambda: False)
+    monkeypatch.setattr(kernel_ops, "interpret_kernels", lambda: False)
+    mesh = Mesh(np.array(v5e_2x2.devices), (POD_AXIS,))
+    sites, rows, dim = 8, 300, 16  # two sites a chip
+    padded = -(-rows // SLAB) * SLAB
+    public = NamedSharding(mesh, P())
+    split = NamedSharding(mesh, P(POD_AXIS))
+
+    def arg(shape, dtype, sharding=public):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    key = jax.random.PRNGKey(0)
+    try:
+        with pod_partitioner(mesh):
+            compiled = fit_scan_block.lower(
+                arg((dim,), jnp.float64), arg((), jnp.float64),
+                arg((), jnp.bool_), arg((), jnp.int32),
+                arg(key.shape, key.dtype), arg((), jnp.int32),
+                arg((sites, rows, dim), jnp.float64, split),
+                arg((sites, rows, dim), jnp.float32, split),
+                XSlices(arg((sites, padded, K * dim), jnp.bfloat16, split),
+                        arg((sites, padded), jnp.float64, split)),
+                arg((sites, rows), jnp.float64, split),
+                arg((sites,), jnp.int32, split), arg((), jnp.float64),
+                agg=SecureCollective(backend="pallas"), protect="both",
+                l1=0.0, tol=1e-10, points=(1, 2), include_count=True,
+                summaries_backend="pallas", num_rounds=2, num_parts=sites,
+                max_rounds=2, mesh=mesh,
+            ).compile()
+    finally:
+        jax.clear_caches()
+    text = compiled.as_text()
+    reduces = [line for line in text.splitlines()
+               if re.search(r" all-reduce(-start)?\(", line)]
+    assert len(reduces) == 1
+    assert "replica_groups={{0,1,2,3}}" in reduces[0] or \
+        "replica_groups=[1,4]<=[4]" in reduces[0]
+    assert "/aggregate/pod_wire/" in reduces[0]
+    assert "tpu_custom_call" in text
