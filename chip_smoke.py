@@ -24,7 +24,10 @@ checks on one line of JSON):
   MXU dots must be an integer under 2**24 (an MXU that did not
   accumulate them exactly would show here first), and z, g and dev must
   lie within a float64 dot's error bound.  64 sites, because XLA fused
-  the float64 level sum into the dot there and not at 16.
+  the float64 level sum into the dot there and not at 16.  Then the
+  ``reference`` rung at the paper's shape, 6 x 166,666 x 6: its H, g
+  and dev from the slices of X and of X's pair design, within a float64
+  Gram's and dot's error bounds of numpy float64.
 * ``collective`` (``--chips 4``) — the SPMD secret-shared all-reduce on
   1e6 f32 parameters: the 1D pod mesh of 4 with the sharded reveal and
   the (2, 2) pod x share mesh, each against ``jax.lax.psum`` of the same
@@ -205,14 +208,12 @@ def phase_sliced(sizes=(1, 900, 3000) + (3760,) * 61, dim: int = 128,
     bound_dev = 2.0 * u * (n * np.abs(ll).sum(axis=1)
                            + dim * (xb * mask).sum(axis=1))
 
-    def worst(err, bound):
-        return float(np.max(err / np.where(bound > 0, bound, 1.0)))
-
-    z_ratio = worst(np.abs(got_z - z), dim * u * xb)
-    g_ratio = worst(np.abs(np.asarray(g) - np.einsum("snd,sn->sd", X, r)),
-                    bound_g)
-    dev_ratio = worst(np.abs(np.asarray(dev) + 2.0 * ll.sum(axis=1)),
-                      bound_dev)
+    z_ratio = _worst(np.abs(got_z - z), dim * u * xb)
+    g_ratio = _worst(np.abs(np.asarray(g) - np.einsum("snd,sn->sd", X, r)),
+                     bound_g)
+    dev_ratio = _worst(np.abs(np.asarray(dev) + 2.0 * ll.sum(axis=1)),
+                       bound_dev)
+    ref = _sliced_reference(seed=seed)
     return _emit("sliced", time.perf_counter() - t0, {
         "sites": len(sizes),
         "rows": n,
@@ -230,7 +231,63 @@ def phase_sliced(sizes=(1, 900, 3000) + (3760,) * 61, dim: int = 128,
         "z_ok": z_ratio <= 1.0,
         "g_ok": g_ratio <= 1.0,
         "dev_ok": dev_ratio <= 1.0,
+        **ref,
     })
+
+
+def _worst(err, bound):
+    import numpy as np
+
+    return float(np.max(err / np.where(bound > 0, bound, 1.0)))
+
+
+def _sliced_reference(sites: int = 6, rows: int = 166_666, dim: int = 6,
+                      seed: int = 0) -> dict:
+    """The ``reference`` rung's H, g and dev from the slices of X and of
+    its pair design against numpy float64, as ``reference_*`` checks."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core.batched_summaries import _reference_summaries
+    from repro.kernels.sliced_terms import cut_pair_slices, cut_slices
+
+    rng = np.random.default_rng(seed + 1)
+    X = rng.standard_normal((sites, rows, dim))
+    X[..., 0] = 1.0  # the intercept
+    X[1, 5] *= 2.0 ** 20  # rows far from the others in size
+    X[1, 6] *= 2.0 ** -20
+    y = (rng.random((sites, rows)) < 0.5).astype(np.float64)
+    counts = np.full((sites,), rows, np.int32)
+    beta = rng.uniform(-0.5, 0.5, dim)
+    X_dev = jnp.asarray(X)
+    slices = cut_slices(X_dev)._replace(pair=cut_pair_slices(X_dev))
+    H, g, dev = jax.jit(_reference_summaries)(
+        jnp.asarray(beta), X_dev, jnp.asarray(y), jnp.asarray(counts),
+        slices)
+    X = np.asarray(X_dev)  # X as the chip holds it, some 48 bits
+    u = 2.0 ** -53
+    z = X @ beta
+    p = np.exp(-np.logaddexp(0.0, -z))
+    w, r = p * (1.0 - p), y - p
+    ll = y * z - np.logaddexp(0.0, z)
+    xb = np.abs(X) @ np.abs(beta)
+    ratios = {
+        "h": _worst(np.abs(np.asarray(H) - np.einsum(
+            "sni,sn,snj->sij", X, w, X)), rows * u * np.einsum(
+                "sni,sn,snj->sij", np.abs(X), w, np.abs(X))),
+        "g": _worst(np.abs(np.asarray(g) - np.einsum("snd,sn->sd", X, r)),
+                    rows * u * np.einsum("snd,sn->sd", np.abs(X),
+                                         np.abs(r))),
+        "dev": _worst(np.abs(np.asarray(dev) + 2.0 * ll.sum(axis=1)),
+                      2.0 * u * (rows * np.abs(ll).sum(axis=1)
+                                 + dim * xb.sum(axis=1))),
+    }
+    out = {"reference_shape": [sites, rows, dim]}
+    for name, ratio in ratios.items():
+        out[f"reference_{name}_err_over_bound"] = ratio
+        out[f"reference_{name}_ok"] = ratio <= 1.0
+    return out
 
 
 def phase_collective(params: int = 1_000_000, seed: int = 0) -> bool:

@@ -229,7 +229,8 @@ def _pod_scan_spec(name: str, num_pods: int = 4):
 
         mesh = pod_mesh(num_pods)
         packed = _with_slices(
-            pack_partitions(toy_parts(2 * num_pods), mesh=mesh), None, mesh)
+            pack_partitions(toy_parts(2 * num_pods), mesh=mesh), None,
+            "pallas", mesh)
         jax.block_until_ready(fit(mesh, *args(packed)))
 
     return DriverSpec(name=name, build=build, runner=runner,

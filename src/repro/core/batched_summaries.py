@@ -10,7 +10,9 @@ This module packs the ragged partitions ONCE per fit into a stacked
   institutions (X streamed through VMEM once; IRLS weights never touch
   HBM; Gram accumulation in f32 as on the MXU).
 * ``backend="reference"`` — the masked jnp oracle (f64 end to end), used
-  by tests and as the legacy-comparable gold path.
+  by tests and as the legacy-comparable gold path; compiled for a chip
+  with a float64 payload it takes the same float64 summaries from the
+  pack's digit slices (``kernels.sliced_terms``).
 * ``backend="mixed"`` — f64 gradient/deviance with a split-accumulation
   f32 Gram (chunked f32 gemms merged in f64): ~4x the Hessian accuracy
   of the single-pass f32 Gram at f32-gemm speed, the natural two-pass
@@ -35,7 +37,15 @@ import jax.numpy as jnp
 import numpy as np
 from typing import NamedTuple
 
-from ..kernels.sliced_terms import MAX_DIM, XSlices, cut_slices
+from ..kernels.sliced_terms import (
+    MAX_DIM,
+    MAX_PAIR_DIM,
+    XSlices,
+    cut_pair_slices,
+    cut_slices,
+    sliced_gram,
+    sliced_terms,
+)
 from ..obs import metrics as _metrics
 from .logreg import LocalSummaries
 
@@ -58,8 +68,10 @@ class PackedPartitions:
     side.  ``y`` stays f64 either way: labels are 0/1 (exact in any
     float) and the gradient/deviance accumulate in f64.  ``slices`` are
     the bf16 digit slices of a float64 ``X`` (``kernels.sliced_terms``),
-    from which the compiled ``pallas`` rung takes its float64 gradient
-    and deviance; ``pack_partitions`` cuts them only for that rung.
+    from which the compiled ``pallas`` and ``reference`` rungs take their
+    float64 gradient and deviance, and the ``reference`` rung its Gram
+    from the slices of X's pair design (``slices.pair``);
+    ``pack_partitions`` cuts them only for those rungs.
     """
 
     X: jnp.ndarray  # (S, N_max, d) payload (f32 or f64)
@@ -157,33 +169,50 @@ def pack_cache_len() -> int:
     return len(_PACK_CACHE)
 
 
+def _wants_pair(packed: PackedPartitions, backend: str | None) -> bool:
+    """The ``reference`` rung takes its Gram from the slices of the pair
+    design while that fits a row of digits, and the pack has none."""
+    return (backend == "reference" and packed.dim <= MAX_PAIR_DIM
+            and (packed.slices is None or packed.slices.pair is None))
+
+
 def _wants_slices(packed: PackedPartitions, backend: str | None) -> bool:
-    """The compiled kernel with a float64 payload of at most ``MAX_DIM``
-    features reads the slices."""
+    """The compiled ``pallas`` or ``reference`` rung with a float64
+    payload of at most ``MAX_DIM`` features reads slices the pack does
+    not carry yet."""
     from ..kernels.backend import interpret_kernels
 
-    return (backend == "pallas" and packed.slices is None
+    return (backend in ("pallas", "reference")
+            and (packed.slices is None or _wants_pair(packed, backend))
             and packed.X.dtype == jnp.float64 and packed.dim <= MAX_DIM
             and not interpret_kernels())
 
 
-def _with_slices(packed: PackedPartitions, key,
+def _with_slices(packed: PackedPartitions, key, backend: str | None,
                  mesh=None) -> PackedPartitions:
-    """``packed`` with its slices cut, in its cache entry too.  On a pod
-    ``mesh`` each device cuts the slices of its own institutions."""
-    if mesh is None:
-        slices = cut_slices(packed.X)
-    else:
+    """``packed`` with the slices ``backend`` reads cut, in its cache
+    entry too.  On a pod ``mesh`` each device cuts the slices of its own
+    institutions."""
+    def cut(fn):
+        if mesh is None:
+            return fn(packed.X)
         from jax.sharding import PartitionSpec as P
 
         from ..distributed.sharding import POD_AXIS
 
         sites = P(POD_AXIS)
-        slices = jax.jit(jax.shard_map(
-            cut_slices, mesh=mesh, in_specs=sites, out_specs=sites,
+        return jax.jit(jax.shard_map(
+            fn, mesh=mesh, in_specs=sites, out_specs=sites,
         ))(packed.X)
+
+    slices = packed.slices
+    if slices is None:
+        slices = cut(cut_slices)
+        _metrics.inc("repro_f64_slice_packs_total")
+    if _wants_pair(packed, backend):
+        slices = slices._replace(pair=cut(cut_pair_slices))
+        _metrics.inc("repro_f64_pair_slice_packs_total")
     out = dataclasses.replace(packed, slices=slices)
-    _metrics.inc("repro_f64_slice_packs_total")
     entry = _PACK_CACHE.get(key)
     if entry is not None and entry[1] is packed:
         _PACK_CACHE[key] = (entry[0], out)
@@ -249,10 +278,12 @@ def pack_partitions(
     float64 keeps the exact oracle payload (plus a separate f32 MXU
     operand); float32 stores one f32 buffer total — the TPU layout.
     ``backend`` names the summaries rung that will read the pack: for
-    the compiled ``pallas`` rung with a float64 payload the pack also
-    carries the slices of X, cut on first use and kept with the cached
-    pack.  ``mesh``: a pod mesh to shard the institution axis over
-    (``_stack_on_pods``); the pack is cached under the mesh too.
+    the compiled ``pallas`` and ``reference`` rungs with a float64
+    payload the pack also carries the slices of X, and for the
+    ``reference`` rung those of its pair design, cut on first use and
+    kept with the cached pack.  ``mesh``: a pod mesh to shard the
+    institution axis over (``_stack_on_pods``); the pack is cached
+    under the mesh too.
     """
     if not parts:
         raise ValueError("need at least one partition")
@@ -273,7 +304,7 @@ def pack_partitions(
         if hit is not None:
             _PACK_CACHE.move_to_end(key)
             if _wants_slices(hit[1], backend):
-                return _with_slices(hit[1], key, mesh)
+                return _with_slices(hit[1], key, backend, mesh)
             return hit[1]
     if mesh is not None:
         packed = _stack_on_pods(parts, dtype, mesh)
@@ -296,7 +327,7 @@ def pack_partitions(
         while len(_PACK_CACHE) > _PACK_CACHE_SIZE:
             _PACK_CACHE.popitem(last=False)
     if _wants_slices(packed, backend):
-        return _with_slices(packed, key, mesh)
+        return _with_slices(packed, key, backend, mesh)
     return packed
 
 
@@ -316,11 +347,20 @@ def _masked_irls_terms(beta, X, y, counts):
         return w, g, dev
 
 
-def _reference_summaries(beta, X, y, counts):
-    """Masked batched oracle in the payload dtype (f64)."""
-    w, g, dev = _masked_irls_terms(beta, X, y, counts)
+def _reference_summaries(beta, X, y, counts, slices=None):
+    """Masked batched oracle in the payload dtype (f64).  Given the
+    pack's ``slices``, the float64 terms come from them, and the Gram
+    from the slices of the pair design where the pack carries them:
+    no float64 contraction over X then remains for a TPU to emulate."""
+    if slices is None:
+        w, g, dev = _masked_irls_terms(beta, X, y, counts)
+    else:
+        w, g, dev = sliced_terms(beta, slices, y, counts, jnp.float64)
     with jax.named_scope("gram"):
-        H = jnp.einsum("sni,snj->sij", X * w[..., None], X)
+        if slices is None or slices.pair is None:
+            H = jnp.einsum("sni,snj->sij", X * w[..., None], X)
+        else:
+            H = sliced_gram(w, slices.pair, X.shape[2])
     return H, g, dev
 
 
@@ -561,6 +601,6 @@ def batched_local_summaries(
             dev = dev.astype(jnp.float64)
         else:
             H, g, dev = _reference_summaries(
-                beta, packed.X, packed.y, packed.counts
+                beta, packed.X, packed.y, packed.counts, packed.slices
             )
         return LocalSummaries(H, g, dev, packed.counts)

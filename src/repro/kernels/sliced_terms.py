@@ -32,6 +32,14 @@ up to a slab: z contracts its minor axis, g its rows slab by slab, and
 neither reads it in another layout.  They cost ``2 K / 8 = 2`` times the
 bytes of a float64 X.  Past ``MAX_DIM`` features a row's products could
 pass 2**24, so wider X keeps the float64 contractions.
+
+The Gram.  H = sum_n w_n x_n x_n^T is linear in w, with the fixed pair
+design P: each row's d (d + 1) / 2 products x_i x_j, i <= j, formed in
+float64 and cut like X (``cut_pair_slices``).  H's upper triangle is
+then g's sum with w in place of the residual (``sliced_gram``), exact
+for the same reason: the only rounding left is P's own float64
+products, which a float64 Gram makes too.  P fits a row of digits up to
+``MAX_PAIR_DIM`` features.
 """
 from __future__ import annotations
 
@@ -41,22 +49,26 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["K", "KQ", "SLAB", "MAX_DIM", "XSlices", "cut_slices",
-           "z_levels", "g_levels", "sliced_terms"]
+__all__ = ["K", "KQ", "SLAB", "MAX_DIM", "MAX_PAIR_DIM", "XSlices",
+           "cut_slices", "cut_pair_slices", "z_levels", "g_levels",
+           "sliced_terms", "sliced_gram"]
 
 K = 8  # digits of X: 64 bits, past float64's 53
 SLAB = 128  # rows per exponent of the scaled residual
 MAX_DIM = 128  # features a row of digits may hold and stay exact
 KQ = K + 3  # digits of the residual: 24 more bits for rows of unlike size
+MAX_PAIR_DIM = 15  # features whose pair design, d (d + 1) / 2, fits MAX_DIM
 _BASE = 256.0
 
 
 class XSlices(NamedTuple):
     """X as base-256 digits: ``X[s, n] = scale[s, n] * sum_i digits[s, n,
-    i*d:(i+1)*d] * 256**-(i + 1)`` up to the tail."""
+    i*d:(i+1)*d] * 256**-(i + 1)`` up to the tail; ``pair``, where cut,
+    the slices of X's pair design, from which the float64 Gram comes."""
 
     digits: jnp.ndarray  # (S, Np, K * d) bfloat16 integers, |a| <= 128
     scale: jnp.ndarray  # (S, Np) float64 powers of two, 2**(e + 1)
+    pair: "XSlices | None" = None
 
 
 def _pow2(e):
@@ -99,6 +111,19 @@ def cut_slices(X) -> XSlices:
     e = _exponent(jnp.max(jnp.abs(X), axis=2))
     a = _digits(X, _pow2(-e - 1)[..., None], axis=2)  # (S, Np, K, d)
     return XSlices(a.reshape(s_dim, n_pad, K * d), _pow2(e + 1))
+
+
+@jax.jit
+def cut_pair_slices(X) -> XSlices:
+    """Cut the pair design of the packed float64 payload (S, N, d): each
+    row's products x_i x_j, i <= j, in ``np.triu_indices`` order."""
+    d = X.shape[2]
+    if d > MAX_PAIR_DIM:
+        raise ValueError(
+            f"the sliced Gram holds exact for d <= {MAX_PAIR_DIM}")
+    i, j = np.triu_indices(d)
+    X = X.astype(jnp.float64)
+    return cut_slices(X[..., i] * X[..., j])
 
 
 def _unfused(levels):
@@ -159,12 +184,25 @@ def g_levels(q, digits):
     return _unfused(gl), _pow2(eq + 1)
 
 
-def sliced_terms(beta, slices: XSlices, y, counts):
-    """The f64 z/g/dev terms and the f32 IRLS weights, as ``_sim_terms``
-    gives them, from the cut slices of X."""
+def _transposed_dot(v, slices: XSlices):
+    """The sliced matrix transposed times v (S, N): float64 (S, columns),
+    from the KQ levels of ``g_levels``."""
+    digits, scale = slices.digits, slices.scale
+    n_pad, n = digits.shape[1], v.shape[1]
+    gl, qscale = g_levels(jnp.pad(v, ((0, 0), (0, n_pad - n))) * scale,
+                          digits)
+    gfix = jnp.sum(_level_weights(KQ)[:, None]
+                   * gl.astype(jnp.float64), axis=2)  # (S, slabs, cols)
+    return jnp.sum(qscale[..., None] * gfix, axis=1)
+
+
+def sliced_terms(beta, slices: XSlices, y, counts, w_dtype=jnp.float32):
+    """The f64 z/g/dev terms and the IRLS weights, as ``_sim_terms``
+    gives them, from the cut slices of X.  The weights come in
+    ``w_dtype``: f32 for the kernel's Gram, float64 for ``sliced_gram``."""
     with jax.named_scope("f64_terms"):
-        digits, xscale = slices
-        n_pad, n = digits.shape[1], y.shape[1]
+        digits, xscale = slices.digits, slices.scale
+        n = y.shape[1]
         zl, bscale = z_levels(beta, digits)
         zfix = jnp.sum(_level_weights(K)[:, None, None]
                        * zl.astype(jnp.float64), axis=0)
@@ -173,11 +211,18 @@ def sliced_terms(beta, slices: XSlices, y, counts):
                 < counts[:, None]).astype(jnp.float64)
         p = jax.nn.sigmoid(z)
         resid = (y - p) * mask
-        gl, qscale = g_levels(
-            jnp.pad(resid, ((0, 0), (0, n_pad - n))) * xscale, digits)
-        gfix = jnp.sum(_level_weights(KQ)[:, None]
-                       * gl.astype(jnp.float64), axis=2)  # (S, slabs, d)
-        g = jnp.sum(qscale[..., None] * gfix, axis=1)
-        w32 = ((p * (1.0 - p)) * mask).astype(jnp.float32)
+        g = _transposed_dot(resid, slices)
+        w = ((p * (1.0 - p)) * mask).astype(w_dtype)
         dev = -2.0 * jnp.sum((y * z - jnp.logaddexp(0.0, z)) * mask, axis=1)
-        return w32, g, dev
+        return w, g, dev
+
+
+def sliced_gram(w, pair: XSlices, d: int):
+    """H = sum_n w_n x_n x_n^T, float64 (S, d, d), from the slices of X's
+    pair design (``cut_pair_slices``): the upper triangle is g's sum
+    with the weights (S, N) in place of the residual, then mirrored."""
+    upper = _transposed_dot(w, pair)  # (S, d (d + 1) / 2)
+    i, j = np.triu_indices(d)
+    at = np.empty((d, d), np.int32)
+    at[i, j] = at[j, i] = np.arange(i.size)
+    return upper[:, at]

@@ -9,9 +9,11 @@ Two halves:
   the in-graph metric leaves) and the audit folds the privacy ledger
   into (``repro_declass_total{site=...}``);
   ``repro_f64_slice_packs_total`` counts the packs whose float64 X was
-  cut into bf16 slices for the compiled summaries kernel (once per
-  study, not per fit); ``repro_pod_wire_bytes_total`` the bytes each
-  device of a pod fit handed the cross-chip wire (executed rounds times
+  cut into bf16 slices for the compiled summaries kernels (once per
+  study, not per fit), ``repro_f64_pair_slice_packs_total`` those whose
+  pair design was cut too, for the ``reference`` rung's Gram;
+  ``repro_pod_wire_bytes_total`` the bytes each device of a pod fit
+  handed the cross-chip wire (executed rounds times
   ``SecureCollective.pod_wire_bytes``, added at each fit's readback).
   :func:`render_prometheus` / :func:`export_textfile` emit the standard
   Prometheus text exposition format, ready for the node-exporter

@@ -337,12 +337,50 @@ def test_sliced_terms_hold_float64_bounds(case):
     assert np.all(np.abs(np.asarray(dev) - want_dev) <= bound_dev)
     # z itself, from the same levels the terms combine
     from repro.kernels.sliced_terms import K, z_levels
-    digits, scale = cut_slices(jnp.asarray(X))
+    slices = cut_slices(jnp.asarray(X))
+    digits, scale = slices.digits, slices.scale
     zl, bscale = z_levels(jnp.asarray(beta), digits)
     zfix = sum(np.asarray(zl[lv], np.float64) * 2.0 ** (-8 * (lv + 2))
                for lv in range(K))
     got_z = (zfix * np.asarray(scale) * float(bscale))[:, :n]
     assert np.all(np.abs(got_z - z) <= d * u * xb)
+
+
+@pytest.mark.parametrize("d", [1, 6, 15])
+@pytest.mark.parametrize("case", ["ragged", "zero_count", "scaled_rows",
+                                  "zero_row"])
+def test_sliced_gram_holds_float64_bounds(case, d):
+    """The Gram from the slices of X's pair design against numpy's
+    float64 Gram, within a float64 Gram's error bound, N 2**-53 sum_n
+    |x_ni| w_n |x_nj| per entry, and symmetric: on ragged sites, a site
+    of no rows, rows 2**20 apart in size and rows of zeros."""
+    from repro.kernels.sliced_terms import cut_pair_slices, sliced_gram
+
+    rng = np.random.default_rng(157 + d)
+    sizes = (0, 130, 300, 77) if case == "zero_count" else (1, 130, 300, 77)
+    X = rng.standard_normal((len(sizes), max(sizes), d))
+    if case == "scaled_rows":
+        X[2, 5] *= 2.0 ** 20
+        X[2, 6] *= 2.0 ** -20
+        X[3, 70] *= 2.0 ** -20
+    elif case == "zero_row":
+        X[2, 40] = 0.0
+        X[1, :3] = 0.0
+    counts = np.asarray(sizes, np.int32)
+    mask = (np.arange(X.shape[1])[None, :] < counts[:, None])
+    X[~mask] = 0.0
+    w = rng.uniform(0.0, 0.25, mask.shape) * mask  # as p (1 - p) lies
+    H = jax.jit(sliced_gram, static_argnums=2)(
+        jnp.asarray(w), cut_pair_slices(jnp.asarray(X)), d)
+    H = np.asarray(H)
+    want = np.einsum("sni,sn,snj->sij", X, w, X)
+    bound = X.shape[1] * 2.0 ** -53 * np.einsum(
+        "sni,sn,snj->sij", np.abs(X), w, np.abs(X))
+    assert H.shape == want.shape and H.dtype == np.float64
+    assert np.all(np.abs(H - want) <= bound)
+    assert np.array_equal(H, np.swapaxes(H, 1, 2))
+    if case == "zero_count":
+        assert not H[0].any()
 
 
 def test_sliced_partials_are_exact_integers():
@@ -354,7 +392,8 @@ def test_sliced_partials_are_exact_integers():
 
     X, y, counts = _slice_case("scaled_rows")
     s_dim, n, d = X.shape
-    digits, scale = cut_slices(jnp.asarray(X))
+    slices = cut_slices(jnp.asarray(X))
+    digits, scale = slices.digits, slices.scale
     a = np.asarray(digits, np.float64)
     assert a.shape == (s_dim, -(-n // SLAB) * SLAB, K * d)
     assert np.all(a == np.round(a)) and np.abs(a).max() <= 128
@@ -374,13 +413,18 @@ def test_sliced_partials_are_exact_integers():
         assert np.abs(part).max() < 2.0 ** 24
 
 
-def test_secure_fit_through_sliced_terms_matches_float64_terms(study):
+@pytest.mark.parametrize("rung, pair", [
+    ("pallas", False), ("reference", False), ("reference", True)])
+def test_secure_fit_through_sliced_terms_matches_float64_terms(
+        study, rung, pair):
     """A whole secure fit whose gradient and deviance come from the slices
-    runs the same rounds as the fit on ``_sim_terms`` (float64
-    contractions over X) and lands on the same beta within 1e-12."""
+    runs the same rounds as the fit on the rung's float64 contractions
+    over X and lands on the same beta within 1e-12: on the ``pallas``
+    rung, and on the ``reference`` rung with its float64 Gram over X or,
+    given the pair design's slices, from them."""
     from repro.core import SecureCollective
     from repro.core.scanfit import fit_scan_block
-    from repro.kernels.sliced_terms import cut_slices
+    from repro.kernels.sliced_terms import cut_pair_slices, cut_slices
 
     packed = pack_partitions(_uneven_parts(study))
     agg = SecureCollective(backend="pallas")
@@ -393,13 +437,16 @@ def test_secure_fit_through_sliced_terms_matches_float64_terms(study):
             jax.random.PRNGKey(0), jnp.zeros((), jnp.int32),
             packed.X, packed.X32, slices, packed.y, packed.counts,
             jnp.asarray(1.0), agg=agg, protect="both", l1=0.0, tol=1e-10,
-            points=None, include_count=True, summaries_backend="pallas",
+            points=None, include_count=True, summaries_backend=rung,
             num_rounds=12, num_parts=packed.num_institutions,
             max_rounds=12)
         return np.asarray(carry[0]), int(carry[3]), bool(carry[2])
 
+    slices = cut_slices(packed.X)
+    if pair:
+        slices = slices._replace(pair=cut_pair_slices(packed.X))
     beta_sim, rounds_sim, conv_sim = fit(None)
-    beta_cut, rounds_cut, conv_cut = fit(cut_slices(packed.X))
+    beta_cut, rounds_cut, conv_cut = fit(slices)
     assert conv_sim and conv_cut
     assert rounds_cut == rounds_sim
     np.testing.assert_allclose(beta_cut, beta_sim, rtol=0, atol=1e-12)
@@ -407,27 +454,50 @@ def test_secure_fit_through_sliced_terms_matches_float64_terms(study):
 
 def test_pack_cuts_slices_once_for_the_compiled_float64_rung(
         study, monkeypatch):
-    """Only the compiled ``pallas`` rung with a float64 payload of at
-    most 128 features gets slices: cut on its first pack, kept with the
-    cached pack and counted once; the CPU's simulation, the other rungs,
-    an f32 payload and a wider X get none."""
+    """Only the compiled ``pallas`` and ``reference`` rungs with a float64
+    payload of at most 128 features get slices, and the ``reference``
+    rung those of the pair design too while d <= 15: each cut on its
+    first pack, kept with the cached pack and counted once; the CPU's
+    rungs, the ``mixed`` rung, an f32 payload and a wider X get none."""
     import repro.kernels.backend as kernel_backend
     from repro.obs import metrics
 
+    def cuts():
+        return [metrics.get(name) or 0.0
+                for name in ("repro_f64_slice_packs_total",
+                             "repro_f64_pair_slice_packs_total")]
+
     parts = _uneven_parts(study)
     pack_cache_clear()
-    assert pack_partitions(parts, backend="pallas").slices is None
-    before = metrics.get("repro_f64_slice_packs_total") or 0.0
-    monkeypatch.setattr(kernel_backend, "interpret_kernels", lambda: False)
-    for rung in ("reference", "mixed"):
+    for rung in ("pallas", "reference"):
         assert pack_partitions(parts, backend=rung).slices is None
-    assert pack_partitions(parts, dtype=jnp.float32,
-                           backend="pallas").slices is None
+    before = cuts()
+    monkeypatch.setattr(kernel_backend, "interpret_kernels", lambda: False)
+    assert pack_partitions(parts, backend="mixed").slices is None
     wide = [(jnp.ones((3, 129)), jnp.ones(3))]
-    assert pack_partitions(wide, backend="pallas").slices is None
+    for rung in ("pallas", "reference"):
+        assert pack_partitions(parts, dtype=jnp.float32,
+                               backend=rung).slices is None
+        assert pack_partitions(wide, backend=rung).slices is None
+    assert cuts() == before
     first = pack_partitions(parts, backend="pallas")
-    assert first.slices is not None
+    assert first.slices is not None and first.slices.pair is None
     assert pack_partitions(parts, backend="pallas") is first
     assert pack_partitions(parts) is first  # the cache keeps the slices
-    assert metrics.get("repro_f64_slice_packs_total") == before + 1.0
+    assert cuts() == [before[0] + 1.0, before[1]]
+    # the reference rung adds the pair design's slices to the same pack
+    ref = pack_partitions(parts, backend="reference")
+    assert ref.slices.digits is first.slices.digits
+    assert ref.slices.pair is not None
+    assert pack_partitions(parts, backend="reference") is ref
+    assert pack_partitions(parts, backend="pallas") is ref
+    assert cuts() == [before[0] + 1.0, before[1] + 1.0]
+    # a reference-rung pack cuts both at once; past d = 15 X's alone
+    other = _uneven_parts(study, sizes=(500, 400, 300, 0))
+    both = pack_partitions(other, backend="reference")
+    assert both.slices is not None and both.slices.pair is not None
+    assert pack_partitions(other, backend="reference") is both
+    mid = [(jnp.ones((3, 16)), jnp.ones(3))]
+    assert pack_partitions(mid, backend="reference").slices.pair is None
+    assert cuts() == [before[0] + 3.0, before[1] + 2.0]
     pack_cache_clear()

@@ -20,7 +20,7 @@ from jax.sharding import SingleDeviceSharding
 import repro.kernels.backend as kernel_backend
 import repro.kernels.ops as kernel_ops
 from repro.core import SecureCollective
-from repro.core.batched_summaries import pack_partitions
+from repro.core.batched_summaries import _reference_summaries, pack_partitions
 from repro.core.field import FIELD_WIDE
 from repro.core.newton import newton_step
 from repro.core.scanfit import fit_scan_block, pod_partitioner
@@ -272,63 +272,83 @@ def test_fit_program_carries_every_scope(rung, on_chip, request,
                 if "jit(fused_irls_pallas)" in text} == {"summaries/gram"}
 
 
-def _f64_contractions_over_x(hlo, shape):
-    """The dots and reduces of ``hlo`` with a float64 operand of the
-    payload's (S, N, d) ``shape``: each operand's type is read from the
-    instruction that defines it."""
-    x_type = "f64[" + ",".join(str(n) for n in shape) + "]"
+def _f64_contractions_over_x(hlo, *shapes):
+    """The dots and reduces of ``hlo`` with a float64 operand of one of
+    ``shapes``, the payload's (S, N, d) and its pair design's: each
+    operand's type is read from the instruction that defines it."""
+    x_types = {"f64[" + ",".join(str(n) for n in shape) + "]"
+               for shape in shapes}
     defined = re.compile(r"\s*(?:ROOT )?(%[\w.\-]+) = (\S+?)(?:\{| )")
     types = {m.group(1): m.group(2) for m in map(defined.match,
                                                    hlo.splitlines()) if m}
     found = []
     for line in hlo.splitlines():
         m = re.search(r" (dot|reduce)\(([^)]*)\)", line)
-        if m and any(types.get(name) == x_type
+        if m and any(types.get(name) in x_types
                      for name in re.findall(r"%[\w.\-]+", m.group(2))):
             found.append(line.strip())
     return found
 
 
-def test_chip_fit_program_contracts_no_float64_x(one_chip, monkeypatch):
-    """The ``pallas`` fit program lowered for the chip takes its float64
-    terms from the slices: no dot or reduce reads X as float64, which a
-    TPU would emulate by re-splitting X every round.  Without the
-    slices the same program holds such a contraction, so the check sees
-    what it guards against."""
+@pytest.mark.parametrize("rung", ["pallas", "reference"])
+def test_chip_fit_program_contracts_no_float64_x(rung, one_chip,
+                                                 monkeypatch):
+    """The fit program lowered for the chip takes its float64 terms, and
+    on the ``reference`` rung its float64 Gram, from the slices: no dot
+    or reduce reads X, or X's pair design, as float64, which a TPU would
+    emulate by re-splitting it every round.  Without the slices the same
+    program holds such a contraction, so the check sees what it guards
+    against."""
     monkeypatch.setattr(kernel_backend, "interpret_kernels", lambda: False)
     monkeypatch.setattr(kernel_ops, "interpret_kernels", lambda: False)
     sites, rows, dim = 3, 41, 6
+    shapes = (sites, rows, dim), (sites, rows, dim * (dim + 1) // 2)
     try:
-        sliced = _fit_hlo("pallas", 5, sites, rows, dim, one_chip)
-        plain = _fit_hlo("pallas", 5, sites, rows, dim, one_chip,
+        sliced = _fit_hlo(rung, 5, sites, rows, dim, one_chip)
+        plain = _fit_hlo(rung, 5, sites, rows, dim, one_chip,
                          slices=False)
     finally:
         jax.clear_caches()
     assert "bf16" in sliced
-    assert _f64_contractions_over_x(sliced, (sites, rows, dim)) == []
-    assert _f64_contractions_over_x(plain, (sites, rows, dim)) != []
+    assert _f64_contractions_over_x(sliced, *shapes) == []
+    assert _f64_contractions_over_x(plain, *shapes) != []
 
 
-def test_sliced_terms_keep_float64_out_of_their_dots(one_chip):
-    """At 64 sites of 3,760 rows the two dots of the sliced terms each
-    write their f32 levels and nothing more.  Without the barrier XLA
-    fused the float64 level sum, an f32 pair, into the z dot at this
-    shape, and on a v5e that program returned wrong z."""
+def _pallas_sliced(beta, digits, scale, y, counts):
+    """The ``pallas`` rung's float64 terms from the slices."""
+    return sliced_terms(beta, XSlices(digits, scale), y, counts)
+
+
+def _reference_sliced(beta, digits, scale, pair_digits, pair_scale, X, y,
+                      counts):
+    """The ``reference`` rung's summaries from the slices."""
+    return _reference_summaries(
+        beta, X, y, counts,
+        XSlices(digits, scale, XSlices(pair_digits, pair_scale)))
+
+
+@pytest.mark.parametrize("rung", ["pallas", "reference"])
+def test_sliced_terms_keep_float64_out_of_their_dots(rung, one_chip):
+    """At 64 sites of 3,760 rows the dots of the sliced terms, and on the
+    ``reference`` rung the Gram's level dot too, each write their f32
+    levels and nothing more.  Without the barrier XLA fused the float64
+    level sum, an f32 pair, into the z dot at this shape, and on a v5e
+    that program returned wrong z."""
     sites, rows = 64, 3760
     padded = -(-rows // SLAB) * SLAB
-    c = _compile(
-        lambda beta, digits, scale, y, counts: sliced_terms(
-            beta, XSlices(digits, scale), y, counts),
-        one_chip,
-        ((D,), jnp.float64),
-        ((sites, padded, K * D), jnp.bfloat16),
-        ((sites, padded), jnp.float64),
-        ((sites, rows), jnp.float64),
-        ((sites,), jnp.int32),
-    )
+    dim = D if rung == "pallas" else 15
+    slices = [((sites, padded, K * dim), jnp.bfloat16),
+              ((sites, padded), jnp.float64)]
+    fn = _pallas_sliced if rung == "pallas" else _reference_sliced
+    if rung == "reference":
+        slices += [((sites, padded, K * dim * (dim + 1) // 2), jnp.bfloat16),
+                   ((sites, padded), jnp.float64),
+                   ((sites, rows, dim), jnp.float64)]
+    c = _compile(fn, one_chip, ((dim,), jnp.float64), *slices,
+                 ((sites, rows), jnp.float64), ((sites,), jnp.int32))
     dots = [line for line in c.as_text().splitlines()
             if "kind=kOutput" in line and "dot_general" in line]
-    assert len(dots) == 2
+    assert len(dots) == (2 if rung == "pallas" else 3)
     assert all(re.match(r"\s*%[\w.\-]+ = f32\[", line) for line in dots)
 
 
